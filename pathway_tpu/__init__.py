@@ -10,6 +10,32 @@ package (``python/pathway/__init__.py``).
 
 from __future__ import annotations
 
+
+def _place_compile_cache() -> None:
+    """Give XLA's persistent compilation cache a home before anything compiles.
+
+    A server start compiles ~20 encoder buckets plus a search kernel per
+    capacity; on a machine that is thrown away after each run that is most of a
+    cold start. If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache goes to ``<checkout>/.jax_cache``,
+    derived from this package's own path: a directory that moves between runs
+    (tempfile, pid, time) never hits. Every entry point (scripts, spawned ranks,
+    replica children, bench sections) passes this line by importing the package.
+    """
+    import os
+
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    jax.config.update(
+        "jax_compilation_cache_dir", os.path.join(checkout, ".jax_cache")
+    )
+
+
+_place_compile_cache()
+
 # core types
 from pathway_tpu.internals import dtype as _dtype_mod
 from pathway_tpu.internals.dtype import DType

@@ -271,8 +271,8 @@ class _LoweredRun:
         self, env: Dict[str, np.ndarray], rows: int
     ) -> "Optional[Tuple[Dict[Tuple[int, str], np.ndarray], Optional[np.ndarray], int]]":
         """Execute on device; returns ``(outputs by (step, name), mask,
-        bucket)`` or None when ineligible (dtypes, import/compile failure) —
-        the caller falls back to the interpreter."""
+        bucket)`` or None when ineligible (dtypes, compile failure) — the
+        caller falls back to the interpreter."""
         if self.disabled:
             return None
         arrays = []
@@ -282,12 +282,8 @@ class _LoweredRun:
                 telemetry.stage_add("fuse.jit_dtype_fallbacks")
                 return None
             arrays.append(col)
-        try:
-            import jax  # noqa: F401
-            from jax.experimental import enable_x64
-        except Exception:
-            self.disabled = True
-            return None
+        from jax import enable_x64
+
         bucket = next_pow2(rows, _JIT_FLOOR)
         padded = []
         for col in arrays:

@@ -2753,6 +2753,10 @@ class GraphRunner:
                     ),
                 )
                 return
+        if env_cfg.processes > 1:
+            from pathway_tpu.parallel.mesh import require_cpu_platform
+
+            require_cpu_platform(f"rank {env_cfg.process_id} of {env_cfg.processes}")
         if persistence_config is None and env_cfg.replay_storage:
             # `pathway_tpu spawn --record` / `replay` contract (reference cli.py:166-284)
             from pathway_tpu import persistence as _pers

@@ -1,6 +1,6 @@
 """Lazy column-expression AST.
 
-Parity with the reference's ``python/pathway/internals/expression.py`` (expression node taxonomy)
+Parity with the reference's ``python/pathway/internals/expression.py`` (expression node kinds)
 and ``src/engine/expression.rs`` (typed op inventory). Expressions are built by operator
 overloading on column references, type-inferred statically, and compiled by the engine into
 vectorized column kernels — numeric subtrees lower to a single jit'd JAX function on TPU.

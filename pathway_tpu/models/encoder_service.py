@@ -62,6 +62,7 @@ semantic_hits/misses) and three log-bucketed histograms on ``/metrics``:
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
@@ -389,7 +390,12 @@ class EncoderService:
                 out.block_until_ready()
                 compiles += 1
         except Exception:
-            pass  # pre-warm is best-effort: a failed compile resurfaces on use
+            # pre-warm is best-effort (a failed compile resurfaces on use), but
+            # not silent: prewarm_compiles then falls short of the bucket count
+            logging.getLogger(__name__).exception(
+                "encoder pre-warm stopped after %d of %d buckets",
+                compiles, len(self._prewarm_shapes()),
+            )
         finally:
             elapsed = time.perf_counter() - t0
             with self._cond:

@@ -5,7 +5,8 @@ key fingerprinting, ``src/connectors/data_format.rs`` parsers). This package bui
 TPU-native counterparts from ``csrc/pathway_native.cc`` with g++ on first import (cached
 as a shared object next to this file) and exposes them behind the same contracts as the
 pure-Python fallbacks in ``internals/keys.py`` / ``io/fs.py``. When no toolchain is
-available everything degrades to the Python paths.
+available everything degrades to the Python paths; ``unavailable_reason()`` says why,
+so a caller that needs the native library (``chip_smoke.py``) can fail with the cause.
 """
 
 from __future__ import annotations
@@ -22,6 +23,13 @@ _SO = os.path.join(_HERE, "_pathway_native.so")
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_reason: Optional[str] = None  # why get_lib() returned None
+
+
+def unavailable_reason() -> Optional[str]:
+    """Why :func:`get_lib` returned None (missing source, xxhash header or g++,
+    a failed compile or load); None while the library is loaded or untried."""
+    return _reason
 
 
 def _xxhash_include_dir() -> Optional[str]:
@@ -43,13 +51,16 @@ def _xxhash_include_dir() -> Optional[str]:
 
 
 def _build(force: bool = False) -> Optional[str]:
+    global _reason
     src = os.path.abspath(_SRC)
     if not os.path.exists(src):
+        _reason = f"source not found: {src}"
         return None
     if not force and os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(src):
         return _SO
     include = _xxhash_include_dir()
     if include is None:
+        _reason = "xxhash.h not found (pyarrow's vendored tree, /usr/include, /usr/local/include)"
         return None
     import sysconfig
 
@@ -72,7 +83,9 @@ def _build(force: bool = False) -> Optional[str]:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _SO)
         return _SO
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as exc:
+        stderr = getattr(exc, "stderr", None) or b""
+        _reason = f"g++ build failed: {exc!r} {stderr[-400:].decode(errors='replace')}".strip()
         try:
             os.unlink(tmp)
         except OSError:
@@ -82,11 +95,12 @@ def _build(force: bool = False) -> Optional[str]:
 
 def get_lib() -> Optional[ctypes.CDLL]:
     """The native library, building it on first use; None when unavailable."""
-    global _lib, _tried
+    global _lib, _tried, _reason
     if _tried:
         return _lib
     _tried = True
     if os.environ.get("PATHWAY_TPU_DISABLE_NATIVE"):
+        _reason = "PATHWAY_TPU_DISABLE_NATIVE is set"
         return None
     path = _build()
     if path is None:
@@ -95,7 +109,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
         # PyDLL: calls keep the GIL — required for the pyobject column kind, which
         # walks PyObject* arrays with CPython C-API calls
         lib = ctypes.PyDLL(path)
-    except OSError:
+    except OSError as exc:
+        _reason = f"dlopen failed: {exc}"
         return None
     if not hasattr(lib, "pwtpu_hash_upsert"):
         # stale prebuilt .so from older source (mtime comparisons can lie across
@@ -113,7 +128,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
         try:
             shutil.copyfile(path, fresh)
             lib = ctypes.PyDLL(fresh)
-        except OSError:
+        except OSError as exc:
+            _reason = f"dlopen of the rebuilt library failed: {exc}"
             return None
         finally:
             try:
@@ -121,6 +137,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
             except OSError:
                 pass
         if not hasattr(lib, "pwtpu_hash_upsert"):
+            _reason = "rebuilt library lacks pwtpu_hash_upsert"
             return None
 
     u64p = ctypes.POINTER(ctypes.c_uint64)

@@ -772,7 +772,7 @@ class _RebuildResult:
         self.generation = generation
         self.centroids: Optional[np.ndarray] = None
         self.pages: Dict[int, _ClusterPages] = {}
-        self.where: Dict[int, tuple] = {}
+        self.where: Dict[int, int] = {}
         self.trained_sizes: Optional[np.ndarray] = None
         self.error: Optional[BaseException] = None
 
@@ -1485,12 +1485,12 @@ class TieredIvfKnnStore:
             cents, pages = _rebuild_split_pass(
                 cents, pages, self.dim, self._n_clusters_base, quant=self._qblocks
             )
-            where: Dict[int, tuple] = {}
+            where: Dict[int, int] = {}  # packed like self._where: (cid << 32) | pos
             trained = np.zeros(len(cents), dtype=np.int64)
             for cid, block in pages.items():
                 trained[cid] = block.n_live
                 for j in range(block.n):
-                    where[int(block.slots[j])] = (cid, j)
+                    where[int(block.slots[j])] = (cid << 32) | j
             result.centroids = cents
             result.pages = pages
             result.where = where
@@ -1550,9 +1550,9 @@ class TieredIvfKnnStore:
                 # removed post-snapshot: flip it dead in the new generation
                 loc = where.get(slot)
                 if loc is not None:
-                    block = new_tiers.pages.get(loc[0])
+                    block = new_tiers.pages.get(loc >> 32)
                     if block is not None:
-                        block.invalidate(loc[1])
+                        block.invalidate(loc & 0xFFFFFFFF)
                 continue
             if slot not in where:
                 dirty_adds.append(slot)

@@ -11,7 +11,7 @@ segment ids (one per touched group) and reduced with vectorized kernels:
   must not round-trip through float32, and tiny unit-test batches would lose to the
   host↔device transfer.
 
-The split mirrors the reference's semigroup-vs-recompute reducer taxonomy: these kernels
+This mirrors the reference's semigroup-vs-recompute reducer classes: these kernels
 serve the semigroup side (count/sum); recompute reducers keep per-group multisets.
 """
 

@@ -17,7 +17,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from pathway_tpu.parallel.mesh import shard_map_compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pathway_tpu.internals.keys import KEY_DTYPE, shard_of
@@ -97,9 +96,12 @@ def exchange_by_key(
         )
 
     spec_in = P(axis, *([None] * (values.ndim - 1)))
-    return shard_map_compat(
+    # check_vma off: the collectives below produce their outputs' replication
+    # by construction
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), spec_in),
         out_specs=(spec_in, P(axis)),
+        check_vma=False,
     )(key_lo, values)

@@ -17,8 +17,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from pathway_tpu.parallel.mesh import shard_map_compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pathway_tpu.parallel.exchange import bucket_rows
@@ -54,11 +52,12 @@ def _sharded_segment_sum_impl(
         )
         return lax.psum(local_sum, axis)
 
-    return shard_map_compat(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
         out_specs=P(),
+        check_vma=False,  # psum output is replicated by construction
     )(key_lo, seg_ids, values)
 
 

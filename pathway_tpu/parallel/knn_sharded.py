@@ -22,10 +22,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from pathway_tpu.parallel.mesh import shard_map_compat
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pathway_tpu.ops.knn import SlotIngestMixin, pad_pow2, pow2_target
+from pathway_tpu.ops.knn import (
+    SlotIngestMixin,
+    next_pow2,
+    pad_pow2,
+    pad_queries_pow2,
+    pow2_target,
+)
 
 
 def _local_search(
@@ -104,7 +109,9 @@ class ShardedKNNStore(SlotIngestMixin):
             donate_argnums=(0, 1, 2),
             out_shardings=(self._row_sharding, self._vec_sharding, self._vec_sharding),
         )
-        self._search = None  # built lazily (depends on k/metric statics)
+        # jitted shard_map search per k bucket, built on first use: a fresh
+        # jax.jit(shard_map(...)) per call would retrace and recompile per query
+        self._search: Dict[int, Any] = {}
 
     def __len__(self) -> int:
         return len(self.slot_of)
@@ -164,19 +171,25 @@ class ShardedKNNStore(SlotIngestMixin):
             queries = np.asarray(queries, dtype=np.float32).reshape(-1, self.dim)
         cap_local = self.capacity // self.n_shards
         k_eff = max(1, min(k, cap_local))
-        fn = shard_map_compat(
-            functools.partial(
-                _local_search, k=k_eff, metric=self.metric, axis=self.axis
-            ),
-            mesh=self.mesh,
-            in_specs=(P(self.axis, None), P(self.axis), P(self.axis), P()),
-            out_specs=(P(), P()),
-        )
-        top_scores, top_idx = jax.jit(fn)(
-            self._data, self._valid, self._norms, jnp.asarray(queries)
-        )
-        scores = np.asarray(top_scores)
-        idx = np.asarray(top_idx)
+        # the dense store's bucketing policy: pow2 query count (floor 8) and
+        # pow2 k bound the compiles at O(log) however ragged the traffic is
+        q_dev, nq = pad_queries_pow2(jnp.asarray(queries), self.dim)
+        k_pad = min(next_pow2(k_eff), cap_local)
+        fn = self._search.get(k_pad)
+        if fn is None:
+            fn = self._search[k_pad] = jax.jit(
+                jax.shard_map(
+                    functools.partial(
+                        _local_search, k=k_pad, metric=self.metric, axis=self.axis
+                    ),
+                    mesh=self.mesh,
+                    in_specs=(P(self.axis, None), P(self.axis), P(self.axis), P()),
+                    out_specs=(P(), P()),
+                    check_vma=False,  # all_gather output is replicated by construction
+                )
+            )
+        top_scores, top_idx = fn(self._data, self._valid, self._norms, q_dev)
+        scores, idx = jax.device_get((top_scores[:nq, :k_eff], top_idx[:nq, :k_eff]))
         return scores, idx, np.isfinite(scores)
 
 

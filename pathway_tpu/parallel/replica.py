@@ -1160,6 +1160,9 @@ def main() -> int:
             file=sys.stderr,
         )
         return 2
+    from pathway_tpu.parallel.mesh import require_cpu_platform
+
+    require_cpu_platform(f"replica {replica_id}")
     port = int(_env_float("PATHWAY_REPLICA_PORT", 0))
     supervise_dir = os.environ.get("PATHWAY_SUPERVISE_DIR")
     bootstrap_deadline = _env_float("PATHWAY_REPLICA_BOOTSTRAP_DEADLINE_S", 240.0)

@@ -36,7 +36,7 @@ class BaseRestServer:
 
         if retry_strategy is not None or cache_strategy is not None:
             # reference applies these to the endpoint's response path; engine-level UDF
-            # caching isn't wired yet (TODO.md) — configure the strategies on the LLM /
+            # caching isn't wired yet — configure the strategies on the LLM /
             # embedder UDFs instead, which does work
             warnings.warn(
                 "retry_strategy/cache_strategy on serve() are not applied yet; set them "
@@ -64,7 +64,7 @@ class BaseRestServer:
     ) -> Any:
         # with_cache/cache_backend configure UDF caching in the reference; here caching is
         # set per-UDF via cache_strategy (see internals/udfs), so they are accepted for
-        # API parity but have no engine-level effect yet (TODO.md).
+        # API parity but have no engine-level effect yet.
         def target() -> None:
             pw.run(monitoring_level=pw.MonitoringLevel.NONE, terminate_on_error=terminate_on_error)
 

@@ -1,0 +1,79 @@
+"""The device programs on the serving path must LOWER for a TPU — checked here on
+the CPU, in seconds, with ``jax.export`` and ``platforms=["tpu"]``.
+
+Lowering is the first gate only: it catches what the Pallas→Mosaic translation
+refuses (a block shape whose last two dims are neither multiples of (8, 128) nor
+the full dimension, an op with no TPU rule). Mosaic's own VMEM and tiling checks
+run when the chip compiles the program; ``chip_smoke.py`` is that check.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from pathway_tpu.ops.knn_ivf import PAGE
+
+S = jax.ShapeDtypeStruct
+
+
+def _export_tpu(fn, *args):
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
+    assert exported.platforms == ("tpu",)
+    return exported
+
+
+@pytest.mark.parametrize("packed_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("metric", ["cos", "l2sq"])
+def test_ivf_pallas_kernel_lowers_for_tpu(packed_dtype, metric):
+    """The shapes VectorStoreServer(index_factory="ivf") produces: d = 384,
+    queries padded to 8, 128-row pages, f32 and bf16 packed corpus."""
+    from pathway_tpu.ops.knn_ivf import _ivf_query_fused
+
+    d, q, n_clusters, n_pages = 384, 8, 64, 64
+    f32, i32 = jnp.float32, jnp.int32
+    fn = functools.partial(
+        _ivf_query_fused, k=8, n_probe=8, max_pages=2, metric=metric, impl="pallas"
+    )
+    exported = _export_tpu(
+        fn,
+        S((n_clusters, d), f32),  # centroids
+        S((n_clusters,), i32),  # first_page
+        S((n_clusters,), i32),  # n_pages
+        S((n_pages * PAGE, d), packed_dtype),  # packed
+        S((n_pages, PAGE), f32),  # pn
+        S((n_pages, PAGE), f32),  # pm
+        S((n_pages * PAGE,), i32),  # packed_rows
+        S((q, d), f32),  # queries
+    )
+    assert "tpu_custom_call" in exported.mlir_module()  # the Mosaic kernel is in it
+
+
+def test_dense_search_kernel_lowers_for_tpu():
+    from pathway_tpu.ops.knn import _search_kernel
+
+    cap, d, q = 4096, 384, 8
+    fn = functools.partial(_search_kernel, k=8, metric="cos")
+    _export_tpu(
+        fn,
+        S((cap, d), jnp.float32),
+        S((cap,), jnp.bool_),
+        S((cap,), jnp.float32),
+        S((q, d), jnp.float32),
+    )
+
+
+def test_encoder_forward_lowers_for_tpu():
+    """``_encode_ids`` with its float16 output cast and exact gelu, on the tiny
+    test architecture (the op set is the full-width model's)."""
+    from pathway_tpu.models.encoder import EncoderConfig, JaxSentenceEncoder
+
+    tiny = EncoderConfig(
+        vocab_size=8192, hidden_size=64, num_layers=2, num_heads=4, intermediate_size=128
+    )
+    enc = JaxSentenceEncoder("pw-test-tiny", config=tiny, max_length=64)
+    exported = _export_tpu(enc._encode_ids, enc.params, S((8, 16), jnp.int32))
+    assert exported.out_avals[0].shape == (8, 64)
