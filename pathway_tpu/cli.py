@@ -295,15 +295,30 @@ def trace(trace_id, limit, directory):
     via the heartbeat-estimated offsets each rank recorded at flush, spans
     are joined across REST, encoder, mesh exchange, and replicas, and each
     rendered trace ends with its critical-path one-liner ("commit 4812:
-    78% in rank 1 groupby; barrier held 41 ms by rank 3")."""
+    78% in rank 1 groupby; barrier held 41 ms by rank 3").
+
+    A DIRECTORY that a ``jax.profiler`` session wrote (it holds
+    ``plugins/profile/<time>/*.xplane.pb``) is read as a device trace
+    instead: for each ``pw.<kind>`` span the host had open, the seconds the
+    device sat idle under it."""
     import glob
 
     from pathway_tpu.engine.tracing import (
         critical_path,
+        find_profile,
+        format_idle_by_span,
         format_trace_tree,
+        idle_by_span,
+        load_profile_events,
         merge_trace_files,
     )
 
+    profile = find_profile(directory)
+    if profile is not None:
+        click.echo(f"profile {profile}")
+        for line in format_idle_by_span(idle_by_span(load_profile_events(profile))):
+            click.echo(line)
+        return
     paths = sorted(glob.glob(os.path.join(directory, "trace-rank-*.jsonl")))
     flights = sorted(glob.glob(os.path.join(directory, "flight-rank-*.json")))
     # replica processes flush into the replicas/ subdir of the supervise dir

@@ -1082,13 +1082,14 @@ class GraphRunner:
         pure function of ``(epoch, commit)``, so every rank's commit span is a
         sibling in ONE trace without anything riding the wire, and barrier /
         checkpoint spans opened below become its children via the
-        context-local parent. Queries admitted since the previous commit link
-        in (a query racing the boundary links the adjacent commit). Operator
-        child spans are synthesized AFTER the commit closes, and only for
-        sampled/promoted commits — nothing on the operator hot path.
+        context-local parent. REST queries whose rows this commit took link
+        in, and each gets its ``queue`` span: from its push to this commit's
+        start. Operator child spans are synthesized AFTER the commit closes,
+        and only for sampled/promoted commits — nothing on the operator hot
+        path.
         """
         tracer = _tracing.get_tracer()
-        if not tracer.enabled or self._materialize_all:
+        if not tracer.recording() or self._materialize_all:
             return self._step_inner()
         epoch = (
             getattr(self._cluster, "epoch", 0) if self._cluster is not None else 0
@@ -1096,27 +1097,61 @@ class GraphRunner:
         tracer.set_epoch(epoch)
         commit = self._commit
         ctx = _tracing.commit_trace_context(epoch, commit, self._rank)
-        links = tuple(tracer.take_commit_links())
         with tracer.trace_span(
             "commit",
             f"commit {commit}",
             self_ctx=ctx,
-            links=links,
             attrs={"commit": commit, "epoch": epoch},
         ) as span:
             any_output = self._step_inner()
+            if span is not None:
+                self._trace_commit_queries(tracer, span)
         if span is not None and span.sampled:
             self._trace_commit_ops(tracer, span)
         return any_output
+
+    def _trace_commit_queries(self, tracer: Any, span: Any) -> None:
+        """Link the REST queries whose rows this commit took (matched by row
+        key among its input deltas) and record, for each, how long the row
+        waited: a ``queue`` span from its push to the commit's start, a child
+        of the query's own ``rest`` span, naming the commit that took it."""
+        taken = tracer.take_commit_links(
+            keys[i : i + size]
+            for keys, size in (
+                (d.keys.tobytes(), d.keys.itemsize)
+                for d in self._input_deltas.values()
+            )
+            for i in range(0, len(keys), size)
+        )
+        span.attrs["queries"] = len(taken)
+        for query_ctx, pushed in taken:
+            span.add_link(query_ctx)
+            if not query_ctx.sampled:
+                continue
+            waited = max(0.0, span.ts_mono - pushed)
+            tracer.record_span(
+                "queue",
+                f"queue for commit {span.attrs['commit']}",
+                parent=query_ctx,
+                ts=span.ts - waited,
+                ts_mono=pushed,
+                duration_s=waited,
+                attrs={"commit": span.attrs["commit"]},
+            )
 
     def _trace_commit_ops(self, tracer: Any, span: Any) -> None:
         """Lift the commit profile's per-evaluator rows into child spans of
         the (sampled or slow-promoted) commit span. Start offsets partition
         the commit window cumulatively — durations are what the critical-path
-        walk consumes; only the slowest rows survive the cap."""
+        walk consumes; only the slowest rows survive the cap. A commit that
+        moved no row (the loop wakes and finds nothing: most commits of a
+        serving process) gets none: its rows are all noise, and at one per
+        operator they would push a traced span's requests out of the ring."""
         commit_profile = self._last_commit_profile
         self._last_commit_profile = None
         if commit_profile is None or not commit_profile.ops:
+            return
+        if not (commit_profile.input_rows or commit_profile.output_rows):
             return
         ops = commit_profile.ops
         if len(ops) > 48:
@@ -2911,7 +2946,11 @@ class GraphRunner:
                     if local_done:
                         break
                     if not any_output and not self.sources_finished():
-                        wake.wait(timeout=idle_wait)
+                        # nothing to do until a source pushes: its own span, so
+                        # that device idle under it reads "no work", not "the
+                        # host was busy with something unnamed"
+                        with _tracing.trace_span("loop_wait"):
+                            wake.wait(timeout=idle_wait)
         except BaseException as exc:
             # a failing run must be distinguishable from a clean close by sinks
             # that hand state to OTHER graphs (ExportedTable._fail) — finish()

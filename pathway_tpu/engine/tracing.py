@@ -23,11 +23,31 @@ Design points, in the order they matter:
   the root closes. Promotion is per-rank local by construction — a slow commit
   is slow on every rank that waited behind its barrier, so in practice all
   ranks promote the same trace.
-- **Zero hot-path operator spans.** ``GraphRunner`` does NOT wrap operators in
-  spans; per-operator / fused-region child spans are synthesized from the
+- **Recording is opt-in, two ways.** A span is recorded when the env gate
+  below is on, or while a ``jax.profiler`` session is on (observed through
+  ``TraceAnnotation.is_enabled()``: no switch of our own). Outside both,
+  ``trace_span``/``start`` return ``None`` after that one check and build
+  nothing. In a session every trace counts as sampled and every span goes to
+  the ring (sized to hold a whole traced span of serving), and every
+  *synchronous* span (``trace_span``) also opens a
+  ``jax.profiler.TraceAnnotation`` named ``pw.<kind>`` carrying its numeric
+  attributes, so that it lands on the ``/host:CPU`` plane of the same
+  ``.xplane.pb`` as the device planes: an idle gap on the device can then be
+  named by what the host was doing (``cli trace <profile dir>``). A span whose
+  life crosses an ``await`` or a thread (``start``/``finish``: the ``rest``
+  span, ``replica_serve``) is never an annotation: annotations on one thread
+  must nest, and requests interleave on the event-loop thread.
+- **Few hot-path spans.** ``GraphRunner`` does NOT wrap every operator in a
+  span; per-operator / fused-region child spans are synthesized from the
   already-collected :class:`~pathway_tpu.engine.profile.CommitProfile` ops at
-  commit end, and only for sampled/promoted commits. The <2% telemetry
-  overhead contract (``bench.py telemetry``) stays honest.
+  commit end, and only for sampled/promoted commits. Live spans sit only at
+  the layer boundaries of the serving path (``admit``, ``embed_wait``,
+  ``search`` and its parts, the encoder tick's parts, ``reply``); each costs
+  one ``recording()`` check when nothing records.
+- **A wait for the device is a kind of its own.** Every place on the serving
+  path where the host blocks on the device is a span whose kind ends in
+  ``.device_wait``: device idle under it reads "host waiting for the chip",
+  idle under anything else reads "chip waiting for the host".
 - **Crash-safe flush.** The ring flushes to ``trace-rank-N.jsonl`` on finish
   AND alongside every flight-recorder dump (crash, fence, SIGTERM, chaos
   kill) via :func:`pathway_tpu.engine.profile.register_trace_hooks` — a
@@ -40,10 +60,11 @@ transition are model-checked (``internals/protocol_models.trace_ring_model``):
 no span orphaned by an epoch bump, flush-on-crash never deadlocks the dying
 rank, sampling decision consistent across a trace.
 
-Env knobs: ``PATHWAY_TRACE=off`` disables span recording (header echo stays);
+Env knobs: ``PATHWAY_TRACE=on`` turns span recording on (default off; the
+header echo works either way; a ``jax.profiler`` session turns it on too);
 ``PATHWAY_TRACE_SAMPLE`` is the head-sampling probability (default 0.01);
 ``PATHWAY_TRACE_SLOW_MS`` always-samples roots slower than this (default 250);
-``PATHWAY_TRACE_RING`` sizes the span ring (default 4096);
+``PATHWAY_TRACE_RING`` sizes the span ring (default 65536);
 ``PATHWAY_TRACE_DIR`` overrides the flush directory (default: the flight
 recorder's dump dir).
 """
@@ -58,7 +79,9 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterable, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from pathway_tpu.engine import telemetry
 
@@ -68,6 +91,13 @@ from pathway_tpu.engine import telemetry
 TRACE_HEADER = "X-Pathway-Trace"
 
 _ID_HEX = 16  # 64-bit ids, rendered as 16 hex chars
+
+# the ring holds a whole traced span of serving: a 4 s profiler session at 200
+# requests/s ends about 4 spans a request, 12 a commit and its encoder tick,
+# and up to 48 synthesized operator rows a commit, some 30,000 in all
+_RING_SPANS = 65536
+# what trace_span hands out while nothing records: yields None, builds nothing
+_NO_SPAN = contextlib.nullcontext()
 
 # pending (unsampled, promotion-eligible) buffer bounds: per-trace and total
 _MAX_PENDING_TRACES = 64
@@ -252,7 +282,9 @@ class Tracer:
         self.rank = 0
         self.epoch = 0
         self._default_dir: Optional[str] = None
-        self._ring: "collections.deque[Span]" = collections.deque(maxlen=4096)
+        self._ring: "collections.deque[Span]" = collections.deque(
+            maxlen=_RING_SPANS
+        )
         # trace_id -> finished-but-unsampled spans awaiting the root's verdict
         self._pending: "collections.OrderedDict[str, List[Span]]" = (
             collections.OrderedDict()
@@ -262,8 +294,12 @@ class Tracer:
         self._query_links: "collections.OrderedDict[str, List[TraceContext]]" = (
             collections.OrderedDict()
         )
-        # contexts admitted since the last commit (drained by the commit span)
-        self._commit_links: List[TraceContext] = []
+        # row key -> (context, push instant) of REST queries pushed into the
+        # engine and not yet taken by a commit (drained by the commit that
+        # finds the key among its input rows)
+        self._commit_links: "collections.OrderedDict[bytes, Tuple[TraceContext, float]]" = (
+            collections.OrderedDict()
+        )
         self._offsets: Dict[int, float] = {}
         self.flushes = 0
         self.refresh()
@@ -288,9 +324,9 @@ class Tracer:
             slow_ms = float(env.get("PATHWAY_TRACE_SLOW_MS", "250"))
         except ValueError:
             pass
-        ring = 4096
+        ring = _RING_SPANS
         try:
-            ring = max(64, int(env.get("PATHWAY_TRACE_RING", "4096")))
+            ring = max(64, int(env.get("PATHWAY_TRACE_RING", "")))
         except ValueError:
             pass
         with self._lock:
@@ -323,6 +359,11 @@ class Tracer:
         with self._lock:
             self._offsets = dict(offsets)
 
+    def recording(self) -> bool:
+        """Whether a span opened now is kept: the env gate is on, or a
+        ``jax.profiler`` session is (observed, not configured)."""
+        return self.enabled or TraceAnnotation.is_enabled()
+
     # -- span lifecycle -------------------------------------------------------
 
     def start(
@@ -337,9 +378,13 @@ class Tracer:
     ) -> Optional[Span]:
         """Open a span. ``ctx`` parents it explicitly (falls back to the
         context-local current span); ``self_ctx`` instead assigns the span's
-        OWN identity (deterministic commit spans). Returns None when tracing
-        is off — callers must tolerate that."""
-        if not self.enabled:
+        OWN identity (deterministic commit spans). Returns None when nothing
+        records — callers must tolerate that. A span opened while a profiler
+        session is on counts as sampled. This is the API for a span whose life
+        crosses an ``await`` or a thread (close it with :meth:`finish`); it
+        never opens a profiler annotation."""
+        session = TraceAnnotation.is_enabled()
+        if not (self.enabled or session):
             return None
         parent = ctx if ctx is not None else current_context()
         if self_ctx is not None:
@@ -385,6 +430,8 @@ class Tracer:
                 links=links,
                 attrs=attrs,
             )
+        if session:
+            span.sampled = True
         return span
 
     def finish(self, span: Span) -> None:
@@ -426,7 +473,6 @@ class Tracer:
             if len(bucket) < _MAX_PENDING_SPANS:
                 bucket.append(span)
 
-    @contextlib.contextmanager
     def trace_span(
         self,
         kind: str,
@@ -436,19 +482,47 @@ class Tracer:
         self_ctx: Optional[TraceContext] = None,
         links: Tuple[TraceContext, ...] = (),
         attrs: Optional[Dict[str, Any]] = None,
+    ) -> "contextlib.AbstractContextManager[Optional[Span]]":
+        """The one span-recording API for SYNCHRONOUS work: opened and closed
+        on one thread with no ``await`` between (PWA205 lints literal ``kind``
+        args against ``telemetry.TRACE_SPAN_KINDS``). Yields the open span (or
+        None when nothing records) and installs it as the context-local
+        parent. While a profiler session is on the span is also a
+        ``TraceAnnotation`` named ``pw.<kind>`` on the calling thread's line
+        of the ``/host:CPU`` plane, with its numeric attributes as stats."""
+        if not self.recording():
+            return _NO_SPAN
+        return self._open_span(kind, name, ctx, self_ctx, links, attrs)
+
+    @contextlib.contextmanager
+    def _open_span(
+        self,
+        kind: str,
+        name: Optional[str],
+        ctx: Optional[TraceContext],
+        self_ctx: Optional[TraceContext],
+        links: Tuple[TraceContext, ...],
+        attrs: Optional[Dict[str, Any]],
     ) -> Iterator[Optional[Span]]:
-        """The one span-recording API (PWA205 lints literal ``kind`` args
-        against ``telemetry.TRACE_SPAN_KINDS``). Yields the open span (or None
-        when tracing is off) and installs it as the context-local parent."""
         span = self.start(
             kind, name, ctx=ctx, self_ctx=self_ctx, links=links, attrs=attrs
         )
-        if span is None:
+        if span is None:  # the session ended between the two checks
             yield None
             return
+        annotation: Any = _NO_SPAN
+        if TraceAnnotation.is_enabled():
+            annotation = TraceAnnotation(
+                "pw." + kind,
+                **{
+                    k: v for k, v in span.attrs.items()
+                    if isinstance(v, (int, float))
+                },
+            )
         token = _current_span.set(span)
         try:
-            yield span
+            with annotation:
+                yield span
         finally:
             _current_span.reset(token)
             self.finish(span)
@@ -467,8 +541,9 @@ class Tracer:
     ) -> None:
         """Synthesize an already-finished child span (operator / fused-region
         rows lifted from a CommitProfile at commit end — nothing on the
-        operator hot path). Only call for sampled/promoted parents."""
-        if not self.enabled:
+        operator hot path; the wait of a query row before its commit). Only
+        call for sampled/promoted parents."""
+        if not self.recording():
             return
         span = Span(
             trace_id=parent.trace_id,
@@ -495,7 +570,7 @@ class Tracer:
     def register_query_link(self, key: str, ctx: TraceContext) -> None:
         """A REST query span waiting on ``key`` (the query text): the encoder
         tick that batches the text drains these into its span's links."""
-        if not self.enabled:
+        if not self.recording():
             return
         with self._lock:
             bucket = self._query_links.get(key)
@@ -507,7 +582,7 @@ class Tracer:
                 bucket.append(ctx)
 
     def take_query_links(self, keys: List[str]) -> List[TraceContext]:
-        if not self.enabled:
+        if not self.recording():
             return []
         out: List[TraceContext] = []
         with self._lock:
@@ -515,20 +590,31 @@ class Tracer:
                 out.extend(self._query_links.pop(key, ()))
         return out
 
-    def register_commit_link(self, ctx: TraceContext) -> None:
-        """A query admitted since the last commit: the next commit span links
-        it (a query racing the boundary links the adjacent commit)."""
-        if not self.enabled:
+    def register_commit_link(self, key: bytes, ctx: TraceContext) -> None:
+        """A REST query about to be pushed into the engine under row key
+        ``key``: the commit that takes that row links the query and records
+        its ``queue`` span from this instant (call right before the push)."""
+        if not self.recording():
             return
         with self._lock:
-            if len(self._commit_links) < _MAX_LINKS_PER_KEY:
-                self._commit_links.append(ctx)
+            while len(self._commit_links) >= _MAX_LINK_KEYS:
+                self._commit_links.popitem(last=False)
+            self._commit_links[key] = (ctx, time.monotonic())
 
-    def take_commit_links(self) -> List[TraceContext]:
-        if not self.enabled:
-            return []
+    def take_commit_links(
+        self, keys: Iterable[bytes]
+    ) -> List[Tuple[TraceContext, float]]:
+        """(context, push instant) of every registered query whose row key is
+        among ``keys`` (a commit's input rows; not read while nothing is
+        registered)."""
+        out: List[Tuple[TraceContext, float]] = []
         with self._lock:
-            out, self._commit_links = self._commit_links, []
+            if not self._commit_links:
+                return out
+            for key in keys:
+                link = self._commit_links.pop(key, None)
+                if link is not None:
+                    out.append(link)
         return out
 
     # -- flush / dump ---------------------------------------------------------
@@ -595,7 +681,7 @@ class Tracer:
             self._ring.clear()
             self._pending.clear()
             self._query_links.clear()
-            self._commit_links = []
+            self._commit_links.clear()
             self._offsets = {}
             self.flushes = 0
         self.refresh()
@@ -857,3 +943,177 @@ def critical_path_line(directory: str) -> Optional[str]:
         return None
     result = critical_path(merged)
     return result["line"] if result else None
+
+
+# -- device idle by host span (a jax.profiler trace) ---------------------------
+
+#: one profiler event: (plane, line, name, start_ns, duration_ns)
+ProfileEvent = Tuple[str, str, str, int, int]
+
+_ANNOTATION_PREFIX = "pw."
+_HOST_PLANE = "/host:CPU"
+_DEVICE_PLANE_PREFIX = "/device:"
+
+
+def find_profile(directory: str) -> Optional[str]:
+    """The newest ``.xplane.pb`` a ``jax.profiler`` session left under
+    ``directory`` (its ``plugins/profile/<time>/``), or None."""
+    import glob as _glob
+
+    paths = sorted(
+        _glob.glob(
+            os.path.join(directory, "plugins", "profile", "*", "*.xplane.pb")
+        )
+    )
+    return paths[-1] if paths else None
+
+
+def load_profile_events(path: str) -> List[ProfileEvent]:
+    """The events ``idle_by_span`` reads from one ``.xplane.pb``: every event
+    of the device planes, and this module's annotations (``pw.<kind>``) of the
+    host plane. Nothing but ``jax.profiler.ProfileData``."""
+    from jax.profiler import ProfileData
+
+    events: List[ProfileEvent] = []
+    for plane in ProfileData.from_file(path).planes:
+        host = plane.name == _HOST_PLANE
+        if not host and not plane.name.startswith(_DEVICE_PLANE_PREFIX):
+            continue
+        for i, line in enumerate(plane.lines):
+            # host lines are threads, and Python threads share one line name
+            line_name = f"{line.name}#{i}" if host else line.name
+            for ev in line.events:
+                if host and not ev.name.startswith(_ANNOTATION_PREFIX):
+                    continue
+                events.append(
+                    (plane.name, line_name, ev.name, int(ev.start_ns),
+                     int(ev.duration_ns))
+                )
+    return events
+
+
+def _innermost_segments(
+    annotations: List[Tuple[int, int, str]]
+) -> List[Tuple[int, int, str]]:
+    """Cut the timeline where the innermost open annotation changes:
+    ``(start, end, name)`` pieces, disjoint and sorted. Across threads the
+    innermost is the annotation opened last (what the host turned to most
+    recently); where none is open there is no piece."""
+    import heapq
+
+    points = sorted({t for a in annotations for t in a[:2]})
+    by_start = sorted(annotations)
+    open_heap: List[Tuple[int, int, str]] = []  # (-start, end, name)
+    segments: List[Tuple[int, int, str]] = []
+    nxt = 0
+    for lo, hi in zip(points, points[1:]):
+        while nxt < len(by_start) and by_start[nxt][0] <= lo:
+            start, end, name = by_start[nxt]
+            heapq.heappush(open_heap, (-start, end, name))
+            nxt += 1
+        while open_heap and open_heap[0][1] <= lo:
+            heapq.heappop(open_heap)
+        if open_heap:
+            name = open_heap[0][2]
+            if segments and segments[-1][2] == name and segments[-1][1] == lo:
+                segments[-1] = (segments[-1][0], hi, name)
+            else:
+                segments.append((lo, hi, name))
+    return segments
+
+
+def idle_by_span(events: List[ProfileEvent]) -> Dict[str, Any]:
+    """Where the device's idle time fell, by what the host was doing.
+
+    Per device plane, busy is the union of its ``XLA Ops`` events (``XLA
+    Modules`` where a plane has no op line) and an idle gap is the time
+    between two busy intervals. Every instant of a gap goes to the innermost
+    ``pw.<kind>`` annotation open on the host at that instant (``none`` where
+    no annotation was open). Returns ``{"idle_s", "planes", "kinds": {kind:
+    {"idle_s", "open_s"}}}``: idle seconds under each kind, averaged over the
+    device planes, beside the seconds that kind was the innermost one. A kind
+    ending in ``.device_wait`` is the host waiting for the chip; idle under
+    any other kind, or under none, is the chip waiting for the host."""
+    annotations = [
+        (start, start + dur, name[len(_ANNOTATION_PREFIX):])
+        for plane, _line, name, start, dur in events
+        if plane == _HOST_PLANE and name.startswith(_ANNOTATION_PREFIX)
+        and dur > 0
+    ]
+    segments = _innermost_segments(annotations)
+    kinds: Dict[str, Dict[str, float]] = {}
+    for lo, hi, name in segments:
+        row = kinds.setdefault(name, {"idle_s": 0.0, "open_s": 0.0})
+        row["open_s"] += (hi - lo) / 1e9
+    planes = sorted(
+        {e[0] for e in events if e[0].startswith(_DEVICE_PLANE_PREFIX)}
+    )
+    idle_total = 0.0
+    for plane in planes:
+        mine = [e for e in events if e[0] == plane]
+        busy = [e for e in mine if e[1] == "XLA Ops"] or [
+            e for e in mine if e[1] == "XLA Modules"
+        ]
+        covered: List[List[int]] = []
+        for start, end in sorted((e[3], e[3] + e[4]) for e in busy):
+            if covered and start <= covered[-1][1]:
+                covered[-1][1] = max(covered[-1][1], end)
+            else:
+                covered.append([start, end])
+        seg = 0
+        for (_, gap_lo), (gap_hi, _) in zip(covered, covered[1:]):
+            idle_total += (gap_hi - gap_lo) / 1e9
+            while seg < len(segments) and segments[seg][1] <= gap_lo:
+                seg += 1
+            at, i = gap_lo, seg
+            while at < gap_hi:
+                if i < len(segments) and segments[i][0] <= at:
+                    upto, name = min(segments[i][1], gap_hi), segments[i][2]
+                    i += 1
+                else:
+                    upto = min(segments[i][0], gap_hi) if i < len(segments) else gap_hi
+                    name = "none"
+                row = kinds.setdefault(name, {"idle_s": 0.0, "open_s": 0.0})
+                row["idle_s"] += (upto - at) / 1e9
+                at = upto
+    n = max(len(planes), 1)
+    for row in kinds.values():
+        row["idle_s"] /= n
+    return {"idle_s": idle_total / n, "planes": len(planes), "kinds": kinds}
+
+
+def format_idle_by_span(result: Dict[str, Any]) -> List[str]:
+    """The table ``cli trace`` prints for a profiler directory."""
+    idle = result["idle_s"]
+    lines = [
+        f"device idle between operations: {idle:.4f} s over "
+        f"{result['planes']} device plane(s), by the innermost pw.<kind> open "
+        "on the host",
+        f"{'kind':<26}{'idle_s':>10}{'of idle':>9}{'open_s':>10}{'idle while open':>17}",
+    ]
+    named = 0.0
+    for kind, row in sorted(
+        result["kinds"].items(), key=lambda kv: -kv[1]["idle_s"]
+    ):
+        share = 100.0 * row["idle_s"] / idle if idle else 0.0
+        within = (
+            f"{100.0 * row['idle_s'] / row['open_s']:.1f} %"
+            if row["open_s"] else "-"
+        )
+        if kind != "none":
+            named += row["idle_s"]
+        lines.append(
+            f"{kind:<26}{row['idle_s']:>10.4f}{share:>8.1f}%"
+            f"{row['open_s']:>10.4f}{within:>17}"
+        )
+    waits = sum(
+        row["idle_s"] for kind, row in result["kinds"].items()
+        if kind.endswith(".device_wait")
+    )
+    if idle:
+        lines.append(
+            f"named: {100.0 * named / idle:.1f} % of the idle seconds; host "
+            f"waiting for the chip (*.device_wait): {100.0 * waits / idle:.1f} %, "
+            f"chip waiting for the host: {100.0 * (idle - waits) / idle:.1f} %"
+        )
+    return lines

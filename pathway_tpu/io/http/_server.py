@@ -298,17 +298,27 @@ class RestServerSubject:
             # the route's "rest" span: parented by the client's X-Pathway-Trace
             # context (or a fresh root), covering admission -> engine commit ->
             # future resolution, and echoed back with OUR span id so the
-            # client can look the request up in the merged trace
+            # client can look the request up in the merged trace. It lives
+            # across awaits on the event-loop thread, where requests
+            # interleave, so it is opened with start/finish and is never a
+            # profiler annotation; its synchronous children (admit, reply) are
             parent_ctx = tracing.parse_trace_header(
                 request.headers.get(tracing.TRACE_HEADER)
             )
-            with tracing.trace_span(
+            tracer = tracing.get_tracer()
+            span = tracer.start(
                 "rest",
                 f"{request.method} {self.route}",
                 ctx=parent_ctx,
                 attrs={"route": self.route},
-            ) as span:
-                response = await _handle(request, span)
+            )
+            try:
+                body = (
+                    await request.text()
+                    if request.method in ("POST", "PUT", "PATCH")
+                    else None
+                )
+                response = await _handle(request, body, span)
                 if span is not None:
                     span.attrs["status"] = response.status
                 echo_ctx = (
@@ -319,14 +329,52 @@ class RestServerSubject:
                 response.headers[tracing.TRACE_HEADER] = (
                     tracing.format_trace_header(echo_ctx)
                 )
+            finally:
+                if span is not None:
+                    tracer.finish(span)
             return response
 
-        async def _handle(request: Any, span: Any) -> Any:
+        async def _handle(request: Any, body: "str | None", span: Any) -> Any:
+            span_ctx = span.context() if span is not None else None
+            with tracing.trace_span("admit", ctx=span_ctx):
+                admitted = _admit(request, body, span_ctx)
+            if not isinstance(admitted, tuple):
+                return admitted  # refused: the response says why
             import aiohttp.web as web
 
-            if request.method in ("POST", "PUT", "PATCH"):
+            kb, key, row, future, t0 = admitted
+            try:
+                result = await future
+                with tracing.trace_span("reply", ctx=span_ctx):
+                    # the serving-path latency histogram (/metrics exports it
+                    # next to commit duration): push -> engine commit ->
+                    # future resolution
+                    _histogram("pathway_rest_latency_seconds").observe(
+                        time.perf_counter() - t0
+                    )
+                    if isinstance(result, Json):
+                        result = result.value
+                    response = web.json_response(result)
+            finally:
+                # a cancelled handler (client disconnect/timeout) must release
+                # its admission slot and retract its query row — under the
+                # max_pending check a leaked slot is a permanent 429 wedge,
+                # not just a memory leak
+                self.futures.pop(kb, None)
+                if self.delete_completed_queries:
+                    source.push(row, key=key, diff=-1)
+            return response
+
+        def _admit(request: Any, body: "str | None", span_ctx: Any) -> Any:
+            """Parse, validate, admission check, push: everything between the
+            request's body and the row's entry into the engine, with no await.
+            Returns the refusal's response, or what the wait for the reply
+            needs."""
+            import aiohttp.web as web
+
+            if body is not None:
                 try:
-                    payload = await request.json()
+                    payload = json.loads(body)
                 except json.JSONDecodeError:
                     payload = {}
             else:
@@ -435,42 +483,23 @@ class RestServerSubject:
                 if col.dtype.strip_optional() == dt.JSON and v is not None and not isinstance(v, Json):
                     v = Json(v)
                 row[name] = v
-            if span is not None:
-                # causal handoff into the engine: the NEXT commit links this
-                # query (take_commit_links in GraphRunner.step), and the
-                # encoder tick that batches the query text links it too
-                # (take_query_links keyed by text) — a coalesced batch ends
-                # up linking all N parent query spans
+            if span_ctx is not None:
+                # causal handoff into the engine: the commit that takes this
+                # row links the query and records its queue wait from here
+                # (take_commit_links in GraphRunner.step, keyed by row key),
+                # and the encoder tick that batches the query text links it
+                # too (take_query_links keyed by text) — a coalesced batch
+                # ends up linking all N parent query spans
                 tracer = tracing.get_tracer()
-                span_ctx = span.context()
-                tracer.register_commit_link(span_ctx)
                 for field in ("query", "text", "prompt"):
                     text = row.get(field)
                     if isinstance(text, str) and text:
                         tracer.register_query_link(text, span_ctx)
                         break
+                tracer.register_commit_link(kb, span_ctx)
             t0 = time.perf_counter()
             source.push(row, key=key, diff=1)
-            try:
-                result = await future
-                # the serving-path latency histogram (/metrics exports it next
-                # to commit duration): push -> engine commit -> future resolution
-                _histogram("pathway_rest_latency_seconds").observe(
-                    time.perf_counter() - t0
-                )
-            finally:
-                # a cancelled handler (client disconnect/timeout) must release
-                # its admission slot and retract its query row — under the
-                # max_pending check a leaked slot is a permanent 429 wedge,
-                # not just a memory leak
-                self.futures.pop(kb, None)
-                if self.delete_completed_queries:
-                    source.push(row, key=key, diff=-1)
-            if isinstance(result, (dict, list)):
-                return web.json_response(result)
-            if isinstance(result, Json):
-                return web.json_response(result.value)
-            return web.json_response(result)
+            return kb, key, row, future, t0
 
         self.webserver._register(self.route, self.methods, handler)
         # block forever: the server lives until the process exits

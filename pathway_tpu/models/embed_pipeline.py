@@ -612,7 +612,12 @@ class EmbedPipeline:
         hash first, then the semantic query cache) return host rows; misses
         ride the encoder service's continuous batch (or the legacy coalescer)
         and return DEVICE-resident jax slices (the downstream KNN kernel
-        consumes either without an extra round trip)."""
+        consumes either without an extra round trip). The whole call is the
+        commit thread's ``embed_wait`` span: until the service hands rows back."""
+        with tracing.trace_span("embed_wait", attrs={"rows": len(texts)}):
+            return self._embed_query_rows(texts)
+
+    def _embed_query_rows(self, texts: List[str]) -> List[Any]:
         rows: List[Any] = [None] * len(texts)
         miss_idx: List[int] = []
         sem_hits = 0
@@ -664,10 +669,13 @@ class EmbedPipeline:
             return
         import jax.numpy as jnp
 
-        host = np.asarray(jnp.stack(list(rows[: len(texts)])), dtype=np.float32)
-        for t, v in zip(texts, host):
-            self.cache.put(t, v)
-            self.semantic_cache.put(t, v)
+        with tracing.trace_span("cache_fill", attrs={"rows": len(texts)}):
+            stacked = jnp.stack(list(rows[: len(texts)]))
+            with tracing.trace_span("cache_fill.device_wait"):
+                host = np.asarray(stacked, dtype=np.float32)
+            for t, v in zip(texts, host):
+                self.cache.put(t, v)
+                self.semantic_cache.put(t, v)
 
     def _stage_cache_counts(self, hits: int, misses: int) -> None:
         """ONE batch-level telemetry add per counter per commit (the telemetry

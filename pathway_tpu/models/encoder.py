@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import threading
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -24,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from pathway_tpu.engine import tracing
 from pathway_tpu.internals.shapes import next_pow2
 
 
@@ -319,23 +321,23 @@ class JaxSentenceEncoder:
         # half a code step); geometry served from cache must key on this mode
         self.quant_encode = quant_encode_enabled()
         self.quant_tag = "quant:int8" if self.quant_encode else ""
-        if self.quant_encode:
-            def _fwd(params: Any, ids: jax.Array) -> jax.Array:
-                out = self.model.apply(
-                    params, ids, (ids != 0).astype(jnp.int32)
-                ).astype(jnp.float32)
+
+        # a named function: the device trace shows the program as
+        # ``jit_encoder_forward`` (a lambda shows as ``jit__lambda_``)
+        def encoder_forward(params: Any, ids: jax.Array) -> jax.Array:
+            with jax.named_scope("encoder_forward"):
+                out = self.model.apply(params, ids, (ids != 0).astype(jnp.int32))
+            if self.quant_encode:
+                out = out.astype(jnp.float32)
                 s = jnp.maximum(
                     jnp.max(jnp.abs(out), axis=1, keepdims=True), 1e-30
                 ) / 127.0
-                return (jnp.round(out / s) * s).astype(out_dtype)
+                out = jnp.round(out / s) * s
+            return out.astype(out_dtype)
 
-            self._encode_ids = jax.jit(_fwd)
-        else:
-            self._encode_ids = jax.jit(
-                lambda params, ids: self.model.apply(
-                    params, ids, (ids != 0).astype(jnp.int32)
-                ).astype(out_dtype)
-            )
+        self._encode_ids = jax.jit(encoder_forward)
+        # (real, padded) tokens each calling thread has sent to the device
+        self._dispatched = threading.local()
 
     def _hf_tokenize(self, tok: Any, texts: list[str]) -> Tuple[np.ndarray, np.ndarray]:
         out = tok(
@@ -375,7 +377,8 @@ class JaxSentenceEncoder:
         fetch blocks)."""
         if not texts:
             return jnp.zeros((0, self.config.hidden_size), dtype=jnp.float32)
-        ids, mask = self._tokenize(texts)
+        with tracing.trace_span("tokenize", attrs={"rows": len(texts)}):
+            ids, mask = self._tokenize(texts)
         out = self._dispatch(ids, mask)
         return out[: ids.shape[0]]
 
@@ -388,7 +391,17 @@ class JaxSentenceEncoder:
         batch = _next_pow2(ids.shape[0])
         ids_p = np.zeros((batch, seq), dtype=np.int32)
         ids_p[: ids.shape[0], : ids.shape[1]] = ids * mask  # padding -> id 0
+        real, padded = self.dispatched_tokens()
+        self._dispatched.counts = (real + int(mask.sum()), padded + batch * seq)
         return self._encode_ids(self.params, jnp.asarray(ids_p))
+
+    def dispatched_tokens(self) -> Tuple[int, int]:
+        """(real tokens, padded tokens) the CALLING thread has sent to the
+        device so far: tokens under the attention mask, and batch bucket x
+        sequence bucket. Per thread, so that a caller reads the delta around
+        its own dispatches whatever other threads encode meanwhile (the
+        encoder service counts its ticks' tokens this way)."""
+        return getattr(self._dispatched, "counts", (0, 0))
 
     def encode(self, texts: list[str]) -> np.ndarray:
         if not texts:

@@ -325,6 +325,10 @@ class EncoderService:
         self.total_rows = 0
         self.batches = 0
         self.dedup_rows = 0
+        # tokens under the attention mask, and batch bucket x sequence bucket,
+        # of what the ticks sent to the device (the fill is their ratio)
+        self.real_tokens = 0
+        self.padded_tokens = 0
         self.max_tick_rows = 0
         self.shed_requests = 0
         # pre-warm state (abort via its own event: stop_worker must be able to
@@ -559,19 +563,20 @@ class EncoderService:
         bucket, dispatched async) so a ragged burst doesn't pay the longest
         row's padding on every short query. Returns (rows, dispatches)."""
         n = len(texts)
-        if n <= self.sub_batch:
-            dev = self.encoder.encode_device(texts)
-            return [dev[i] for i in range(n)], 1
-        order = sorted(range(n), key=lambda i: len(str(texts[i]).split()))
-        rows: List[Any] = [None] * n
-        dispatches = 0
-        for start in range(0, n, self.sub_batch):
-            idx = order[start : start + self.sub_batch]
-            dev = self.encoder.encode_device([texts[i] for i in idx])
-            for j, i in enumerate(idx):
-                rows[i] = dev[j]
-            dispatches += 1
-        return rows, dispatches
+        with _tracing.trace_span("encode.dispatch", attrs={"rows": n}):
+            if n <= self.sub_batch:
+                dev = self.encoder.encode_device(texts)
+                return [dev[i] for i in range(n)], 1
+            order = sorted(range(n), key=lambda i: len(str(texts[i]).split()))
+            rows: List[Any] = [None] * n
+            dispatches = 0
+            for start in range(0, n, self.sub_batch):
+                idx = order[start : start + self.sub_batch]
+                dev = self.encoder.encode_device([texts[i] for i in idx])
+                for j, i in enumerate(idx):
+                    rows[i] = dev[j]
+                dispatches += 1
+            return rows, dispatches
 
     def _run(self) -> None:
         from pathway_tpu.engine.profile import histogram
@@ -579,6 +584,9 @@ class EncoderService:
         depth_hist = histogram("pathway_encsvc_queue_depth_rows")
         occ_hist = histogram("pathway_encsvc_tick_occupancy")
         tick_hist = histogram("pathway_encsvc_tick_seconds")
+        # tokens this thread has sent to the device so far, read around each
+        # tick (an encoder that does not count them leaves both at 0)
+        sent = getattr(self.encoder, "dispatched_tokens", lambda: (0, 0))
         while True:
             batch, depth = self._gather()
             if not batch:
@@ -610,75 +618,73 @@ class EncoderService:
             # tick span samples whenever ANY linked query's trace is sampled
             # (the batch is shared work — every sampled parent needs it)
             tracer = _tracing.get_tracer()
-            trace_links = (
-                tuple(tracer.take_query_links(unique)) if tracer.enabled else ()
-            )
-            enc_span = None
-            if trace_links:
-                enc_span = tracer.start(
-                    "encode",
-                    f"encode tick {len(unique)}",
-                    links=trace_links,
-                )
+            trace_links = tuple(tracer.take_query_links(unique))
+            real0, padded0 = sent()
+            # the tick's span: encode, hand every waiter its rows, count,
+            # fill the cache (None while nothing records)
+            with tracer.trace_span(
+                "encode",
+                f"encode tick {len(unique)}",
+                links=trace_links,
+                attrs={"rows": n_rows, "unique": len(unique)},
+            ) as enc_span:
                 if enc_span is not None and any(l.sampled for l in trace_links):
                     enc_span.sampled = True
-            try:
-                t_enc = time.monotonic()
-                with telemetry.stage_timer("embed.svc.encode"):
-                    out, dispatches = self._encode_packed(unique)
-                enc_s = time.monotonic() - t_enc
-                self._encode_ewma_s = (
-                    0.8 * self._encode_ewma_s + 0.2 * enc_s
-                    if self._encode_ewma_s
-                    else enc_s
-                )
-                rows = [out[j] for j in slot_of]
-                if enc_span is not None:
-                    enc_span.attrs.update(
-                        {"rows": n_rows, "unique": len(unique),
-                         "dispatches": dispatches}
-                    )
-                    tracer.finish(enc_span)
-            except BaseException as exc:  # propagate to every waiter in the tick
-                if enc_span is not None:
-                    enc_span.attrs["error"] = type(exc).__name__
-                    tracer.finish(enc_span)
-                self._release_inflight(n_rows)
-                for sub in batch:
-                    sub.error = exc
-                    sub.event.set()
-                continue
-            with self._cond:
-                self.ticks += 1
-                self.total_rows += n_rows
-                self.batches += dispatches
-                self.dedup_rows += n_rows - len(unique)
-                self.max_tick_rows = max(self.max_tick_rows, n_rows)
-                self._inflight_rows -= n_rows
-                self._cond.notify_all()
-            pos = 0
-            for sub in batch:
-                sub.rows = rows[pos : pos + len(sub.texts)]
-                pos += len(sub.texts)
-                sub.event.set()
-            # telemetry AFTER responders are released: stage counters and
-            # histograms are off the request latency path
-            telemetry.stage_add_many(
-                {
-                    "embed.svc.ticks": 1.0,
-                    "embed.svc.rows": float(n_rows),
-                    "embed.svc.batches": float(dispatches),
-                    "embed.svc.dedup_rows": float(n_rows - len(unique)),
-                }
-            )
-            depth_hist.observe(float(depth))
-            occ_hist.observe(n_rows / self.max_in_flight)
-            tick_hist.observe(time.perf_counter() - t_tick)
-            if self._after_batch is not None:
                 try:
-                    self._after_batch(unique, out)
-                except Exception:
-                    pass  # cache fill is best-effort; responders already released
+                    t_enc = time.monotonic()
+                    with telemetry.stage_timer("embed.svc.encode"):
+                        out, dispatches = self._encode_packed(unique)
+                    enc_s = time.monotonic() - t_enc
+                    self._encode_ewma_s = (
+                        0.8 * self._encode_ewma_s + 0.2 * enc_s
+                        if self._encode_ewma_s
+                        else enc_s
+                    )
+                    rows = [out[j] for j in slot_of]
+                    if enc_span is not None:
+                        enc_span.attrs["dispatches"] = dispatches
+                except BaseException as exc:  # propagate to every waiter in the tick
+                    if enc_span is not None:
+                        enc_span.attrs["error"] = type(exc).__name__
+                    self._release_inflight(n_rows)
+                    for sub in batch:
+                        sub.error = exc
+                        sub.event.set()
+                    continue
+                real1, padded1 = sent()
+                with self._cond:
+                    self.ticks += 1
+                    self.total_rows += n_rows
+                    self.batches += dispatches
+                    self.dedup_rows += n_rows - len(unique)
+                    self.real_tokens += real1 - real0
+                    self.padded_tokens += padded1 - padded0
+                    self.max_tick_rows = max(self.max_tick_rows, n_rows)
+                    self._inflight_rows -= n_rows
+                    self._cond.notify_all()
+                pos = 0
+                for sub in batch:
+                    sub.rows = rows[pos : pos + len(sub.texts)]
+                    pos += len(sub.texts)
+                    sub.event.set()
+                # telemetry AFTER responders are released: stage counters and
+                # histograms are off the request latency path
+                telemetry.stage_add_many(
+                    {
+                        "embed.svc.ticks": 1.0,
+                        "embed.svc.rows": float(n_rows),
+                        "embed.svc.batches": float(dispatches),
+                        "embed.svc.dedup_rows": float(n_rows - len(unique)),
+                    }
+                )
+                depth_hist.observe(float(depth))
+                occ_hist.observe(n_rows / self.max_in_flight)
+                tick_hist.observe(time.perf_counter() - t_tick)
+                if self._after_batch is not None:
+                    try:
+                        self._after_batch(unique, out)
+                    except Exception:
+                        pass  # cache fill is best-effort; responders already released
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -721,6 +727,8 @@ class EncoderService:
                 "svc_rows": self.total_rows,
                 "svc_batches": self.batches,
                 "svc_dedup_rows": self.dedup_rows,
+                "svc_real_tokens": self.real_tokens,
+                "svc_padded_tokens": self.padded_tokens,
                 "svc_max_tick_rows": self.max_tick_rows,
                 "svc_avg_tick_rows": round(self.total_rows / max(self.ticks, 1), 2),
                 "svc_occupancy": round(

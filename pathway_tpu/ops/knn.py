@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from pathway_tpu.engine import tracing
 from pathway_tpu.internals.shapes import next_pow2 as _next_pow2_shared
 
 
@@ -31,20 +32,22 @@ def _search_kernel(
     data: jax.Array, valid: jax.Array, norms: jax.Array, queries: jax.Array, k: int, metric: str
 ) -> Tuple[jax.Array, jax.Array]:
     """Top-k over the full store: (q, cap) score matrix on the MXU, masked, top_k."""
-    scores = jnp.dot(
-        queries, data.T, preferred_element_type=jnp.float32
-    )  # (q, cap) — MXU path (bf16 operands accumulate in f32)
-    # query norms in f32 regardless of storage dtype: a bf16 self-product loses
-    # ~3 decimal digits, which skews l2 distances near ties
-    qf = queries.astype(jnp.float32)
-    if metric == "l2sq":
-        qn = jnp.sum(qf * qf, axis=1, keepdims=True)
-        scores = -(qn + norms[None, :] - 2.0 * scores)  # -(||q-d||^2), higher is better
-    elif metric == "cos":
-        qn = jnp.linalg.norm(qf, axis=1, keepdims=True)
-        scores = scores / jnp.maximum(qn * jnp.sqrt(norms)[None, :], 1e-30)
-    scores = jnp.where(valid[None, :], scores, -jnp.inf)
-    top_scores, top_idx = lax.top_k(scores, k)
+    with jax.named_scope("knn_score"):
+        scores = jnp.dot(
+            queries, data.T, preferred_element_type=jnp.float32
+        )  # (q, cap) — MXU path (bf16 operands accumulate in f32)
+        # query norms in f32 regardless of storage dtype: a bf16 self-product
+        # loses ~3 decimal digits, which skews l2 distances near ties
+        qf = queries.astype(jnp.float32)
+        if metric == "l2sq":
+            qn = jnp.sum(qf * qf, axis=1, keepdims=True)
+            scores = -(qn + norms[None, :] - 2.0 * scores)  # -(||q-d||^2), higher is better
+        elif metric == "cos":
+            qn = jnp.linalg.norm(qf, axis=1, keepdims=True)
+            scores = scores / jnp.maximum(qn * jnp.sqrt(norms)[None, :], 1e-30)
+        scores = jnp.where(valid[None, :], scores, -jnp.inf)
+    with jax.named_scope("knn_top_k"):
+        top_scores, top_idx = lax.top_k(scores, k)
     return top_scores, top_idx
 
 
@@ -306,6 +309,18 @@ class DenseKNNStore(SlotIngestMixin):
 
     def search_batch(self, queries: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Returns (scores (q,k), slots (q,k), valid_mask (q,k)); slots map via key_of."""
+        with tracing.trace_span("search.prepare"):
+            top_scores, top_idx = self._dispatch_search(queries, k)
+        # one batched host fetch for scores and ids together: the one place
+        # where a search blocks on the device
+        with tracing.trace_span("search.device_wait"):
+            scores, idx = jax.device_get((top_scores, top_idx))
+        valid = np.isfinite(scores)
+        return scores, idx, valid
+
+    def _dispatch_search(self, queries: Any, k: int) -> Tuple[jax.Array, jax.Array]:
+        """Flush, cast, pad to the bucket and enqueue the search program and
+        the slices of its result; nothing here waits for the device."""
         self._flush()
         if isinstance(queries, jax.Array):
             # device-resident queries (e.g. straight from the embedder) chain into
@@ -344,10 +359,7 @@ class DenseKNNStore(SlotIngestMixin):
             k_pad,
             self.metric,
         )
-        # one batched host fetch for scores and ids together
-        scores, idx = jax.device_get((top_scores[:nq, :k_eff], top_idx[:nq, :k_eff]))
-        valid = np.isfinite(scores)
-        return scores, idx, valid
+        return top_scores[:nq, :k_eff], top_idx[:nq, :k_eff]
 
 
 class BruteForceKnnIndex:
@@ -531,14 +543,31 @@ class BruteForceKnnIndex:
         )
         overfetch = max(limits) if not has_filter else max(max(limits) * 4, 16)
         overfetch = min(overfetch, max(len(self.store), 1))
-        vecs = [_as_vector(v) for v in query_vectors]
-        if any(isinstance(v, jax.Array) for v in vecs):
-            q: Any = jnp.stack([jnp.asarray(v, dtype=jnp.float32) for v in vecs])
-        else:
-            q = np.stack(vecs)
-        scores, idx, valid = self.store.search_batch(q, overfetch)
+        with tracing.trace_span("search", attrs={"queries": n}):
+            with tracing.trace_span("search.prepare"):
+                vecs = [_as_vector(v) for v in query_vectors]
+                if any(isinstance(v, jax.Array) for v in vecs):
+                    q: Any = jnp.stack(
+                        [jnp.asarray(v, dtype=jnp.float32) for v in vecs]
+                    )
+                else:
+                    q = np.stack(vecs)
+            scores, idx, valid = self.store.search_batch(q, overfetch)
+            with tracing.trace_span("search.assemble"):
+                return self._assemble(scores, idx, valid, limits, filter_exprs)
+
+    def _assemble(
+        self,
+        scores: np.ndarray,
+        idx: np.ndarray,
+        valid: np.ndarray,
+        limits: List[int],
+        filter_exprs: List[Any] | None,
+    ) -> List[List[tuple]]:
+        """Host side of a search: slots to keys, filters, each query's limit."""
         from pathway_tpu.stdlib.indexing.filters import matches_filter
 
+        n = len(limits)
         results: List[List[tuple]] = []
         for qi in range(n):
             if limits[qi] <= 0:

@@ -1163,7 +1163,7 @@ class ClusterExchange:
             updates[f"exchange.peer{slowest_peer}.straggler_wait_s"] = slowest_wait
         _stage_add_many(updates)
         tracer = _get_tracer()
-        if tracer.enabled and _trace_current() is not None:
+        if tracer.recording() and _trace_current() is not None:
             # a barrier inside a traced scope (the commit span's context-local
             # parent) becomes a child span carrying the SAME straggler
             # attribution the stage counters got — "barrier held 41 ms by
@@ -1278,7 +1278,7 @@ class ClusterExchange:
                         link_ctxs.append(peer_ctx)
                 merged.append(Delta(keys, diffs, columns, neu=neu))
         tracer = _get_tracer()
-        if link_ctxs and tracer.enabled and ctx is not None:
+        if link_ctxs and tracer.recording() and ctx is not None:
             span = tracer.start(
                 "exchange",
                 f"exchange {tag.decode('utf-8', 'replace')}",

@@ -339,7 +339,7 @@ class ReplicaFollower:
             )
 
             tracer = get_tracer()
-            if not tracer.enabled:
+            if not tracer.recording():
                 return
             parent = parse_trace_header(str(rider))
             if parent is None:
@@ -540,7 +540,7 @@ class ReplicaServer:
                     from pathway_tpu.engine import tracing as _tracing
 
                     tracer = _tracing.get_tracer()
-                    if tracer.enabled:
+                    if tracer.recording():
                         parent = _tracing.parse_trace_header(
                             self.headers.get(_tracing.TRACE_HEADER) or ""
                         )

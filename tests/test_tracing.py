@@ -205,12 +205,18 @@ def test_query_and_commit_link_registries_drain_once(tracer):
     q1, q2, c1 = new_trace_context(), new_trace_context(), new_trace_context()
     tracer.register_query_link("what is pathway", q1)
     tracer.register_query_link("what is pathway", q2)
-    tracer.register_commit_link(c1)
+    before = time.monotonic()
+    tracer.register_commit_link(b"row-key-1", c1)
     got = tracer.take_query_links(["what is pathway", "absent"])
     assert {g.span_id for g in got} == {q1.span_id, q2.span_id}
     assert tracer.take_query_links(["what is pathway"]) == []
-    assert [c.span_id for c in tracer.take_commit_links()] == [c1.span_id]
-    assert tracer.take_commit_links() == []
+    # a commit takes the links of the row keys among its input rows, each
+    # with the instant it was registered (the push instant), once
+    assert tracer.take_commit_links([b"another-row"]) == []
+    [(ctx, pushed)] = tracer.take_commit_links([b"row-key-1", b"another-row"])
+    assert ctx.span_id == c1.span_id
+    assert before <= pushed <= time.monotonic()
+    assert tracer.take_commit_links([b"row-key-1"]) == []
 
 
 # -- flush / merge / critical path --------------------------------------------
