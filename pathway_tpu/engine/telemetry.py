@@ -141,13 +141,13 @@ TRACE_SPAN_KINDS: "frozenset[str]" = frozenset({
     "admit",         # REST: parse, validate, admission check, source.push
     "barrier",       # exchange barrier wait (carries straggler attribution)
     "cache_fill",    # encoder tick: embed caches filled after the waiters left
-    "cache_fill.device_wait",  # ... its fetch of the tick's rows from the device
     "checkpoint",    # coordinated checkpoint write inside a commit
     "coalesce",      # query-coalescer admission wait
     "commit",        # one engine commit (deterministic cross-rank trace id)
     "embed_wait",    # commit thread inside embed_query_rows, until rows come back
     "encode",        # encoder-service tick (links N parent query spans)
-    "encode.dispatch",  # ... forward enqueued, per-row slices of its output
+    "encode.device_wait",  # ... the one fetch of the padded forward
+    "encode.dispatch",  # ... tokenize, pad on the host, forward enqueued
     "exchange",      # mesh delta receive (links the sender's commit span)
     "fused_region",  # one fused chain executed as a single program
     "loop_wait",     # commit loop between commits, waiting for a source's push
@@ -160,7 +160,7 @@ TRACE_SPAN_KINDS: "frozenset[str]" = frozenset({
     "search",        # KnnIndex.search_many: one commit's queries
     "search.assemble",     # ... slots to keys, filters, limits (host loop)
     "search.device_wait",  # ... the fetch of scores and ids from the device
-    "search.prepare",      # ... stack, casts, pad, search program enqueued
+    "search.prepare",      # ... stack and pad on the host, search program enqueued
     "tokenize",      # encoder tick: texts to ids on the host
 })
 

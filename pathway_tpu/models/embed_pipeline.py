@@ -45,6 +45,7 @@ import numpy as np
 
 from pathway_tpu.engine import telemetry
 from pathway_tpu.engine import tracing
+from pathway_tpu.models.encoder import fetch_rows
 
 
 class EmbedCache:
@@ -155,8 +156,8 @@ class QueryCoalescer:
     (content dedup) — every request still receives its own rows, in order.
 
     ``encode_rows(texts) -> sequence of per-row values`` runs on the worker
-    thread; row values may be host arrays or device-resident jax slices — the
-    coalescer never inspects them. An optional ``after_batch(texts, rows)``
+    thread; the coalescer never inspects the row values (the pipeline hands
+    it the rows of one host array). An optional ``after_batch(texts, rows)``
     hook runs AFTER responders are released (cache fill without adding to
     request latency).
 
@@ -538,7 +539,7 @@ class EmbedPipeline:
                 tick_ms=tick_ms,
                 max_in_flight=max_in_flight,
                 prewarm=prewarm,
-                after_batch=self._fill_cache_from_device,
+                after_batch=self._fill_caches,
             )
             if service_mode
             else None
@@ -569,7 +570,7 @@ class EmbedPipeline:
             max_wait_ms=max_wait_ms,
             max_batch=max_batch,
             max_queue_rows=max_queue_rows,
-            after_batch=self._fill_cache_from_device,
+            after_batch=self._fill_caches,
             service=self.service,
         )
 
@@ -608,12 +609,12 @@ class EmbedPipeline:
     # -- query path ----------------------------------------------------------
 
     def embed_query_rows(self, texts: List[str]) -> List[Any]:
-        """Per-row embedding values for the serving path. Cache hits (content
-        hash first, then the semantic query cache) return host rows; misses
-        ride the encoder service's continuous batch (or the legacy coalescer)
-        and return DEVICE-resident jax slices (the downstream KNN kernel
-        consumes either without an extra round trip). The whole call is the
-        commit thread's ``embed_wait`` span: until the service hands rows back."""
+        """Per-row embedding values for the serving path: read-only host
+        float32 rows. Cache hits (content hash first, then the semantic query
+        cache) return the cached row; misses ride the encoder service's
+        continuous batch (or the legacy coalescer) and return views of the one
+        host array their tick fetched. The whole call is the commit thread's
+        ``embed_wait`` span: until the service hands rows back."""
         with tracing.trace_span("embed_wait", attrs={"rows": len(texts)}):
             return self._embed_query_rows(texts)
 
@@ -655,25 +656,23 @@ class EmbedPipeline:
                 rows[i] = v
         return rows
 
-    def _encode_device_rows(self, texts: List[str]) -> List[Any]:
+    def _encode_device_rows(self, texts: List[str]) -> np.ndarray:
+        """The legacy coalescer's batch: one padded forward, fetched once and
+        cut on the host, as the service's tick does."""
         dev = self.encoder.encode_device(texts)
-        return [dev[i] for i in range(len(texts))]
+        with tracing.trace_span("encode.device_wait"):
+            rows = fetch_rows(dev, len(texts))
+        rows.setflags(write=False)  # waiters and the caches share these rows
+        return rows
 
-    def _fill_cache_from_device(self, texts: List[str], rows: Sequence[Any]) -> None:
+    def _fill_caches(self, texts: List[str], rows: Sequence[Any]) -> None:
         """Runs on the service/coalescer worker AFTER responders are released:
-        ONE device→host fetch of the whole batch (restacked from the rows the
-        responders got — no hidden state shared with the encode call) fills
-        the content-hash AND semantic caches without adding a sync to any
-        query's latency."""
+        fills the content-hash AND semantic caches from the host rows the
+        batch already fetched, without adding to any query's latency."""
         if self.cache.max_entries <= 0 or not texts:
             return
-        import jax.numpy as jnp
-
         with tracing.trace_span("cache_fill", attrs={"rows": len(texts)}):
-            stacked = jnp.stack(list(rows[: len(texts)]))
-            with tracing.trace_span("cache_fill.device_wait"):
-                host = np.asarray(stacked, dtype=np.float32)
-            for t, v in zip(texts, host):
+            for t, v in zip(texts, rows):
                 self.cache.put(t, v)
                 self.semantic_cache.put(t, v)
 

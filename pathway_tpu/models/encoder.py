@@ -370,17 +370,17 @@ class JaxSentenceEncoder:
         return s.lower() if self._tokenizer_lowercases else s
 
     def encode_device(self, texts: list[str]) -> Any:
-        """Embeddings as a DEVICE-resident (n, dim) jax array — no host sync.
-
-        Serving paths chain this straight into the KNN search kernel so a query
-        pays exactly one device round-trip (dispatches pipeline; only the final
-        fetch blocks)."""
+        """The PADDED forward of ``texts`` as a device array, enqueued and not
+        waited for: ``(batch bucket, dim)`` in the transfer dtype, row ``i``
+        the embedding of ``texts[i]``, the rows past ``len(texts)`` the
+        bucket's zero padding. Nothing is sliced on the device (a slice is a
+        program of its own, keyed by ``len(texts)``): callers fetch the bucket
+        once and cut it on the host (:func:`fetch_rows`)."""
         if not texts:
-            return jnp.zeros((0, self.config.hidden_size), dtype=jnp.float32)
+            return np.zeros((0, self.config.hidden_size), dtype=np.float32)
         with tracing.trace_span("tokenize", attrs={"rows": len(texts)}):
             ids, mask = self._tokenize(texts)
-        out = self._dispatch(ids, mask)
-        return out[: ids.shape[0]]
+        return self._dispatch(ids, mask)
 
     def _dispatch(self, ids: np.ndarray, mask: np.ndarray) -> Any:
         """Pad a tokenized batch to pow2 (seq, batch) buckets and dispatch the
@@ -393,7 +393,9 @@ class JaxSentenceEncoder:
         ids_p[: ids.shape[0], : ids.shape[1]] = ids * mask  # padding -> id 0
         real, padded = self.dispatched_tokens()
         self._dispatched.counts = (real + int(mask.sum()), padded + batch * seq)
-        return self._encode_ids(self.params, jnp.asarray(ids_p))
+        # the host array goes in as it is: the call transfers it (a
+        # ``jnp.asarray`` first is a second trip through the runtime)
+        return self._encode_ids(self.params, ids_p)
 
     def dispatched_tokens(self) -> Tuple[int, int]:
         """(real tokens, padded tokens) the CALLING thread has sent to the
@@ -406,7 +408,7 @@ class JaxSentenceEncoder:
     def encode(self, texts: list[str]) -> np.ndarray:
         if not texts:
             return np.zeros((0, self.config.hidden_size), dtype=np.float32)
-        return np.asarray(self.encode_device(texts), dtype=np.float32)
+        return fetch_rows(self.encode_device(texts), len(texts))
 
     def encode_pipelined(
         self, texts: list[str], sub_batch: int = 128
@@ -453,6 +455,14 @@ class JaxSentenceEncoder:
         for dev, idx in inflight:
             out[idx] = np.asarray(dev[: len(idx)], dtype=np.float32)
         return out, stats
+
+
+def fetch_rows(out: Any, n: int) -> np.ndarray:
+    """The first ``n`` rows of a (padded) forward as ONE host float32 array:
+    one fetch of the whole bucket (6 KB at 8 x 384 float16), the slice and the
+    cast in numpy. float16 to float32 is exact, so the rows are bit for bit
+    what a cast on the device gave. Blocks until the device has the forward."""
+    return np.asarray(out)[:n].astype(np.float32)
 
 
 def _next_pow2(n: int) -> int:

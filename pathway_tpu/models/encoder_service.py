@@ -74,6 +74,7 @@ import numpy as np
 
 from pathway_tpu.engine import telemetry
 from pathway_tpu.engine import tracing as _tracing
+from pathway_tpu.models.encoder import fetch_rows
 
 
 def _env_float(name: str, default: float) -> float:
@@ -271,8 +272,9 @@ class EncoderService:
     """Persistent continuous-batching worker in front of one encoder.
 
     ``submit(texts)`` blocks until the worker answers with one row value per
-    text (device-resident jax slices from ``encoder.encode_device``). The
-    worker packs everything queued at each tick — up to ``max_in_flight`` rows,
+    text: read-only host float32 rows, views of the one array the tick fetched
+    from ``encoder.encode_device``'s padded forward. The worker packs
+    everything queued at each tick — up to ``max_in_flight`` rows,
     length-sorted, duplicates encoded once — into one bucketed dispatch, so a
     solo request is dispatched the moment the worker is free (no deadline
     window) and a burst coalesces exactly like the PR-4 path did under load.
@@ -381,15 +383,14 @@ class EncoderService:
         """Compile every reachable bucket off the request path; wall time and
         compile count land on ``embed.svc.prewarm_*`` so startup cost is
         reported instead of billed to the first query."""
-        import jax.numpy as jnp
-
         t0 = time.perf_counter()
         compiles = 0
         try:
             for batch, seq in self._prewarm_shapes():
                 if self._prewarm_abort.is_set() or self._closed:
                     break  # remaining buckets compile lazily on first use
-                ids = jnp.zeros((batch, seq), dtype=jnp.int32)
+                # host ids, as a tick passes them: the same call signature
+                ids = np.zeros((batch, seq), dtype=np.int32)
                 out = self.encoder._encode_ids(self.encoder.params, ids)
                 out.block_until_ready()
                 compiles += 1
@@ -556,27 +557,32 @@ class EncoderService:
             self._inflight_rows -= rows
             self._cond.notify_all()
 
-    def _encode_packed(self, texts: List[str]) -> Tuple[List[Any], int]:
+    def _encode_packed(self, texts: List[str]) -> Tuple[np.ndarray, int]:
         """Length-sorted packing of one tick's unique texts: small ticks are a
         single bucketed dispatch; large ticks split into ``sub_batch``-row
         length-sorted sub-batches (each padded only to ITS longest row's pow2
         bucket, dispatched async) so a ragged burst doesn't pay the longest
-        row's padding on every short query. Returns (rows, dispatches)."""
+        row's padding on every short query. Every dispatch is enqueued before
+        the first fetch; each padded forward is fetched once and cut on the
+        host. Returns (read-only host float32 ``(len(texts), dim)``,
+        dispatches)."""
         n = len(texts)
+        order = list(range(n))
+        if n > self.sub_batch:
+            order.sort(key=lambda i: len(str(texts[i]).split()))
         with _tracing.trace_span("encode.dispatch", attrs={"rows": n}):
-            if n <= self.sub_batch:
-                dev = self.encoder.encode_device(texts)
-                return [dev[i] for i in range(n)], 1
-            order = sorted(range(n), key=lambda i: len(str(texts[i]).split()))
-            rows: List[Any] = [None] * n
-            dispatches = 0
+            inflight = []
             for start in range(0, n, self.sub_batch):
                 idx = order[start : start + self.sub_batch]
-                dev = self.encoder.encode_device([texts[i] for i in idx])
-                for j, i in enumerate(idx):
-                    rows[i] = dev[j]
-                dispatches += 1
-            return rows, dispatches
+                inflight.append((self.encoder.encode_device([texts[i] for i in idx]), idx))
+        # the tick's one wait for the chip
+        with _tracing.trace_span("encode.device_wait"):
+            packed = np.concatenate([fetch_rows(dev, len(idx)) for dev, idx in inflight])
+        rows = np.empty_like(packed)
+        rows[order] = packed  # back from length order to the texts' order
+        # waiters, the engine's memo and the caches share these rows
+        rows.setflags(write=False)
+        return rows, len(inflight)
 
     def _run(self) -> None:
         from pathway_tpu.engine.profile import histogram

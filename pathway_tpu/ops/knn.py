@@ -60,15 +60,21 @@ def next_pow2(n: int) -> int:
     return _next_pow2_shared(n, floor=1)
 
 
-def pad_queries_pow2(q_dev: jax.Array, dim: int) -> Tuple[jax.Array, int]:
-    """Pad a device query batch with zero rows to the next pow2 count (floor
-    8) — the ONE bucketing policy shared by the dense and IVF search paths.
-    Returns (padded batch, original row count) for slicing results back."""
-    nq = q_dev.shape[0]
+def pad_queries_pow2(queries: Any, dim: int) -> Tuple[Any, int]:
+    """Pad a query batch with zero rows to the next pow2 count (floor 8) —
+    the ONE bucketing policy shared by the dense and IVF search paths. A host
+    batch is padded in numpy (no program runs); a device batch on the device
+    (a concatenate keyed by its row count). Returns (padded batch, original
+    row count) for slicing results back."""
+    nq = queries.shape[0]
     q_pad = next_pow2(max(8, nq))
-    if q_pad != nq:
-        q_dev = jnp.concatenate([q_dev, jnp.zeros((q_pad - nq, dim), q_dev.dtype)])
-    return q_dev, nq
+    if q_pad == nq:
+        return queries, nq
+    if isinstance(queries, jax.Array):
+        return jnp.concatenate([queries, jnp.zeros((q_pad - nq, dim), queries.dtype)]), nq
+    padded = np.zeros((q_pad, dim), dtype=queries.dtype)
+    padded[:nq] = queries
+    return padded, nq
 
 
 def kernel_cache_sizes() -> Dict[str, int]:
@@ -307,59 +313,57 @@ class DenseKNNStore(SlotIngestMixin):
         vecs = np.asarray(self._data[jnp.asarray(slots)].astype(jnp.float32))
         return keys, vecs
 
-    def search_batch(self, queries: np.ndarray, k: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    def search_batch(self, queries: Any, k: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Returns (scores (q,k), slots (q,k), valid_mask (q,k)); slots map via key_of."""
         with tracing.trace_span("search.prepare"):
-            top_scores, top_idx = self._dispatch_search(queries, k)
+            top_scores, top_idx, nq, k_eff = self._dispatch_search(queries, k)
         # one batched host fetch for scores and ids together: the one place
-        # where a search blocks on the device
+        # where a search blocks on the device. The bucket's padding rows and
+        # columns are cut off here, on the host
         with tracing.trace_span("search.device_wait"):
             scores, idx = jax.device_get((top_scores, top_idx))
+        scores, idx = scores[:nq, :k_eff], idx[:nq, :k_eff]
         valid = np.isfinite(scores)
         return scores, idx, valid
 
-    def _dispatch_search(self, queries: Any, k: int) -> Tuple[jax.Array, jax.Array]:
-        """Flush, cast, pad to the bucket and enqueue the search program and
-        the slices of its result; nothing here waits for the device."""
+    def _dispatch_search(self, queries: Any, k: int) -> Tuple[jax.Array, jax.Array, int, int]:
+        """Flush, cast, pad to the bucket and enqueue the search program;
+        nothing here waits for the device. Returns the PADDED (query bucket,
+        k bucket) results and the (rows, columns) that are real. A host batch
+        is cast and padded in numpy and crosses with the call, so the search
+        program is the only one that runs; a device batch is cast and padded
+        on the device."""
         self._flush()
+        # bf16-resident corpus (HBM capacity: 10M x 384 fits one v5e chip):
+        # the MXU consumes bf16 natively with f32 accumulation — cast the
+        # QUERIES down instead of materializing an f32 copy of the corpus
+        bf16 = self._data.dtype == jnp.bfloat16
+        want = jnp.bfloat16 if bf16 else jnp.float32
         if isinstance(queries, jax.Array):
-            # device-resident queries (e.g. straight from the embedder) chain into
-            # the search without a host round-trip; skip no-op casts/reshapes so
-            # the serving path dispatches exactly one device computation
-            if queries.dtype != jnp.float32:
-                queries = queries.astype(jnp.float32)
             if queries.ndim != 2 or queries.shape[-1] != self.dim:
                 queries = queries.reshape(-1, self.dim)
+            if queries.dtype != want:
+                queries = queries.astype(want)
         else:
             queries = np.asarray(queries, dtype=np.float32).reshape(-1, self.dim)
-        k_eff = max(1, min(k, self.capacity))
-        q_dev = queries if isinstance(queries, jax.Array) else jnp.asarray(queries)
+            queries = queries.astype(want, copy=False)
+        # a padded host batch is handed to the program as it is: the call
+        # transfers it
+        q_pad, nq = pad_queries_pow2(queries, self.dim)
         # pow2 shape bucketing: serving traffic arrives at ragged batch sizes
         # and per-request k; padding both to the next power of two bounds the
         # kernel's jit cache at O(log) entries instead of one compile per size
-        q_dev, nq = pad_queries_pow2(q_dev, self.dim)
+        k_eff = max(1, min(k, self.capacity))
         k_pad = min(next_pow2(k_eff), self.capacity)
-        if self._data.dtype == jnp.bfloat16:
-            # bf16-resident corpus (HBM capacity: 10M x 384 fits one v5e chip):
-            # the MXU consumes bf16 natively with f32 accumulation — cast the
-            # QUERIES down instead of materializing an f32 copy of the corpus
-            q_dev = q_dev.astype(jnp.bfloat16)
-            data = self._data
-        else:
-            data = (
-                self._data
-                if self._data.dtype == jnp.float32
-                else self._data.astype(jnp.float32)
-            )
-        top_scores, top_idx = _search_kernel(
-            data,
-            self._valid,
-            self._norms,
-            q_dev,
-            k_pad,
-            self.metric,
+        data = (
+            self._data
+            if bf16 or self._data.dtype == jnp.float32
+            else self._data.astype(jnp.float32)
         )
-        return top_scores[:nq, :k_eff], top_idx[:nq, :k_eff]
+        top_scores, top_idx = _search_kernel(
+            data, self._valid, self._norms, q_pad, k_pad, self.metric
+        )
+        return top_scores, top_idx, nq, k_eff
 
 
 class BruteForceKnnIndex:
@@ -545,13 +549,14 @@ class BruteForceKnnIndex:
         overfetch = min(overfetch, max(len(self.store), 1))
         with tracing.trace_span("search", attrs={"queries": n}):
             with tracing.trace_span("search.prepare"):
-                vecs = [_as_vector(v) for v in query_vectors]
-                if any(isinstance(v, jax.Array) for v in vecs):
-                    q: Any = jnp.stack(
-                        [jnp.asarray(v, dtype=jnp.float32) for v in vecs]
-                    )
-                else:
-                    q = np.stack(vecs)
+                # host rows (the serving path's: views of one array per
+                # encoder tick) are stacked in numpy; a batch that already
+                # lives on the device goes to the store whole
+                q: Any = (
+                    query_vectors
+                    if isinstance(query_vectors, jax.Array)
+                    else np.stack([_as_vector(v) for v in query_vectors])
+                )
             scores, idx, valid = self.store.search_batch(q, overfetch)
             with tracing.trace_span("search.assemble"):
                 return self._assemble(scores, idx, valid, limits, filter_exprs)
@@ -678,14 +683,11 @@ def _score_candidates(matrix: jax.Array, query: jax.Array, metric: str) -> jax.A
     return scores
 
 
-def _as_vector(value: Any) -> Any:
-    if isinstance(value, jax.Array):
-        # device-resident: normalize shape/dtype lazily, stays on device
-        return value.astype(jnp.float32).reshape(-1)
-    if isinstance(value, np.ndarray):
-        return value.astype(np.float32).reshape(-1)
-    if isinstance(value, (tuple, list)):
-        return np.asarray(value, dtype=np.float32)
+def _as_vector(value: Any) -> np.ndarray:
+    """One vector cell as a fresh host float32 ``(dim,)`` array (a device
+    array is fetched)."""
+    if isinstance(value, (np.ndarray, jax.Array, tuple, list)):
+        return np.array(value, dtype=np.float32).reshape(-1)
     raise TypeError(f"expected a vector, got {type(value).__name__}")
 
 

@@ -58,9 +58,10 @@ class _KnnInnerIndex(InnerIndex):
     def make_instance_factory(self) -> Callable[[], Any]:
         return self._make_index
 
-    # Indexes whose search kernel consumes device-resident query vectors override
-    # this to True: query embeddings then stay on device and chain into the search
-    # with one total round-trip. Host-side indexes (LSH) keep numpy cells.
+    # Indexes behind the serving path override this to True: their queries are
+    # embedded through the embedder's query-path variant (caches, the encoder
+    # service's continuous batch, one memoized encode per query row). Cells are
+    # host float32 rows either way.
     _device_queries = False
 
     def preprocess_query(self, query_column: expr.ColumnReference) -> expr.ColumnExpression:
@@ -106,7 +107,7 @@ class BruteForceKnn(_KnnInnerIndex):
     """Exact KNN on the TPU (reference ``BruteForceKnn:170`` over
     ``brute_force_knn_integration.rs``)."""
 
-    _device_queries = True  # dense store consumes device query batches directly
+    _device_queries = True  # served: queries ride the encoder service
 
     def __init__(
         self,

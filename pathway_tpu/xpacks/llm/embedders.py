@@ -157,8 +157,9 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         )
 
     def device_expression(self, *args: Any, **kwargs: Any) -> expr.ColumnExpression:
-        """Query-path variant: embedding cells are DEVICE-resident jax slices so
-        downstream device kernels (KNN search) chain without a host round-trip.
+        """Query-path variant: embedding cells are read-only host float32 rows
+        (views of the one array an encoder tick fetched), which the KNN search
+        stacks, pads and ships to the device in one transfer.
         Runs through the pipeline's content-hash + semantic caches and submits
         misses into the persistent encoder service's continuous batch (the
         coalescer admission shim), so a solo query dispatches immediately into

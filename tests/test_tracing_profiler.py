@@ -76,8 +76,8 @@ def test_ring_keeps_a_traced_span_of_200_requests_a_second(sampled):
             with tracer.trace_span("commit", self_ctx=ctx) as span:
                 for kind in ("embed_wait", "coalesce", "search", "search.prepare",
                              "search.prepare", "search.device_wait", "search.assemble",
-                             "encode", "encode.dispatch", "tokenize", "cache_fill",
-                             "cache_fill.device_wait"):
+                             "encode", "encode.dispatch", "tokenize", "encode.device_wait",
+                             "cache_fill"):
                     with tracer.trace_span(kind):
                         pass
             for _ in range(31):

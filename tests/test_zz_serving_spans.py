@@ -105,8 +105,10 @@ def test_zz_session_puts_the_sync_spans_on_the_host_plane(traced):
     names = {e[2] for e in events}
     assert names >= {"pw.admit", "pw.commit", "pw.embed_wait", "pw.search", "pw.search.prepare",
                      "pw.search.device_wait", "pw.search.assemble", "pw.encode",
-                     "pw.encode.dispatch", "pw.tokenize", "pw.cache_fill",
-                     "pw.cache_fill.device_wait", "pw.reply"}
+                     "pw.encode.dispatch", "pw.tokenize", "pw.encode.device_wait",
+                     "pw.cache_fill", "pw.reply"}
+    # the cache fill reuses the tick's one fetch: it waits for no device
+    assert "pw.cache_fill.device_wait" not in names
     # the asynchronous rest span lives across awaits on the event-loop thread,
     # where requests interleave: never an annotation
     assert "pw.rest" not in names
@@ -147,10 +149,16 @@ def test_zz_ring_holds_one_requests_stages_and_they_add_up(traced):
     assert under_commit["search.device_wait"]["parent_id"] == under_commit["search"]["span_id"]
     [encode] = [s for s in spans if s["kind"] == "encode"]
     assert {"trace_id": rest["trace_id"], "span_id": rest["span_id"]} in encode["links"]
-    tick = {s["kind"] for s in spans if s["trace_id"] == encode["trace_id"]}
-    assert tick == {"encode", "encode.dispatch", "tokenize", "cache_fill", "cache_fill.device_wait"}
-
     end = lambda s: s["ts_mono"] + s["duration_s"]
+    tick = {s["kind"]: s for s in spans if s["trace_id"] == encode["trace_id"]}
+    # encode > encode.dispatch > tokenize, encode.device_wait (the tick's one
+    # fetch of the padded forward), cache_fill (host only: no child)
+    assert set(tick) == {"encode", "encode.dispatch", "tokenize", "encode.device_wait", "cache_fill"}
+    assert tick["tokenize"]["parent_id"] == tick["encode.dispatch"]["span_id"]
+    for kind in ("encode.dispatch", "encode.device_wait", "cache_fill"):
+        assert tick[kind]["parent_id"] == encode["span_id"], kind
+    assert end(tick["encode.dispatch"]) <= tick["encode.device_wait"]["ts_mono"] + 1e-6
+
     # admitted, waited, committed, replied, in that order and without overlap
     # up to the commit; the queue ends where its commit starts
     assert rest["ts_mono"] <= mine["admit"]["ts_mono"] <= end(mine["admit"]) <= end(mine["queue"]) + 1e-4

@@ -185,6 +185,12 @@ def test_zz_coalesced_encode_tick_links_the_batched_query_spans():
         >= {parent_a.span_id, parent_b.span_id}
     )
     assert span["attrs"]["unique"] == 2
+    # the tick's parts: the enqueue, then its one wait for the forward
+    parts = [
+        s["kind"] for s in get_tracer().recent_spans(limit=4096)
+        if s["parent_id"] == span["span_id"]
+    ]
+    assert parts == ["encode.dispatch", "encode.device_wait"]  # no cache, no fill
 
 
 def test_zz_trace_current_context_does_not_leak_between_requests():
