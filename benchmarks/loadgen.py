@@ -1,18 +1,20 @@
-"""Open-loop load generator for ``POST /v1/retrieve``: a process of its own.
+"""Open-loop load generator for one ``POST`` route: a process of its own.
 
 Standard library only; it never imports ``jax`` or ``pathway_tpu``, so it shares
 neither the server's interpreter lock nor the chip. The schedule (due instants,
-query texts, ``k``) is a function of the traffic file and the seed alone
-(``schedule``). Every latency is taken from the instant a request was *due*, not
-from when it was sent, and how late the generator sent each one is logged beside
-it, so a starved generator is not read as a fast server.
+query texts) is a function of the traffic file and the seed alone
+(``schedule``); the route and the body's fields are the traffic file's
+``request`` group: ``{"route", "text_key", "fixed"}``, the drawn text under
+``text_key`` and then the ``fixed`` fields as they stand. Every latency is taken from the instant a
+request was *due*, not from when it was sent, and how late the generator sent
+each one is logged beside it, so a starved generator is not read as a fast server.
 
 As a child it is started with the traffic parameters on its command line and the
 shared monotonic instant at which its first request is due (``time.monotonic``
 is one clock for every process of the machine). It writes one JSON line per
 request to ``--out``: ``{"i", "phase", "due", "sent", "done", "status", "query",
-"k", "body"}`` (seconds relative to ``--start-at``; ``status`` 0 and no body
-where no reply came before ``--timeout``).
+the body's fixed fields, "body"}`` (seconds relative to ``--start-at``; ``status``
+0 and no body where no reply came before ``--timeout``).
 """
 
 from __future__ import annotations
@@ -58,14 +60,15 @@ def schedule(traffic: Dict[str, Any], corpus: Dict[str, Any], seed: int, seconds
     # the running tag makes every query unique: no cache can answer it
     texts = [textgen.query_from(docs[order.randrange(len(docs))], order, n_words, f"q{i}")
              for i, n_words in enumerate(window_sizes + sizes[n_window:])]
+    fixed = traffic["request"]["fixed"]
     out, t = [], -lead_in_s
     for g, text in zip(lead_gaps, texts[n_window:]):
         t += g
-        out.append({"phase": "lead", "due": t, "query": text, "k": int(traffic["k"])})
+        out.append({"phase": "lead", "due": t, "query": text, **fixed})
     t = 0.0
     for g, text in zip(window_gaps, texts[:n_window]):
         t += g
-        out.append({"phase": "window", "due": t, "query": text, "k": int(traffic["k"])})
+        out.append({"phase": "window", "due": t, "query": text, **fixed})
     for i, r in enumerate(out):
         r["i"] = i
     return out
@@ -126,15 +129,18 @@ async def _post(pool: _Pool, route: str, payload: bytes) -> tuple:
     raise AssertionError("unreachable")
 
 
-async def drive(requests: List[dict], host: str, port: int, route: str, start_at: float,
+async def drive(requests: List[dict], host: str, port: int, request: Dict[str, Any], start_at: float,
                  timeout_s: float) -> List[dict]:
+    """Send every request at its due instant to ``request["route"]`` (the
+    traffic's ``request`` group), its text under ``request["text_key"]``."""
+    route, text_key, fixed = request["route"], request["text_key"], request["fixed"]
     pool = _Pool(host, port)
     loop = asyncio.get_running_loop()
     offset = loop.time() - time.monotonic()  # the loop's clock is monotonic too
 
     async def one(req: dict) -> dict:
         rec = dict(req, sent=None, done=None, status=0, body=None)
-        payload = json.dumps({"query": req["query"], "k": req["k"]}).encode()
+        payload = json.dumps({text_key: req["query"], **fixed}).encode()
         delay = start_at + req["due"] + offset - loop.time()
         if delay > 0:
             await asyncio.sleep(delay)
@@ -160,17 +166,17 @@ def main(argv: List[str] | None = None) -> int:
     ap.add_argument("--lead-in", type=float, default=0.0)
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, required=True)
-    ap.add_argument("--route", default="/v1/retrieve")
     ap.add_argument("--start-at", type=float, required=True,
                     help="time.monotonic() instant at which the window's first gap starts")
     ap.add_argument("--timeout", type=float, default=60.0)
     ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
     assert "jax" not in sys.modules and "pathway_tpu" not in sys.modules
-    requests = schedule(json.loads(args.traffic), json.loads(args.corpus), args.seed,
-                        args.seconds, args.lead_in, args.docs_seed)
+    traffic = json.loads(args.traffic)
+    requests = schedule(traffic, json.loads(args.corpus), args.seed, args.seconds, args.lead_in,
+                        args.docs_seed)
     records = asyncio.run(
-        drive(requests, args.host, args.port, args.route, args.start_at, args.timeout)
+        drive(requests, args.host, args.port, traffic["request"], args.start_at, args.timeout)
     )
     with open(args.out, "w") as f:
         for rec in records:

@@ -12,8 +12,8 @@ def encoder_flops(model, tokens_per_query):
 
 
 def read(ctx):
-    if ctx.get("trace") is None:
-        return None
+    if ctx.get("trace") is None or not ctx.get("work") or "n_rows" not in ctx:
+        return None  # not traced, or not the system this step is: an encoder forward and a scan of n_rows
     span, model = ctx["trace_span"], ctx["spec"]["config"]["model"]
     flops = 0.0
     for r in ctx["gen"]["records"]:

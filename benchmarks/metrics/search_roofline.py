@@ -7,8 +7,8 @@ import sys
 
 
 def read(ctx):
-    found = ctx["search_time"](ctx)
-    if found is None:
+    found = ctx["search_time"](ctx) if "search_time" in ctx and ctx.get("work") else None
+    if found is None:  # no search program in the trace, or a system that states none and counts no work
         return None
     seconds, calls, per_call = found
     work = ctx["work"].search_call(ctx["n_rows"], ctx["spec"]["config"]["model"]["hidden_size"], per_call)

@@ -4,16 +4,20 @@
 
 Everything that belongs to one cell, configuration or metric is found by the
 name ``BENCHMARK.json`` gives it: the cell's traffic in ``workloads/<cell>.json``,
-its configuration in ``configs/<config>.json``, the configuration's work count in
-``work/<name>.py`` and each metric's reader in ``metrics/<metric>.py``. The last
-line of standard output is the result; everything else goes to standard error.
+its configuration in ``configs/<config>.json``, the configuration's system under
+test in ``systems/<name>.py`` (what is served, how it is built from the seed, its
+plain reference and the numbers compared), its work count in ``work/<name>.py``
+and each metric's reader in ``metrics/<metric>.py``. This file keeps what every
+cell shares: the window, the generator child, the sample, the limits, the trace
+and the result line. The last line of standard output is the result; everything
+else goes to standard error.
 
 ``--sweep r1,r2,...`` runs one set-up and then a ladder of rates to find the knee;
 ``--calibrate n`` reads the numbers ``correct`` compares on ``n`` query seeds, and
-on the first four the controls' (the reference in lower precision, or with a
-guarantee broken, in the program's place), each judged by the cell's limits, in
-one process; ``--rehearse`` runs the whole command at a tiny size on the CPU and
-prints no metric.
+on the first four those of the system's ``CONTROLS`` (its reference in lower
+precision, or with a guarantee broken, in the program's place), each judged by
+the cell's limits, in one process; ``--rehearse`` runs the whole command at a
+tiny size on the CPU and prints no metric.
 """
 
 from __future__ import annotations
@@ -53,12 +57,22 @@ def load_json(*parts: str) -> Any:
 
 
 def load_module(kind: str, name: str) -> Any:
-    """``work/<name>.py``, ``metrics/<name>.py`` or ``hooks/<name>.py``, by the name the data gives."""
+    """``systems/<name>.py``, ``work/<name>.py``, ``metrics/<name>.py`` or
+    ``hooks/<name>.py``, by the name the data gives."""
     path = os.path.join(HERE, kind, name + ".py")
     spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name.replace('.', '_')}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def merged(base: Dict[str, Any], over: Dict[str, Any]) -> Dict[str, Any]:
+    """``base`` with ``over`` laid on it key by key, groups inside groups too."""
+    out = dict(base)
+    for key, value in over.items():
+        both = isinstance(value, dict) and isinstance(out.get(key), dict)
+        out[key] = merged(out[key], value) if both else value
+    return out
 
 
 def resolve(cell_name: str, rehearse: bool) -> Dict[str, Any]:
@@ -70,10 +84,7 @@ def resolve(cell_name: str, rehearse: bool) -> Dict[str, Any]:
     cell = cells[cell_name]
     cfg = load_json("configs", cell["config"] + ".json")
     if rehearse:
-        r = cfg["rehearse"]
-        cfg["model"] = {**cfg["model"], **r["model"]}
-        cfg["corpus"] = {**cfg["corpus"], **r["corpus"]}
-        cfg["index"]["args"] = {**cfg["index"]["args"], **r["index_args"]}
+        cfg = merged(cfg, cfg["rehearse"])
 
     def wanted(metric: Dict[str, Any]) -> bool:
         return cell_name in metric.get("workloads", [cell_name])
@@ -141,18 +152,16 @@ def run_generator(spec: Dict[str, Any], system: Any, seed: int, seconds: float, 
             "rate_rps": float(traffic["rate_rps"])}
 
 
-def window_stats(gen: Dict[str, Any], k: int) -> Dict[str, Any]:
+def window_stats(gen: Dict[str, Any], spec: Dict[str, Any]) -> Dict[str, Any]:
     """What the harness itself takes from the generator's log: per request the
-    latency from its due instant (a request with no good reply counts as the
-    drain's whole wait) and how late it was sent."""
-    import compare
-
+    latency from its due instant (a request with no good reply, as the system's
+    module reads and judges it, counts as the drain's whole wait) and how late
+    it was sent."""
     window = [r for r in gen["records"] if r["phase"] == "window"]
     latencies, late, good = [], [], 0
     for r in window:
-        answer = compare.parse_reply(r["body"]) if r["status"] == 200 else None
-        r["answer"] = answer
-        ok = answer is not None and len(answer) == k
+        r["answer"] = spec["system"].parse_reply(r["body"]) if r["status"] == 200 else None
+        ok = spec["system"].good(r["answer"], spec["traffic"])
         good += ok
         latencies.append((r["done"] - r["due"]) * 1e3 if ok else DRAIN_TIMEOUT_S * 1e3)
         if r["sent"] is not None:
@@ -186,72 +195,20 @@ def profile_span(trace_dir: str, start_at: float) -> Dict[str, float]:
     return {"t0": t0 - start_at, "t1": t1 - start_at, "stop_s": time.monotonic() - t1}
 
 
-# what --calibrate puts in the program's place: (the encoder's precision, the
-# scoring's precision, whether the resident rows are scanned)
-CONTROLS = {
-    "fp8_encoder": ("fp8", "f32", True),   # the control: the nearest precision below bfloat16
-    "fp8_index": ("f32", "fp8", True),     # the same step down in the index's scoring passes
-    "int8_encoder": ("int8", "f32", True),  # per-tensor int8, read beside the control
-    "live_rows_only": ("f32", "f32", False),  # a guarantee broken: resident rows left out
-}
-
-
-def reference_check(spec: Dict[str, Any], system: Any, sample: List[dict], controls=()):
-    """The plain reference over the sampled queries: its embeddings of the live
-    documents (made in set-up, where the resident rows' neighbours are drawn
-    around them) and of the queries, and its exact top-k over ALL rows (live and
-    resident, the resident ones drawn again from the seed block by block). For
-    each name in ``controls`` also that control's answers, in the program's place."""
-    import compare
-    import reference
-
-    cfg, k = spec["config"], int(spec["traffic"]["k"])
-    model, corpus = cfg["model"], cfg["corpus"]
-    queries = [r["query"] for r in sample]
-    query_vecs = reference.embed_texts(system.weights, queries, model)
-    n_res, block = int(corpus["resident_rows"]), int(corpus["install_block_rows"])
-
-    def resident(b: int, lo: int):
-        return lambda: (system.resident_block(b)[: n_res - lo], len(system.docs) + lo)
-
-    def blocks(doc_vecs, with_resident: bool = True):
-        rest = [resident(b, lo) for b, lo in enumerate(range(0, n_res, block))] if with_resident else []
-        return [lambda: (doc_vecs, 0)] + rest
-
-    ref_topk, ref_ids = reference.exact_topk(query_vecs, blocks(system.doc_vecs), k)
-    out = {"ref_scores": reference.cosine_to(query_vecs, system.doc_vecs), "ref_topk": ref_topk,
-           "ref_ids": ref_ids, "control_answers": {}}
-    for name in controls:
-        encoder, scoring, with_resident = CONTROLS[name]
-        docs_low, queries_low = system.doc_vecs, query_vecs
-        if encoder != "f32":
-            docs_low = reference.embed_texts(system.weights, system.docs, model, encoder)
-            queries_low = reference.embed_texts(system.weights, queries, model, encoder)
-        scores, ids = reference.exact_topk(queries_low, blocks(docs_low, with_resident), k, scoring)
-        out["control_answers"][name] = compare.answers_from(ids, scores, system.docs)
-    return out
-
-
 def judge_window(spec, system, stats, seed, counters_before, counters_after, controls=()):
-    """Sample the window's requests from the seed, run the reference over them
-    and compare. Returns (correct, compared table, each control's (correct, table))."""
+    """Sample the window's requests from the seed, have the system's module run
+    its reference over them, and hold each number it compares, and the programs
+    compiled inside the window, to the cell's limits. Returns (correct, compared
+    table, each control's (correct, table))."""
     import compare
 
     traffic = spec["traffic"]
-    k, limits = int(traffic["k"]), traffic["limits"]
-    window = stats["window"]
-    rng = random.Random(seed)
-    sample = rng.sample(window, min(int(traffic["sample"]), len(window)))
-    ref = reference_check(spec, system, sample, controls)
-    n_live, block = len(system.docs), int(spec["config"]["corpus"]["install_block_rows"])
-    res_ids = ref["ref_ids"][ref["ref_ids"] >= n_live] - n_live
-    log(f"reference top-{k} of {len(sample)} sampled queries: {res_ids.size} of {ref['ref_ids'].size} "
-        f"entries are resident rows, {len(set(res_ids.tolist()))} distinct, from install blocks "
-        f"{sorted(set((res_ids // block).tolist()))}")
-    judged = lambda answers: compare.compare(answers, k, system.docs, ref["ref_scores"], ref["ref_topk"])
-    numbers = judged([r["answer"] for r in sample])
-    grew = sum(max(0.0, counters_after[n] - counters_before[n]) for n in counters_before
-               if n.startswith("kernel.") or n == "svc_prewarm_compiles")
+    limits, window = traffic["limits"], stats["window"]
+    sample = random.Random(seed).sample(window, min(int(traffic["sample"]), len(window)))
+    numbers, control_numbers = spec["system"].judge(spec, system, sample, controls)
+    # each counter the system names as counting compiled programs, by its own growth
+    grew = sum(max(0.0, value - counters_before.get(name, 0.0)) for name, value in counters_after.items()
+               if name.startswith(tuple(spec["system"].COMPILE_COUNTERS)))
     late = percentile(stats["late_ms"], 0.95) or 0.0
     # a batch past the warmed sizes compiles; where the generator itself was late the
     # machine stalled and the backlog is the stall's, not the program's: printed, not judged
@@ -262,23 +219,7 @@ def judge_window(spec, system, stats, seed, counters_before, counters_after, con
     if stalled:
         table["compiles_in_window"] = {"value": grew, "limit": None,
                                        "not_judged": f"generator late p95 {late:.1f} ms: the machine stalled"}
-    return correct, table, {name: compare.judge(judged(a), limits) for name, a in ref["control_answers"].items()}
-
-
-def search_time(ctx: Dict[str, Any]):
-    """(device seconds, calls, queries per call) of the configuration's search
-    programs inside the traced span; None where the trace shows none."""
-    import trace_reduce
-
-    if ctx.get("trace") is None:
-        return None
-    seconds, calls = trace_reduce.program_seconds(ctx["trace"], ctx["spec"]["config"]["search_programs"])
-    if calls <= 0 or seconds <= 0:
-        return None
-    span = ctx["trace_span"]
-    served = sum(1 for r in ctx["gen"]["records"]
-                 if r["done"] is not None and r["status"] == 200 and span["t0"] <= r["done"] < span["t1"])
-    return seconds, calls, served / calls
+    return correct, table, {name: compare.judge(n, limits) for name, n in control_numbers.items()}
 
 
 def read_metrics(names: List[Dict[str, Any]], ctx: Dict[str, Any]) -> Dict[str, Any]:
@@ -299,21 +240,26 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--sweep", default="", help="comma-separated rates: one set-up, one step each")
     ap.add_argument("--calibrate", type=int, default=0, help="query seeds to read the compared numbers on")
     ap.add_argument("--rehearse", action="store_true", help="tiny size on the CPU; prints no metric")
-    ap.add_argument("--keep-trace", action="store_true", help="leave the profiler's files in .bench_out")
+    ap.add_argument("--keep-trace", action="store_true",
+                    help="leave the profiler's files in .bench_out, and what the readers read beside them")
     args = ap.parse_args(argv)
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
     spec = resolve(args.workload, args.rehearse)
     cfg, traffic = spec["config"], spec["traffic"]
 
-    import serving
     import textgen
 
+    system_kind = spec["system"] = load_module("systems", cfg.get("system", "vector_store"))
+    for name in ("System", "parse_reply", "good", "judge", "CONTROLS", "COMPILE_COUNTERS", "metric_context"):
+        assert hasattr(system_kind, name), f"{system_kind.__file__} gives no {name}"  # before any set-up
     hooks = [load_module("hooks", name) for name in cfg.get("hooks", [])]
     for hook in hooks:
         if hasattr(hook, "before_server"):
             hook.before_server(cfg, log)
+    t0 = time.monotonic()
     device = device_report(args.rehearse, int(spec["cell"]["chips"]))
+    device_s = time.monotonic() - t0  # importing jax and the runtime's start: the part of set-up that moves most
     import jax
 
     peaks = load_json("peaks.json")
@@ -326,21 +272,21 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
 
     docs = textgen.documents(cfg["corpus"], args.seed)
-    system = serving.System(cfg, args.seed, free_port(), docs, log)
+    system = system_kind.System(cfg, args.seed, free_port(), docs, log)
     system.wait_ready()
-    system.warm_up(int(traffic["k"]), int(traffic["warm_max_batch"]))
+    system.warm_up(traffic)
     for hook in hooks:
         if hasattr(hook, "after_ready"):
             hook.after_ready(system, log)
-    log("set-up parts (s): " + json.dumps({k: round(v, 2) for k, v in system.timings.items()}))
-    k = int(traffic["k"])
+    parts = {"device_s": device_s, **system.timings}
+    log("set-up parts (s): " + json.dumps({k: round(v, 2) for k, v in parts.items()}))
 
     if args.sweep:
         for i, rate in enumerate(float(r) for r in args.sweep.split(",")):
             c0 = system.counters()
             gen = run_generator(spec, system, args.seed + i, args.seconds, rate, f"sweep{i}")
             c1 = system.counters()
-            st = window_stats(gen, k)
+            st = window_stats(gen, spec)
             done = sorted(r["done"] for r in st["window"] if r["done"] is not None)
             half = args.seconds / 2
 
@@ -361,7 +307,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 "inflight_mid": inflight_mid, "inflight_end": inflight_end,
                 "last_done_s": done[-1] if done else None,
                 "late_p95_ms": percentile(st["late_ms"], 0.95),
-                "rows_per_tick": (c1["svc_rows"] - c0["svc_rows"]) / max(c1["svc_ticks"] - c0["svc_ticks"], 1.0),
+                "counters": {n: c1[n] - c0.get(n, 0.0) for n in c1 if c1[n] != c0.get(n)},
                 "device": device}), flush=True)
         return 0
 
@@ -369,10 +315,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         for i in range(args.calibrate):
             before = system.counters()
             gen = run_generator(spec, system, args.seed + 1 + i, args.seconds, None, f"cal{i}")
-            st = window_stats(gen, k)
+            st = window_stats(gen, spec)
             correct, table, controls = judge_window(
                 spec, system, st, args.seed + 1 + i, before, system.counters(),
-                controls=tuple(CONTROLS) if i < 4 else ())
+                controls=tuple(system_kind.CONTROLS) if i < 4 else ())
             values = lambda t: {n: v["value"] for n, v in t.items()}
             print(json.dumps({"calibrate_seed": args.seed + 1 + i, "attempted": st["attempted"],
                               "failed": st["failed"], "late_p95_ms": percentile(st["late_ms"], 0.95),
@@ -392,7 +338,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     gen = run_generator(spec, system, args.seed, args.seconds, None, "run", during)
     after = system.counters()
-    stats = window_stats(gen, k)
+    stats = window_stats(gen, spec)
     in_window = [t for t in compile_events if gen["start_at"] <= t <= gen["start_at"] + args.seconds]
     mem = jax.devices()[0].memory_stats() or {}
     device["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
@@ -404,8 +350,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     ctx: Dict[str, Any] = {
         "spec": spec, "stats": stats, "gen": gen, "setup_s": setup_s, "seconds": args.seconds,
         "counters_before": before, "counters_after": after, "peaks": peaks.get(device["kind"]),
-        "percentile": percentile, "search_time": search_time, "trace": None, "work": load_module("work", cfg["work"]),
-        "n_rows": int(cfg["corpus"]["total_rows"]),
+        "percentile": percentile, "trace": None,
+        "work": load_module("work", cfg["work"]) if "work" in cfg else None,
+        **system_kind.metric_context(cfg),
     }
     breakdown: Dict[str, Any] = {}
     if args.trace and not args.rehearse:
@@ -428,10 +375,20 @@ def main(argv: Optional[List[str]] = None) -> int:
     t0 = time.monotonic()
     correct, table, _ = judge_window(spec, system, stats, args.seed, before, after)
     log(f"reference and comparison: {time.monotonic() - t0:.1f} s (not part of setup_s)")
-    metrics = {} if args.rehearse else read_metrics(spec["per_layer"] if args.trace else spec["end_to_end"], ctx)
+    metrics = read_metrics(spec["per_layer"] if args.trace else spec["end_to_end"], ctx)
+    if args.keep_trace and ctx["trace"] is not None:  # what the readers read, beside the profiler's files
+        import gzip
+
+        with gzip.open(os.path.join(trace_dir, "window.json.gz"), "wt") as f:
+            records = [{k: v for k, v in r.items() if k not in ("body", "answer")} for r in gen["records"]]
+            json.dump({"from": f"run.py {' '.join(sys.argv[1:])} on one {device['kind']}",
+                       "start_at": gen["start_at"], "trace_span": ctx["trace_span"],
+                       "spans": ctx.get("spans_in_window"), "records": records,
+                       "counters_before": before, "counters_after": after, "metrics": metrics}, f)
     result = {"correct": bool(correct), "attempted": stats["attempted"], "failed": stats["failed"],
               "metrics": metrics, "device": device, **breakdown}
-    if args.rehearse:
+    if args.rehearse:  # the readers have run on what a CPU run leaves; a CPU number is never reported
+        result["metrics"], result["read_not_reported"] = {}, sorted(metrics)
         result["rehearsal"] = "tiny size on the CPU: no metric is reported"
     result["compared"] = table
     for name, row in table.items():

@@ -1,6 +1,13 @@
-"""Set-up of the system under test: ``VectorStoreServer`` in a thread of this process.
+"""The system under test ``vector_store``: ``VectorStoreServer`` in a thread of this
+process, a reply that is "embed the query, then the cosine top-k over all rows",
+and what belongs to that reply alone: how it is read, the plain reference it is
+judged by (``reference.py`` over the inputs of ``weights.py``), the numbers
+compared (``compare.compare``) and the controls. ``run.py`` finds this file by
+the configuration's ``"system"`` key (this one where it has none) and asks for
+``System``, ``parse_reply``, ``good``, ``judge``, ``CONTROLS``, ``COMPILE_COUNTERS``,
+``metric_context``.
 
-Copied from ``chip_smoke.py:serve_phase`` (proven on the chip in PR 21), not
+The set-up is copied from ``chip_smoke.py:serve_phase`` (proven on the chip in PR 21), not
 imported: the server is built through its normal constructor, the index through
 its normal factory, and the engine-built index instance is kept through the
 factory hook. What the benchmark adds is the run's inputs: the encoder's weights
@@ -22,21 +29,46 @@ from typing import Any, Callable, Dict, List
 
 import numpy as np
 
+import compare
 import loadgen
 import reference
+import trace_reduce
 import weights as weights_mod
 
 READY_DEADLINE_S = 1100.0  # a cold first run compiles 20 encoder buckets
+parse_reply = compare.parse_reply  # a ``/v1/retrieve`` body as (document, text, score) per rank
+
+# what --calibrate puts in the program's place: (the encoder's precision, the
+# scoring's precision, whether the resident rows are scanned)
+CONTROLS = {
+    "fp8_encoder": ("fp8", "f32", True),   # the control: the nearest precision below bfloat16
+    "fp8_index": ("f32", "fp8", True),     # the same step down in the index's scoring passes
+    "int8_encoder": ("int8", "f32", True),  # per-tensor int8, read beside the control
+    "live_rows_only": ("f32", "f32", False),  # a guarantee broken: resident rows left out
+}
+
+
+# the counters whose growth is a program compiled: every search kernel's cache, the encoder's pre-warm
+COMPILE_COUNTERS = ("kernel.", "svc_prewarm_compiles")
+
+
+def good(answer: compare.Answer, traffic: Dict[str, Any]) -> bool:
+    """A reply counts where it could be read and holds ``k`` entries."""
+    return answer is not None and len(answer) == asked_k(traffic)
+
+
+def asked_k(traffic: Dict[str, Any]) -> int:
+    """The ``k`` every request of the traffic asks for: a fixed field of its body."""
+    return int(traffic["request"]["fixed"]["k"])
 
 
 def index_factory(cfg: Dict[str, Any], embedder: Any) -> Any:
     """The program's index factory the configuration names, with its arguments."""
     from pathway_tpu.stdlib.indexing import nearest_neighbors as nn
 
-    spec = cfg["index"]
-    args = dict(spec["args"])
+    args = dict(cfg["index"]["args"])
     args["metric"] = nn.BruteForceKnnMetricKind[args["metric"]]
-    return getattr(nn, spec["factory"])(embedder=embedder, **args)
+    return getattr(nn, cfg["index"]["factory"])(embedder=embedder, **args)
 
 
 def post(port: int, route: str, payload: dict, timeout: float = 60.0) -> Any:
@@ -190,46 +222,32 @@ class System:
                  f"store data on {platform}; pre-warm {svc.prewarm_compiles}/{self.prewarm_buckets} "
                  f"buckets in {svc.prewarm_s:.1f} s (set-up: {self.timings['ready_s']:.1f} s to ready)")
 
-    def warm_up(self, k: int, max_batch: int) -> None:
+    def warm_up(self, traffic: Dict[str, Any]) -> None:
         """Compile what the window will run before it runs: the search program
-        for every padded query bucket, then, for every batch size up to
-        ``max_batch``, the small programs the serving path compiles per batch
-        size (slice the encoder's output, stack the rows, pad to the bucket), by
-        driving the program's own query path (``embed_query_rows`` into
-        ``search_many``) with that many unique texts; last a few bursts over
-        HTTP, so that REST, the commit and the reply have run too."""
+        for every padded query bucket (the encoder's buckets are the program's
+        own pre-warm), then a few bursts over HTTP on the traffic's own route,
+        so that REST, the commit and the reply have run too."""
         t0 = time.monotonic()
-        dim = self.cfg["model"]["hidden_size"]
+        k, dim = asked_k(traffic), self.cfg["model"]["hidden_size"]
         for q in self.cfg["index"]["warm_query_buckets"]:
             self.store.search_batch(np.full((q, dim), 1.0 / np.sqrt(dim), np.float32), k)
         self.timings["search_compile_s"] = time.monotonic() - t0
         t0 = time.monotonic()
+        request = traffic["request"]
         n = 0
-
-        def texts(count: int) -> List[str]:
-            nonlocal n
-            n += count
-            return [f"{self.docs[(n + j) % len(self.docs)].split(' ', 1)[1][:40]} warm{n + j}"
-                    for j in range(count)]
-
-        index = self.built[0]
-        for size in range(1, max_batch + 1):
-            rows = self.embedder.pipeline.embed_query_rows(texts(size))
-            got = index.search_many(rows, [k] * size, None)
-            assert len(got) == size and all(len(g) == k for g in got), (size, [len(g) for g in got])
-        self.timings["warm_sizes_s"] = time.monotonic() - t0
-        t0 = time.monotonic()
         for burst in (1, 4, 16):
-            reqs = [{"i": j, "phase": "warm", "due": 0.0, "k": k, "query": q}
-                    for j, q in enumerate(texts(burst))]
-            recs = asyncio.run(loadgen.drive(reqs, "127.0.0.1", self.port, "/v1/retrieve",
-                                              time.monotonic(), 120.0))
+            reqs = [{"i": j, "phase": "warm", "due": 0.0, **request["fixed"],
+                     "query": f"{self.docs[(n + j) % len(self.docs)].split(' ', 1)[1][:40]} warm{n + j}"}
+                    for j in range(burst)]
+            n += burst
+            recs = asyncio.run(loadgen.drive(reqs, "127.0.0.1", self.port, request, time.monotonic(), 120.0))
             bad = [r for r in recs if r["status"] != 200]
             assert not bad, f"warm-up burst of {burst}: {bad[0]}"
         self.timings["warm_http_s"] = time.monotonic() - t0
 
     def counters(self) -> Dict[str, float]:
-        """The program's own counts, read before and after the window."""
+        """The program's own counts, read before and after the window: the
+        search kernels' cache sizes and the embed pipeline's numbers."""
         from pathway_tpu.ops.knn import kernel_cache_sizes
 
         out = {f"kernel.{k}": float(v) for k, v in kernel_cache_sizes().items()}
@@ -237,3 +255,72 @@ class System:
             if isinstance(value, (int, float)) and not isinstance(value, bool):
                 out[name] = float(value)
         return out
+
+
+def reference_check(spec: Dict[str, Any], system: System, sample: List[dict], controls=()):
+    """The plain reference over the sampled queries: its embeddings of the live
+    documents (made in set-up, where the resident rows' neighbours are drawn
+    around them) and of the queries, and its exact top-k over ALL rows (live and
+    resident, the resident ones drawn again from the seed block by block). For
+    each name in ``controls`` also that control's answers, in the program's place."""
+    cfg, k = spec["config"], asked_k(spec["traffic"])
+    model, corpus = cfg["model"], cfg["corpus"]
+    queries = [r["query"] for r in sample]
+    query_vecs = reference.embed_texts(system.weights, queries, model)
+    n_res, block = int(corpus["resident_rows"]), int(corpus["install_block_rows"])
+
+    def resident(b: int, lo: int):
+        return lambda: (system.resident_block(b)[: n_res - lo], len(system.docs) + lo)
+
+    def blocks(doc_vecs, with_resident: bool = True):
+        rest = [resident(b, lo) for b, lo in enumerate(range(0, n_res, block))] if with_resident else []
+        return [lambda: (doc_vecs, 0)] + rest
+
+    ref_topk, ref_ids = reference.exact_topk(query_vecs, blocks(system.doc_vecs), k)
+    out = {"ref_scores": reference.cosine_to(query_vecs, system.doc_vecs), "ref_topk": ref_topk,
+           "ref_ids": ref_ids, "control_answers": {}}
+    for name in controls:
+        encoder, scoring, with_resident = CONTROLS[name]
+        docs_low, queries_low = system.doc_vecs, query_vecs
+        if encoder != "f32":
+            docs_low = reference.embed_texts(system.weights, system.docs, model, encoder)
+            queries_low = reference.embed_texts(system.weights, queries, model, encoder)
+        scores, ids = reference.exact_topk(queries_low, blocks(docs_low, with_resident), k, scoring)
+        out["control_answers"][name] = compare.answers_from(ids, scores, system.docs)
+    return out
+
+
+def judge(spec: Dict[str, Any], system: System, sample: List[dict], controls=()):
+    """The numbers ``compare.compare`` reads off the sampled replies against
+    the reference, and off each control's answers in their place: (the
+    program's numbers, {control: its numbers}). ``run.py`` holds each to the
+    cell's limits."""
+    k = asked_k(spec["traffic"])
+    ref = reference_check(spec, system, sample, controls)
+    n_live, block = len(system.docs), int(spec["config"]["corpus"]["install_block_rows"])
+    res_ids = ref["ref_ids"][ref["ref_ids"] >= n_live] - n_live
+    system.log(f"reference top-{k} of {len(sample)} sampled queries: {res_ids.size} of {ref['ref_ids'].size} "
+               f"entries are resident rows, {len(set(res_ids.tolist()))} distinct, from install blocks "
+               f"{sorted(set((res_ids // block).tolist()))}")
+    judged = lambda answers: compare.compare(answers, k, system.docs, ref["ref_scores"], ref["ref_topk"])
+    return judged([r["answer"] for r in sample]), {n: judged(a) for n, a in ref["control_answers"].items()}
+
+
+def search_time(ctx: Dict[str, Any]):
+    """(device seconds, calls, queries per call) of the configuration's search
+    programs inside the traced span; None where the trace shows none."""
+    if ctx.get("trace") is None:
+        return None
+    seconds, calls = trace_reduce.program_seconds(ctx["trace"], ctx["spec"]["config"]["search_programs"])
+    if calls <= 0 or seconds <= 0:
+        return None
+    span = ctx["trace_span"]
+    served = sum(1 for r in ctx["gen"]["records"]
+                 if r["done"] is not None and r["status"] == 200 and span["t0"] <= r["done"] < span["t1"])
+    return seconds, calls, served / calls
+
+
+def metric_context(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """What this system's metric readers need beyond the common context: the
+    rows a search scans, and the search programs' time in the trace."""
+    return {"n_rows": int(cfg["corpus"]["total_rows"]), "search_time": search_time}

@@ -1,6 +1,7 @@
 """A rehearsal run with the timed path broken underneath: ``--fault <name>`` plants
 one fault in the program, then the rest of ``run.py`` runs as always (at the tiny
-CPU size, skipping only the look for a chip) and has to print ``correct: false``.
+CPU size, skipping only the look for a chip), through the cell's own system
+module (``systems/vector_store.py``), and has to print ``correct: false``.
 
     python3 benchmarks/tests/faulty_run.py --fault shifted_ranks --workload serve-dense-2m
 

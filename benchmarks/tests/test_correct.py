@@ -5,7 +5,9 @@ The controls' and the program's readings at the cell's own size are chip runs
 (``run.py --calibrate``; PERF.md lists them). Here the same comparison and the
 same limits are held at a size a test run can hold: the tiny CPU rehearsal for
 the planted faults, the full-width encoder over 96 documents and 2,048 resident
-rows for the controls.
+rows for the controls. Both go through the ``vector_store`` system's module
+(``systems/vector_store.py``): the faults through its ``judge`` under ``run.py``,
+the controls by its ``CONTROLS`` table.
 """
 
 import json
@@ -45,7 +47,8 @@ def run_faulty(cell, fault):
 def test_a_planted_fault_reads_not_correct(fault):
     result = run_faulty("serve-dense-2m", fault)
     assert result["correct"] is False
-    over = [n for n, row in result["compared"].items() if row["value"] > row["limit"]]
+    # on a stalled test machine ``compiles_in_window`` is printed with no limit: not judged
+    over = [n for n, row in result["compared"].items() if row["limit"] is not None and row["value"] > row["limit"]]
     assert over, result["compared"]
     if fault in ("live_rows_only", "last_block_only"):
         # whole replies of k ordered entries: only the scores say that rows were left out
@@ -96,16 +99,18 @@ def judged(world, cell, scores, ids):
 @pytest.mark.parametrize("control", ["fp8_encoder", "fp8_index"])
 def test_a_float8_control_fails_the_cells_limits(cell, control, small_world):
     """The reference one precision down, in the encoder's products or in the
-    index's scoring, in the program's place: its answers against the float32
-    reference's fail at least one number under the cell's own limits."""
+    index's scoring (the system's ``CONTROLS`` say which), in the program's
+    place: its answers against the float32 reference's fail at least one number
+    under the cell's own limits."""
     import reference
+    import run
 
     w = small_world
-    if control == "fp8_encoder":
-        scores, ids = reference.exact_topk(w["embed"](w["queries"], "fp8"), w["blocks"](w["embed"](w["docs"], "fp8")), w["k"])
-    else:
-        scores, ids = reference.exact_topk(w["ref_q"], w["blocks"](w["ref_docs"]), w["k"], "fp8")
-    correct, table = judged(w, cell, scores, ids)
+    encoder, scoring, _ = run.load_module("systems", "vector_store").CONTROLS[control]
+    docs, queries = w["ref_docs"], w["ref_q"]
+    if encoder != "f32":
+        docs, queries = w["embed"](w["docs"], encoder), w["embed"](w["queries"], encoder)
+    correct, table = judged(w, cell, *reference.exact_topk(queries, w["blocks"](docs), w["k"], scoring))
     assert not correct, table
 
 

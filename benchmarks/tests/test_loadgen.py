@@ -12,7 +12,10 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import loadgen  # noqa: E402
 
-TRAFFIC = {"arrival": "exponential", "rate_rps": 40, "k": 10, "pool_seed": 1,
+with open(os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "workloads",
+                       "serve-dense-2m.json")) as f:
+    DENSE_REQUEST = json.load(f)["request"]  # the dense cell's own: /v1/retrieve, {"query", "k": 10}
+TRAFFIC = {"arrival": "exponential", "rate_rps": 40, "pool_seed": 1, "request": DENSE_REQUEST,
            "query_words": {"min": 3, "max": 12, "mean": 6}}
 CORPUS = {"live_docs": 64, "doc_words": {"min": 20, "max": 120, "mean": 56}, "vocab_words": 20000,
           "pool_seed": 1}
@@ -70,7 +73,8 @@ def test_latency_runs_from_the_due_instant():
         # three requests all due 0.3 s BEFORE the generator starts: sent late, and
         # the lateness is part of the latency and reported beside it
         reqs = [{"i": i, "phase": "window", "due": -0.3, "query": f"q{i}", "k": 1} for i in range(3)]
-        recs = asyncio.run(loadgen.drive(reqs, "127.0.0.1", server.server_address[1], "/x",
+        recs = asyncio.run(loadgen.drive(reqs, "127.0.0.1", server.server_address[1],
+                                          {"route": "/x", "text_key": "query", "fixed": {"k": 1}},
                                           time.monotonic(), 10.0))
     finally:
         server.shutdown()
@@ -79,6 +83,44 @@ def test_latency_runs_from_the_due_instant():
         assert r["sent"] - r["due"] >= 0.3  # how late it was sent
         assert r["done"] - r["due"] >= 0.35  # due -> reply, the wait included
         assert r["done"] - r["sent"] >= 0.05
+
+
+class _Echo(_Slow):
+    """Replies with the path and the body it was sent, as it was sent."""
+
+    def do_POST(self):
+        sent = self.rfile.read(int(self.headers["Content-Length"])).decode()
+        body = json.dumps({"path": self.path, "sent": sent}).encode()
+        self.send_response(200)
+        self.send_header("Content-Length", str(len(body)))
+        self.end_headers()
+        self.wfile.write(body)
+
+
+def test_the_traffics_request_group_names_the_route_and_the_bodys_fields():
+    """The text goes under the group's key, the fixed fields after it, to its
+    route: for the dense cell's group the body is what it always was, byte for byte."""
+    server = http.server.ThreadingHTTPServer(("127.0.0.1", 0), _Echo)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    other = dict(TRAFFIC, request={"route": "/v2/rank", "text_key": "prompt", "fixed": {"top": 3, "greedy": True}})
+    try:
+        got = {}
+        for name, traffic in (("dense", TRAFFIC), ("other", other)):
+            reqs = loadgen.schedule(traffic, CORPUS, 5, 0.1)
+            recs = asyncio.run(loadgen.drive(reqs, "127.0.0.1", server.server_address[1],
+                                             traffic["request"], time.monotonic(), 10.0))
+            got[name] = [(r, json.loads(r["body"])) for r in recs]
+    finally:
+        server.shutdown()
+    assert len(got["dense"]) == len(got["other"]) == 4
+    for r, echo in got["dense"]:
+        assert r["k"] == 10 and echo["path"] == "/v1/retrieve"
+        assert echo["sent"] == json.dumps({"query": r["query"], "k": 10})
+    for r, echo in got["other"]:
+        assert "k" not in r and r["top"] == 3 and echo["path"] == "/v2/rank"
+        assert echo["sent"] == json.dumps({"prompt": r["query"], "top": 3, "greedy": True})
+    # the route and the fields change nothing of the schedule
+    assert [(r["due"], r["query"]) for r, _ in got["dense"]] == [(r["due"], r["query"]) for r, _ in got["other"]]
 
 
 def test_the_generator_never_imports_jax():

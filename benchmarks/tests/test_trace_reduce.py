@@ -1,5 +1,6 @@
-"""The trace reduction on a hand-made event list, and on a cut of a recorded
-chip trace where one is kept beside this file (``trace_cut.json``)."""
+"""The trace reduction on a hand-made event list, and, with every per-layer
+reader, on a cut of a recorded chip run where one is kept beside this file
+(``trace_cut.json``, made by ``record_cut.py``)."""
 
 import json
 import os
@@ -8,7 +9,9 @@ import sys
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import trace_reduce  # noqa: E402
+from record_cut import events_of  # noqa: E402
 
 P, M, O = "/device:TPU:0", trace_reduce.MODULE_LINE, trace_reduce.OP_LINE
 MS = 1_000_000
@@ -62,40 +65,56 @@ def test_two_planes_average():
     assert r["busy_s"] == pytest.approx((0.025 + 0.010) / 2)
 
 
-def test_recorded_chip_trace_cut():
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "trace_cut.json")
-    if not os.path.exists(path):
+CUT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "trace_cut.json")
+
+
+@pytest.fixture(scope="module")
+def cut():
+    """A quarter second of a traced chip run, as ``record_cut.py`` keeps it: the
+    profiler's events, the program's spans, the generator's records and the
+    counters, with what the readers returned over the whole traced span."""
+    if not os.path.exists(CUT):
         pytest.skip("no recorded trace kept")
-    events = [tuple(e) for e in json.load(open(path))]
-    r = trace_reduce.reduce(events, window_s=0.25)
-    assert r["planes"] == 1 and 0.0 < r["busy_s"] <= 0.25 * 1.05
-    seconds, calls = trace_reduce.program_seconds(r, ["^jit__search_kernel$"])
-    assert calls >= 1 and 0.0 < seconds <= r["busy_s"] * 1.01
+    with open(CUT) as f:
+        return json.load(f)
 
 
-def test_per_layer_readers_on_the_recorded_cut():
+def test_recorded_chip_trace_cut(cut):
+    events = events_of(cut)
+    r = trace_reduce.reduce(events, window_s=cut["seconds"])
+    assert r["planes"] == 1 and 0.0 < r["busy_s"] <= cut["seconds"] * 1.05
+    for program in ("^jit__search_kernel$", "^jit_encoder_forward$"):
+        seconds, calls = trace_reduce.program_seconds(r, [program])
+        assert calls >= 1 and 0.0 < seconds <= r["busy_s"] * 1.01
+    annotations = {e[2] for e in events if e[0] == "/host:CPU"}
+    assert {"pw.commit", "pw.search", "pw.search.device_wait", "pw.encode.device_wait"} <= annotations
+    assert {s["kind"] for s in cut["spans"]} >= {"rest", "admit", "queue", "commit", "embed_wait", "search", "reply"}
+
+
+def test_per_layer_readers_on_the_recorded_cut(cut):
     """Every per-layer reader of the dense cell finds something to read in the
-    recorded cut, and returns nothing (not 0) where there is no trace."""
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "trace_cut.json")
-    if not os.path.exists(path):
-        pytest.skip("no recorded trace kept")
+    recorded cut, close to what it read over the whole traced span where a
+    quarter second can say, and returns nothing (not 0) where there is no trace."""
     import run
 
     spec = run.resolve("serve-dense-2m", False)
-    events = [tuple(e) for e in json.load(open(path))]
-    records = [{"done": 0.01 * i, "status": 200, "query": "a b c d", "due": 0.0, "sent": 0.001, "phase": "window"}
-               for i in range(12)]
+    cfg = spec["config"]
+    system_kind = run.load_module("systems", cfg.get("system", "vector_store"))
     ctx = {"spec": spec, "stats": {"latency_ms": [1.0, 2.0], "late_ms": [0.1], "good": 2},
-           "gen": {"records": records}, "setup_s": 1.0, "seconds": 40.0,
-           "counters_before": {"svc_ticks": 1.0, "svc_rows": 2.0}, "counters_after": {"svc_ticks": 3.0, "svc_rows": 9.0},
+           "gen": {"records": cut["records"], "start_at": 0.0}, "setup_s": 1.0, "seconds": 40.0,
+           "counters_before": cut["counters_before"], "counters_after": cut["counters_after"],
            "peaks": run.load_json("peaks.json")["TPU v5 lite"], "percentile": run.percentile,
-           "search_time": run.search_time, "trace": trace_reduce.reduce(events, 0.12),
-           "trace_span": {"t0": 0.0, "t1": 0.12}, "work": run.load_module("work", spec["config"]["work"]),
-           "n_rows": 2**21}
+           "trace": trace_reduce.reduce(events_of(cut), cut["seconds"]),
+           "trace_span": {"t0": 0.0, "t1": cut["seconds"]}, "spans": cut["spans"],
+           "work": run.load_module("work", cfg["work"]), **system_kind.metric_context(cfg)}
     got = run.read_metrics(spec["per_layer"], ctx)
-    assert set(got) == {m["name"] for m in spec["per_layer"]}
+    assert set(got) == {m["name"] for m in spec["per_layer"]} and len(got) == 16
     assert 0.0 < got["search_roofline"]["value"] <= 100.0 and 0.0 < got["retrieve_mfu"]["value"] < 100.0
-    assert got["encsvc_rows_per_tick"]["value"] == pytest.approx(3.5)
+    whole = cut["read_over_the_whole_span"]
+    for name in ("search_ms_per_call", "encoder_ms_per_call", "search_roofline", "encsvc_token_fill",
+                 "encsvc_rows_per_tick", "rest_admit_p50_ms", "search_host_p50_ms"):
+        assert got[name]["value"] == pytest.approx(whole[name]["value"], rel=0.25), name
+    del ctx["trace_span"]
     ctx["trace"] = None
     assert set(run.read_metrics(spec["per_layer"], ctx)) == {
-        "retrieve_p95_ms", "gen_late_p95_ms", "encsvc_rows_per_tick"}
+        "retrieve_p95_ms", "gen_late_p95_ms", "encsvc_rows_per_tick", "encsvc_token_fill"}
