@@ -71,6 +71,8 @@ RUNTIME_MODULES: Tuple[str, ...] = (
     "pathway_tpu/engine/brownout.py",
     "pathway_tpu/models/embed_pipeline.py",
     "pathway_tpu/models/encoder_service.py",
+    "pathway_tpu/models/device_worker.py",
+    "pathway_tpu/models/generation_service.py",
     "pathway_tpu/ops/knn_tiers.py",
     "pathway_tpu/ops/knn_quant.py",
     "pathway_tpu/engine/http_server.py",

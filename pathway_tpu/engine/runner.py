@@ -2644,13 +2644,13 @@ class GraphRunner:
         if self._http_server is not None:
             self._http_server.close()
             self._http_server = None
-        # stop idle encoder-service workers (drain + join): teardown must not
+        # stop idle device-service workers (drain + join): teardown must not
         # leave a device-owning thread behind a finished run — services stay
         # usable, the worker respawns lazily on the next submit. Module never
         # imported = no services exist = nothing to stop.
         import sys as _sys
 
-        svc_mod = _sys.modules.get("pathway_tpu.models.encoder_service")
+        svc_mod = _sys.modules.get("pathway_tpu.models.device_worker")
         if svc_mod is not None:
             try:
                 svc_mod.stop_all_workers()

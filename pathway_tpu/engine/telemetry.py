@@ -87,6 +87,7 @@ STAGE_NAMESPACES: "tuple[str, ...]" = (
     "index.",       # tiered IVF index: tier hits, prefetch, rebuild/swap
     "index.quant.", # int8 retrieval: rescore batches, recalibrations, audits
     "lint.",        # graph/runtime lint diagnostics
+    "lm.",          # generation service: prefills, decode steps, rows, experts touched
     "modelcheck.",  # deterministic schedule exploration
     "persist.",     # checkpoints, journal compaction
     "replica.",     # read-replica fleet: feed, follow, serve/shed, failover
@@ -150,6 +151,11 @@ TRACE_SPAN_KINDS: "frozenset[str]" = frozenset({
     "encode.dispatch",  # ... tokenize, pad on the host, forward enqueued
     "exchange",      # mesh delta receive (links the sender's commit span)
     "fused_region",  # one fused chain executed as a single program
+    "generate",      # chat UDF: a prompt's submission to its tokens resolved
+    "lm.decode_step",  # generation service: one step over the slots (links its requests)
+    "lm.decode_step.device_wait",  # ... the fetch of the step's tokens
+    "lm.prefill",    # generation service: one prompt into one slot
+    "lm.prefill.device_wait",  # ... the fetch of the first token
     "loop_wait",     # commit loop between commits, waiting for a source's push
     "operator",      # one evaluator run (synthesized from CommitProfile ops)
     "queue",         # a query row's wait: source.push to its commit's start

@@ -66,14 +66,14 @@ import logging
 import os
 import threading
 import time
-import weakref
-from collections import OrderedDict, deque
+from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from pathway_tpu.engine import telemetry
 from pathway_tpu.engine import tracing as _tracing
+from pathway_tpu.models.device_worker import DeviceWorker, stop_all_workers  # noqa: F401 (re-exported)
 from pathway_tpu.models.encoder import fetch_rows
 
 
@@ -254,21 +254,7 @@ class _Submission:
         self.error: Optional[BaseException] = None
 
 
-#: every live service, so ``pw.run`` teardown can stop idle workers without
-#: holding references that would keep dead pipelines alive
-_services: "weakref.WeakSet[EncoderService]" = weakref.WeakSet()
-
-
-def stop_all_workers(timeout_s: float = 10.0) -> None:
-    """Stop (drain + join) every live service's worker and pre-warm threads.
-    Called from ``GraphRunner.finish`` so back-to-back runs and interpreter
-    shutdown never hold a device-owning thread; services stay usable — the
-    worker respawns lazily on the next submit."""
-    for svc in list(_services):
-        svc.stop_worker(timeout_s=timeout_s)
-
-
-class EncoderService:
+class EncoderService(DeviceWorker):
     """Persistent continuous-batching worker in front of one encoder.
 
     ``submit(texts)`` blocks until the worker answers with one row value per
@@ -285,6 +271,8 @@ class EncoderService:
     ``retry_after_s`` probes so the REST plane's 429 + Retry-After semantics
     are unchanged."""
 
+    _thread_name = "pathway:encsvc-worker"
+
     def __init__(
         self,
         encoder: Any,
@@ -297,6 +285,7 @@ class EncoderService:
         prewarm_max_batch: int | None = None,
         after_batch: Callable[[List[str], Sequence[Any]], None] | None = None,
     ):
+        super().__init__()
         self.encoder = encoder
         if tick_ms is None:
             tick_ms = _env_float("PATHWAY_ENCSVC_TICK_MS", 50.0)
@@ -312,14 +301,8 @@ class EncoderService:
         self.max_queue_rows = max(0, int(max_queue_rows))
         self._after_batch = after_batch
         self.wait_timeout_s = _env_float("PATHWAY_EMBED_WAIT_TIMEOUT_S", 0.0)
-        self._lock = threading.Lock()
-        self._cond = threading.Condition(self._lock)
-        self._queue: "deque[_Submission]" = deque()
         self._queued_rows = 0
         self._inflight_rows = 0
-        self._worker: threading.Thread | None = None
-        self._stop_requested = False
-        self._closed = False
         self._encode_ewma_s = 0.0
         # counters (mirrored batch-level into the telemetry stage counters)
         self.requests = 0
@@ -346,7 +329,6 @@ class EncoderService:
         if prewarm_max_batch is None:
             prewarm_max_batch = _env_int("PATHWAY_ENCSVC_PREWARM_MAX_BATCH", 64)
         self.prewarm_max_batch = max(8, int(prewarm_max_batch))
-        _services.add(self)
         if prewarm and self._prewarm_shapes():
             self._prewarm_thread = threading.Thread(
                 target=self._prewarm_run, name="pathway:encsvc-prewarm", daemon=True
@@ -477,17 +459,6 @@ class EncoderService:
         assert sub.rows is not None
         return sub.rows
 
-    def _ensure_worker_locked(self) -> None:
-        # _locked suffix = caller-holds-self._cond convention (submit/_await);
-        # the writes below are therefore lock-protected even though this frame
-        # takes no lock itself
-        if self._worker is None or not self._worker.is_alive():
-            self._stop_requested = False  # noqa: PWA103 (caller holds self._cond)
-            self._worker = threading.Thread(  # noqa: PWA103 (caller holds self._cond)
-                target=self._run, name="pathway:encsvc-worker", daemon=True
-            )
-            self._worker.start()
-
     def _await(self, sub: _Submission) -> None:
         """Abortable timed wait (PWA102): wakes every 0.25 s to observe
         teardown. A submission stranded with no worker (a stop/close raced the
@@ -597,13 +568,7 @@ class EncoderService:
             batch, depth = self._gather()
             if not batch:
                 with self._cond:
-                    # exit only with an empty queue (drain semantics); a
-                    # request appended after the final check respawns the
-                    # worker from submit()/_await()
-                    if (self._closed or self._stop_requested) and not self._queue:
-                        self._stop_requested = False
-                        self._worker = None
-                        self._cond.notify_all()
+                    if self._exit_if_stopping_locked():
                         return
                 continue
             t_tick = time.perf_counter()
@@ -695,33 +660,14 @@ class EncoderService:
     # -- lifecycle -----------------------------------------------------------
 
     def stop_worker(self, timeout_s: float = 10.0) -> None:
-        """Drain the queue and stop the worker, and abort a running pre-warm
-        (it cancels between bucket compiles; the join may still ride out ONE
-        in-flight compile). The service stays usable — the next submit
-        respawns the worker. Safe to call with requests in flight: every
-        admitted submission is still answered before the worker exits."""
+        """Drain the queue and stop the worker (``DeviceWorker.stop_worker``),
+        and abort a running pre-warm (it cancels between bucket compiles; the
+        join may still ride out ONE in-flight compile)."""
         self._prewarm_abort.set()
-        with self._cond:
-            worker = self._worker
-            if worker is not None and worker.is_alive():
-                self._stop_requested = True
-            self._cond.notify_all()
-        if worker is not None:
-            worker.join(timeout=timeout_s)
+        super().stop_worker(timeout_s=timeout_s)
         prewarm = self._prewarm_thread
         if prewarm is not None and prewarm is not threading.current_thread():
             prewarm.join(timeout=timeout_s)
-
-    def close(self, timeout_s: float = 10.0) -> None:
-        """Permanent, idempotent: drain, stop the worker, refuse new submits."""
-        with self._cond:
-            self._closed = True
-            self._cond.notify_all()
-        self.stop_worker(timeout_s=timeout_s)
-
-    def worker_alive(self) -> bool:
-        worker = self._worker
-        return worker is not None and worker.is_alive()
 
     # -- reporting -----------------------------------------------------------
 

@@ -2,6 +2,8 @@
 
 ``OpenAIChat`` (``:84``), ``LiteLLMChat`` (``:313``), ``HFPipelineChat`` (``:441``),
 ``CohereChat`` (``:544``) — async UDFs with capacity/retry/cache; clients gated at call time.
+``Lfm2Chat`` is the one that calls nothing out: an ``lfm2_moe`` decoder on this process's
+device behind a generation service (``models/lfm2.py``, ``models/generation_service.py``).
 """
 
 from __future__ import annotations
@@ -190,6 +192,84 @@ class CohereChat(BaseChat):
             return response.text, cited_documents
 
         self.func = chat
+
+
+class Lfm2Chat(BaseChat):
+    """A chat model on the device: the ``lfm2_moe`` decoder (LFM2-8B-A1B's
+    family, ``models/lfm2.py``) behind a slot-based ``GenerationService``.
+
+    Its limits: greedy decoding, exactly ``max_new_tokens`` tokens a reply, no
+    stop token; the tokenizer is the repository's ``HashTokenizer`` (one token a
+    lower-cased whitespace word, no vocabulary file), so the reply is the
+    generated ids written as words, ``t<id>`` each (``reply_ids`` reads them
+    back); the weights are random from ``seed`` unless ``params`` (a tree of
+    ``models/lfm2.param_shapes``) is given. ``config`` is a published
+    ``config.json`` as a dict, cut to what the chip holds: LFM2-8B-A1B's 24
+    layers are 16.7 GB in bfloat16, over one chip's 16, so there is no default
+    (``benchmarks/configs/lfm2-8b-a1b-rag.json`` serves its layers 0-13). A prompt
+    longer than ``max_prompt_tokens`` keeps its last tokens. The messages are
+    rendered as their contents, one a line: there is no chat template without
+    the checkpoint's tokenizer."""
+
+    def __init__(
+        self,
+        config: dict,
+        params: Any = None,
+        *,
+        slots: int = 16,
+        max_prompt_tokens: int = 1024,
+        max_new_tokens: int = 32,
+        prefill_buckets: tuple = (256, 512, 1024),
+        seed: int = 0,
+        cache_strategy: CacheStrategy | None = None,
+    ):
+        super().__init__(executor=async_executor(), cache_strategy=cache_strategy)
+        # the device is touched here, never at import: a process that builds no such chat loads no jax
+        from pathway_tpu.models.encoder import HashTokenizer
+        from pathway_tpu.models.generation_service import GenerationService
+        from pathway_tpu.models.lfm2 import Lfm2Config, Lfm2Decoder
+
+        self.config = Lfm2Config.from_dict(config)
+        self.decoder = Lfm2Decoder(
+            self.config, params, slots=slots, max_prompt_tokens=max_prompt_tokens,
+            max_new_tokens=max_new_tokens, prefill_buckets=prefill_buckets, seed=seed,
+        )
+        self.service = GenerationService(self.decoder)
+        self._tokenizer = HashTokenizer(vocab_size=self.config.vocab_size, max_length=1 << 30)
+
+        async def chat(messages: Any, **kwargs: Any) -> str:
+            import asyncio
+
+            from pathway_tpu.engine import tracing
+
+            ids = self.tokenize(self.render(messages))
+            tracer = tracing.get_tracer()
+            # lives across the await, so a span and never an annotation
+            span = tracer.start("generate", attrs={"prompt_tokens": len(ids)})
+            try:
+                future = self.service.submit(ids, ctx=span.context() if span is not None else None)
+                tokens = await asyncio.wrap_future(future)
+            finally:
+                if span is not None:
+                    tracer.finish(span)
+            return " ".join(f"t{t}" for t in tokens)
+
+        self.func = chat
+
+    @staticmethod
+    def render(messages: Any) -> str:
+        return "\n".join(str(m.get("content", "")) for m in _coerce_messages(messages))
+
+    def tokenize(self, text: str) -> List[int]:
+        """One id a word (``HashTokenizer`` without its [CLS]/[SEP]), the last
+        ``max_prompt_tokens`` of them."""
+        ids, _ = self._tokenizer([text])
+        return ids[0, 1:-1].tolist()[-self.decoder.max_prompt_tokens :]
+
+    @staticmethod
+    def reply_ids(reply: str) -> List[int]:
+        """The generated ids of a reply of this chat, exactly."""
+        return [int(word[1:]) for word in reply.split()]
 
 
 def prompt_chat_single_qa(question: str) -> Json:

@@ -77,3 +77,22 @@ def test_encoder_forward_lowers_for_tpu():
     enc = JaxSentenceEncoder("pw-test-tiny", config=tiny, max_length=64)
     exported = _export_tpu(enc._encode_ids, enc.params, S((8, 16), jnp.int32))
     assert exported.out_avals[0].shape == (8, 64)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_generators_programs_lower_for_tpu_at_published_width(program):
+    """``lm_decode`` and ``lm_prefill`` of the ``lfm2_moe`` decoder at LFM2-8B-A1B's
+    widths (hidden 2,048, 32 experts of 1,792, top 4, vocabulary 65,536), one
+    period of its layer pattern, 16 slots: shapes only, nothing is allocated.
+    The expert products stay one grouped product each (XLA's ragged dot)."""
+    from pathway_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2Config(num_hidden_layers=6, layer_types=lfm2.PUBLISHED_LAYER_TYPES[:6])
+    params = lfm2.param_shapes(cfg)
+    state = jax.eval_shape(lambda: lfm2.init_state(cfg, 16, 1088))
+    if program == "decode":
+        exported = _export_tpu(functools.partial(lfm2.decode_logits, cfg=cfg), params, state, S((16,), jnp.bool_))
+    else:
+        exported = _export_tpu(functools.partial(lfm2.prefill_logits, cfg=cfg), params, state,
+                               S((256,), jnp.int32), S((), jnp.int32), S((), jnp.int32))
+    assert exported.mlir_module().count("ragged_dot") >= 3 * 4  # w1, w3, w2 of the four expert layers
