@@ -196,6 +196,7 @@ class StreamingDataSource(DataSource):
         self.loopback = loopback
         self._thread: threading.Thread | None = None
         self._autocommit_ms = autocommit_ms
+        self._next_commit_at = 0.0  # the end of the open autocommit window (time.monotonic)
         self._seq = 0
         # persistence: events consumed so far (journaled events count as consumed on
         # resume); deterministically re-pushed events dedup via segment-scoped skips
@@ -289,7 +290,7 @@ class StreamingDataSource(DataSource):
         self._requeued_state = []
         now = time_mod.monotonic()
         if (
-            now < getattr(self, "_next_commit_at", 0.0)
+            now < self._next_commit_at
             and not self._finished.is_set()
             and self.events.qsize() < self._MAX_EVENTS_PER_COMMIT
         ):
@@ -395,6 +396,11 @@ class StreamingDataSource(DataSource):
 
     def is_finished(self) -> bool:
         return self._finished.is_set() and self.events.empty()
+
+    def release_at(self) -> "float | None":
+        """When ``next_batch`` lets the queued events go (``time.monotonic``; in the
+        past once the autocommit window is over); None while nothing is queued."""
+        return None if self.events.empty() else self._next_commit_at
 
     # -- elastic membership (parallel/membership.py) --------------------------
 

@@ -508,7 +508,14 @@ class ExpressionEvaluator:
         self._memo_record(store, out)
         return _tidy(out)
 
-    _eval_FullyAsyncApplyExpression = _eval_AsyncApplyExpression
+    def _eval_FullyAsyncApplyExpression(self, e: expr.FullyAsyncApplyExpression) -> np.ndarray:
+        # Table.select lowers such a call onto the loop-back connector before any
+        # evaluator sees it (internals/fully_async.py); here no row can wait for one
+        raise TypeError(
+            "a fully_async UDF is called in select() or with_columns() of a table: its row "
+            "appears when the result is in. Select its result into a column first, or give "
+            "the UDF async_executor() to have it awaited inside the commit"
+        )
 
     def _eval_PointerExpression(self, e: expr.PointerExpression) -> np.ndarray:
         args = [self._eval(a) for a in e._args]

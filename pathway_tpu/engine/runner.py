@@ -2843,14 +2843,29 @@ class GraphRunner:
         runtime["global_source"] = getattr(self.graph, "_error_log_source", None)
         from pathway_tpu.engine.datasource import StreamingDataSource
 
-        # idle pacing: wake on producer pushes (latency = wake + one commit), with the
-        # smallest configured autocommit interval as the staleness cap. The wake event
-        # is per-runner so concurrent loops never consume each other's signals.
+        # idle pacing: a producer's push wakes the loop at once (latency = wake + one
+        # commit), events a source holds back inside its autocommit window are taken
+        # when the window ends, and otherwise the loop looks again every 10 ms. It
+        # does not tick at the smallest autocommit interval: an idle step is most of
+        # a millisecond of Python under the interpreter lock, and at the REST
+        # connector's 1 ms it took the lock from the generation service's thread
+        # while a request generated outside any commit (a decode step's host time
+        # 1.0 -> 2.2 ms on the chip, PERF.md section 6, PR 29). The wake event is
+        # per-runner so concurrent loops never consume each other's signals.
         idle_wait = 0.010
-        for node, _ in self._sources:
-            ms = getattr(node.config["source"], "_autocommit_ms", None)
-            if ms:
-                idle_wait = min(idle_wait, ms / 1000.0)
+        streams = [
+            node.config["source"]
+            for node, _ in self._sources
+            if isinstance(node.config["source"], StreamingDataSource)
+        ]
+
+        def idle_timeout() -> float:
+            held = [at for at in (s.release_at() for s in streams) if at is not None]
+            if not held:
+                return idle_wait
+            # never 0: a step that leaves queued events where they are must not spin
+            return min(idle_wait, max(0.0005, min(held) - time_mod.monotonic()))
+
         import threading as _threading
 
         wake = _threading.Event()
@@ -2941,7 +2956,7 @@ class GraphRunner:
                         if not any_output:
                             # keep stepping (peers may exchange into us), but pace
                             # the idle spin — barriers resume inside the next step
-                            wake.wait(timeout=idle_wait)
+                            wake.wait(timeout=idle_timeout())
                         continue
                     if local_done:
                         break
@@ -2950,7 +2965,7 @@ class GraphRunner:
                         # that device idle under it reads "no work", not "the
                         # host was busy with something unnamed"
                         with _tracing.trace_span("loop_wait"):
-                            wake.wait(timeout=idle_wait)
+                            wake.wait(timeout=idle_timeout())
         except BaseException as exc:
             # a failing run must be distinguishable from a clean close by sinks
             # that hand state to OTHER graphs (ExportedTable._fail) — finish()

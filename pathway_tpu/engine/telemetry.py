@@ -81,7 +81,7 @@ STAGE_NAMESPACES: "tuple[str, ...]" = (
     "brownout.",    # overload-degradation ladder rungs + quiesce
     "cluster.",     # mesh fences/rejoins/membership/reshard
     "embed.",       # embed pipeline, caches, encoder service (embed.svc.*)
-    "eval.",        # batch-UDF evaluation
+    "eval.",        # batch-UDF evaluation; fully_async calls out of a commit (_rows) and back (_returned)
     "exchange.",    # per-peer traffic + barrier waits/stragglers
     "fuse.",        # whole-commit fusion planner/jit
     "index.",       # tiered IVF index: tier hits, prefetch, rebuild/swap

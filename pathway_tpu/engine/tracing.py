@@ -266,6 +266,34 @@ def current_context() -> Optional[TraceContext]:
     return span.context() if span is not None else None
 
 
+class _AdoptedSpan:
+    """Stands in the context-local slot for a span that is open elsewhere (a
+    request's ``rest`` span on the event loop's thread)."""
+
+    __slots__ = ("_ctx",)
+
+    def __init__(self, ctx: TraceContext):
+        self._ctx = ctx
+
+    def context(self) -> TraceContext:
+        return self._ctx
+
+
+@contextlib.contextmanager
+def adopt_context(ctx: Optional[TraceContext]) -> Iterator[None]:
+    """Spans opened inside become children of ``ctx``: for work that carries a
+    request on after the commit that took its row has ended (the loop-back
+    connector's invocations), where no span is open around it."""
+    if ctx is None:
+        yield
+        return
+    token = _current_span.set(_AdoptedSpan(ctx))
+    try:
+        yield
+    finally:
+        _current_span.reset(token)
+
+
 # -- the tracer ---------------------------------------------------------------
 
 
@@ -600,6 +628,14 @@ class Tracer:
             while len(self._commit_links) >= _MAX_LINK_KEYS:
                 self._commit_links.popitem(last=False)
             self._commit_links[key] = (ctx, time.monotonic())
+
+    def commit_link_context(self, key: bytes) -> Optional[TraceContext]:
+        """The context registered under row key ``key`` and not taken yet: what
+        a sink inside the commit that takes the row reads, to carry the
+        request on past that commit (the commit takes its links at its end)."""
+        with self._lock:
+            link = self._commit_links.get(key)
+        return link[0] if link is not None else None
 
     def take_commit_links(
         self, keys: Iterable[bytes]

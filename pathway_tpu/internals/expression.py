@@ -203,6 +203,33 @@ class ColumnExpression(ABC):
         return out
 
 
+def rewrite(
+    e: ColumnExpression, replace: Callable[[ColumnExpression], "ColumnExpression | None"]
+) -> ColumnExpression:
+    """A copy of ``e`` in which every sub-expression for which ``replace`` gives
+    one is replaced by it, outermost first (what ``replace`` returns is not
+    walked again). Every expression around a replacement is a shallow copy;
+    ``e`` is not touched."""
+    import copy
+
+    replaced = replace(e)
+    if replaced is not None:
+        return replaced
+
+    def walk(value: Any) -> Any:
+        return rewrite(value, replace) if isinstance(value, ColumnExpression) else value
+
+    clone = copy.copy(e)
+    for attr, value in list(vars(e).items()):
+        if isinstance(value, ColumnExpression):
+            setattr(clone, attr, walk(value))
+        elif isinstance(value, tuple) and any(isinstance(v, ColumnExpression) for v in value):
+            setattr(clone, attr, tuple(walk(v) for v in value))
+        elif isinstance(value, dict) and any(isinstance(v, ColumnExpression) for v in value.values()):
+            setattr(clone, attr, {k: walk(v) for k, v in value.items()})
+    return clone
+
+
 ColumnExpressionOrValue = Any
 
 

@@ -143,6 +143,11 @@ class Table(Joinable):
             exprs[_name_of(arg)] = self._resolve(arg)
         for out_name, e in kwargs.items():
             exprs[out_name] = self._resolve(e)
+        from pathway_tpu.internals import fully_async
+
+        if any(fully_async.holds_call(e) for e in exprs.values()):
+            # a fully_async UDF's call leaves this commit: the row exists once its result is back
+            return fully_async.lower_select(self, exprs)
         node = G.add_node(pg.RowwiseNode(inputs=[self], exprs=exprs))
         out_schema = self._make_output_schema(exprs, "select")
         result = Table(node, out_schema, universe=self._universe, name="select")

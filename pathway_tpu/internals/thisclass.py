@@ -85,41 +85,16 @@ def substitute(e: Any, mapping: dict[type, Any]) -> Any:
 
 
 def _substitute(e: expr.ColumnExpression, mapping: dict[type, Any]) -> expr.ColumnExpression:
-    import copy
+    def replace(e: expr.ColumnExpression) -> "expr.ColumnExpression | None":
+        if isinstance(e, ThisColumnReference):
+            target = mapping.get(e._kind)
+            if target is None:
+                raise ValueError(f"cannot resolve {e!r} in this context")
+            return target.id if e._name == "id" else target[e._name]
+        if isinstance(e, expr.ColumnReference):
+            # a reference to a this-substituted table may itself need rebinding when the
+            # table participating in the op was replaced (e.g. ix); leave as-is
+            return e
+        return None
 
-    if isinstance(e, ThisColumnReference):
-        target = mapping.get(e._kind)
-        if target is None:
-            raise ValueError(f"cannot resolve {e!r} in this context")
-        if e._name == "id":
-            return target.id
-        return target[e._name]
-    if isinstance(e, expr.ColumnReference):
-        # a reference to a this-substituted table may itself need rebinding when the
-        # table participating in the op was replaced (e.g. ix); leave as-is
-        return e
-    clone = copy.copy(e)
-    for attr, value in list(vars(e).items()):
-        if isinstance(value, expr.ColumnExpression):
-            setattr(clone, attr, _substitute(value, mapping))
-        elif isinstance(value, tuple) and any(isinstance(v, expr.ColumnExpression) for v in value):
-            setattr(
-                clone,
-                attr,
-                tuple(
-                    _substitute(v, mapping) if isinstance(v, expr.ColumnExpression) else v
-                    for v in value
-                ),
-            )
-        elif isinstance(value, dict) and any(
-            isinstance(v, expr.ColumnExpression) for v in value.values()
-        ):
-            setattr(
-                clone,
-                attr,
-                {
-                    k: _substitute(v, mapping) if isinstance(v, expr.ColumnExpression) else v
-                    for k, v in value.items()
-                },
-            )
-    return clone
+    return expr.rewrite(e, replace)

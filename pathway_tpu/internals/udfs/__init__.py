@@ -218,12 +218,21 @@ def sync_executor() -> SyncExecutor:
 
 
 def async_executor(capacity: int | None = None, timeout: float | None = None, retry_strategy: AsyncRetryStrategy | None = None) -> AsyncExecutor:
+    """The coroutines of one commit's rows are gathered inside that commit: it ends
+    when the last of them has, and the rows that arrive meanwhile wait for the next."""
     ex = AsyncExecutor(capacity, timeout)
     ex.retry_strategy = retry_strategy  # type: ignore[attr-defined]
     return ex
 
 
 def fully_async_executor(capacity: int | None = None, timeout: float | None = None, autocommit_duration_ms: int | None = 100) -> FullyAsyncExecutor:
+    """The call leaves the commit that carried its arguments (``internals/fully_async.py``):
+    that commit ends at once, the coroutine runs on a loop of its own, and the result
+    re-enters the graph as a later commit through a loop-back source whose tick is
+    ``autocommit_duration_ms``. Such a UDF is called in ``select`` / ``with_columns``, and
+    the row of that select appears when its result is in: there is no pending value to
+    filter out, and a retracted row starts no call. Elsewhere the call is refused when
+    the graph runs."""
     return FullyAsyncExecutor(capacity, timeout, autocommit_duration_ms)
 
 
@@ -339,6 +348,7 @@ class UDF:
             e: expr.ApplyExpression = expr.FullyAsyncApplyExpression(
                 fun, ret, self.propagate_none, self.deterministic, args, kwargs, self.max_batch_size
             )
+            e.autocommit_duration_ms = self.executor.autocommit_duration_ms
         elif is_async:
             e = expr.AsyncApplyExpression(
                 fun, ret, self.propagate_none, self.deterministic, args, kwargs, self.max_batch_size

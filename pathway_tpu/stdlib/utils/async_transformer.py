@@ -11,6 +11,12 @@ failed), ``finished``, ``output_table``. Instance consistency: an (instance, tim
 group's results are released atomically, in time order per instance, only when every
 invocation of the group completed. ``with_options`` applies capacity / timeout /
 retry / cache around ``invoke`` (``internals/udfs`` strategies).
+
+A ``fully_async`` UDF's call is one more user of this connector
+(``internals/fully_async.py``). Where a row is a traced request's (a REST row whose
+commit link is registered), the invocation's spans are children of the request's
+span, and the commit that carries the result in links the request as the commit
+that took the row did.
 """
 
 from __future__ import annotations
@@ -21,10 +27,12 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, ClassVar, Dict, Optional
 
+from pathway_tpu.engine import tracing
 from pathway_tpu.engine.datasource import StreamingDataSource
 from pathway_tpu.internals import dtype as dt
 from pathway_tpu.internals import parse_graph as pg
 from pathway_tpu.internals import schema as sch
+from pathway_tpu.internals.keys import pointers_to_keys
 from pathway_tpu.internals.parse_graph import G
 from pathway_tpu.internals.table import Table
 
@@ -40,6 +48,9 @@ class _Entry:
     time: int
     seq: int
     is_addition: bool
+    # (row key's bytes, context) of the traced request whose row this is: read
+    # inside the commit that took the row, kept until its result is pushed
+    request: Optional[tuple] = field(default=None, compare=False)
 
 
 @dataclass
@@ -178,16 +189,21 @@ class AsyncTransformer:
         ).start()
         instances: Dict[Any, _Instance] = {}
         inflight: set = set()
+        tasks: set = set()
         seq_box = [0]
         ended = [False]
         closed_time = [-1]  # flushes gate on time-end markers (reference semantics)
 
-        def upsert(key: Any, row: dict, status: str) -> None:
+        def upsert(key: Any, row: dict, status: str, request: Optional[tuple]) -> None:
             data = {**row, _ASYNC_STATUS_COLUMN: status}
             kb = repr(key).encode()
             old = state.pop(kb, None)
             if old is not None:
                 source.push(old, key=key, diff=-1)
+            if request is not None:
+                # the commit that carries the result links the request, as the
+                # commit that took its row did (GraphRunner._trace_commit_queries)
+                tracing.get_tracer().register_commit_link(*request)
             source.push(data, key=key, diff=1)
             state[kb] = data
 
@@ -197,15 +213,15 @@ class AsyncTransformer:
                 source.push(old, key=key, diff=-1)
 
         def flush_buffer(inst: _Instance) -> None:
-            for key, is_addition, result in inst.buffer:
-                if is_addition and inst.correct:
-                    upsert(key, result, _SUCCESS)
-                elif is_addition:
+            for entry, result in inst.buffer:
+                if entry.is_addition and inst.correct:
+                    upsert(entry.key, result, _SUCCESS, entry.request)
+                elif entry.is_addition:
                     # instance consistency: one failure poisons the whole
                     # (instance, time) group (reference .failed contract)
-                    upsert(key, {n: None for n in out_names}, _FAILURE)
+                    upsert(entry.key, {n: None for n in out_names}, _FAILURE, entry.request)
                 else:
-                    remove(key)
+                    remove(entry.key)
             inst.buffer.clear()
 
         def maybe_produce(instance_key: Any) -> None:
@@ -225,12 +241,9 @@ class AsyncTransformer:
                         flush_buffer(inst)
                         inst.correct = True
                     inst.buffer_time = entry.time
-                if entry.is_addition:
-                    if result is None:
-                        inst.correct = False
-                    inst.buffer.append((entry.key, True, result))
-                else:
-                    inst.buffer.append((entry.key, False, None))
+                if entry.is_addition and result is None:
+                    inst.correct = False
+                inst.buffer.append((entry, result))
             if not inst.pending:
                 flush_buffer(inst)
                 del instances[instance_key]
@@ -258,7 +271,14 @@ class AsyncTransformer:
             # group before a sibling entry registered
             instance_key = row.get(_INSTANCE_NAME, key) if self._instance_expr is not None else key
             seq_box[0] += 1
-            entry = _Entry(key, time, seq_box[0], is_addition)
+            request = None
+            tracer = tracing.get_tracer()
+            if is_addition and tracer.recording():
+                row_key = pointers_to_keys([key]).tobytes()
+                ctx = tracer.commit_link_context(row_key)
+                if ctx is not None:
+                    request = (row_key, ctx)
+            entry = _Entry(key, time, seq_box[0], is_addition, request)
             values = {n: row[n] for n in names} if is_addition else None
 
             def register_and_spawn() -> None:
@@ -270,7 +290,9 @@ class AsyncTransformer:
 
                 async def run_one() -> None:
                     try:
-                        result = await invoke(**values)
+                        # no commit is open around the invocation: its spans are the request's
+                        with tracing.adopt_context(request and request[1]):
+                            result = await invoke(**values)
                         if set(result.keys()) != set(out_names):
                             raise ValueError(
                                 "result of async function does not match output_schema"
@@ -279,7 +301,9 @@ class AsyncTransformer:
                         result = None
                     task_done(instance_key, entry, result)
 
-                loop.create_task(run_one())
+                task = loop.create_task(run_one())
+                tasks.add(task)  # the loop holds its tasks weakly
+                task.add_done_callback(tasks.discard)
 
             loop.call_soon_threadsafe(register_and_spawn)
 

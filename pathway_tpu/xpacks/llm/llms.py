@@ -3,7 +3,8 @@
 ``OpenAIChat`` (``:84``), ``LiteLLMChat`` (``:313``), ``HFPipelineChat`` (``:441``),
 ``CohereChat`` (``:544``) — async UDFs with capacity/retry/cache; clients gated at call time.
 ``Lfm2Chat`` is the one that calls nothing out: an ``lfm2_moe`` decoder on this process's
-device behind a generation service (``models/lfm2.py``, ``models/generation_service.py``).
+device behind a generation service (``models/lfm2.py``, ``models/generation_service.py``),
+and the one whose call outlives the commit that made it (``fully_async_executor``).
 """
 
 from __future__ import annotations
@@ -18,6 +19,7 @@ from pathway_tpu.internals.udfs import (
     CacheStrategy,
     UDF,
     async_executor,
+    fully_async_executor,
 )
 
 
@@ -209,7 +211,12 @@ class Lfm2Chat(BaseChat):
     (``benchmarks/configs/lfm2-8b-a1b-rag.json`` serves its layers 0-13). A prompt
     longer than ``max_prompt_tokens`` keeps its last tokens. The messages are
     rendered as their contents, one a line: there is no chat template without
-    the checkpoint's tokenizer."""
+    the checkpoint's tokenizer.
+
+    Its executor is ``fully_async_executor``: the commit that carries a prompt
+    hands it to the service and ends, and the reply is a row of a later commit
+    (a ``select`` that calls the chat has a row once its reply is there). The
+    API chats above keep ``async_executor`` and are awaited inside the commit."""
 
     def __init__(
         self,
@@ -223,7 +230,10 @@ class Lfm2Chat(BaseChat):
         seed: int = 0,
         cache_strategy: CacheStrategy | None = None,
     ):
-        super().__init__(executor=async_executor(), cache_strategy=cache_strategy)
+        # a reply takes a prefill and many steps of the service's own loop, which admits a
+        # prompt between any two of them: the call leaves the commit that carried the prompt, and
+        # the answer re-enters when it is ready (a wake-up and the REST connector's tick, no timer)
+        super().__init__(executor=fully_async_executor(autocommit_duration_ms=1), cache_strategy=cache_strategy)
         # the device is touched here, never at import: a process that builds no such chat loads no jax
         from pathway_tpu.models.encoder import HashTokenizer
         from pathway_tpu.models.generation_service import GenerationService

@@ -48,7 +48,14 @@ class SummaryQuestionAnswerer(BaseQuestionAnswerer):
 
 
 class BaseRAGQuestionAnswerer(SummaryQuestionAnswerer):
-    """Standard RAG: retrieve k docs, build prompt, ask the chat model (reference ``:314``)."""
+    """Standard RAG: retrieve k docs, build prompt, ask the chat model (reference ``:314``).
+
+    ``answer_query`` and ``summarize_query`` call the chat in a ``select``, so the chat's
+    executor decides when the answer's row exists: with ``async_executor`` in the commit
+    that carried the question, which holds the call; with ``fully_async_executor``
+    (``Lfm2Chat``) in the later commit in which the answer arrives, the question's commit
+    having done retrieval and the prompt and ended. Either way a request's row appears
+    once, with its answer."""
 
     class AnswerQuerySchema(pw.Schema):
         prompt: str
@@ -169,7 +176,12 @@ class BaseRAGQuestionAnswerer(SummaryQuestionAnswerer):
 
 class AdaptiveRAGQuestionAnswerer(BaseRAGQuestionAnswerer):
     """Geometric context growth (reference ``:620``): try n_starting_documents, re-ask with
-    factor× more docs until the model finds an answer or max_iterations is hit."""
+    factor× more docs until the model finds an answer or max_iterations is hit.
+
+    The rounds run inside one ``@pw.udf async`` of this class's own, which takes the
+    chat's function and not its executor: so they are awaited inside the commit that
+    carried the question, whatever the chat (``Lfm2Chat`` too, whose own calls leave
+    the commit under ``BaseRAGQuestionAnswerer``)."""
 
     def __init__(
         self,

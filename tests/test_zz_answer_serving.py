@@ -11,8 +11,11 @@ stream forever (daemon threads); see ``test_zz_trace_serving.py``.
 
 from __future__ import annotations
 
+import concurrent.futures
+import contextlib
 import json
 import socket
+import threading
 import time
 import urllib.request
 
@@ -38,6 +41,21 @@ def _post(port: int, route: str, payload: dict) -> dict:
     )
     with urllib.request.urlopen(req, timeout=120) as resp:
         return json.loads(resp.read())
+
+
+@contextlib.contextmanager
+def profiler_session(directory):
+    """A ``jax.profiler`` session: while it is on, every request leaves its spans."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(directory), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
 
 
 @pytest.fixture(scope="module")
@@ -91,17 +109,12 @@ def served(tmp_path_factory):
     before = (chat.service.stats(), telemetry.stage_snapshot("lm."))
     assert tracing.get_tracer().recent_spans() == []  # nothing records yet
 
-    opts = jax.profiler.ProfileOptions()
-    opts.python_tracer_level = 0
-    opts.host_tracer_level = 1
-    jax.profiler.start_trace(str(tmp_path_factory.mktemp("profile")), profiler_options=opts)
-    try:
+    with profiler_session(tmp_path_factory.mktemp("profile")):
         reply = _post(port, "/v2/answer", {"prompt": QUESTION, "return_context_docs": True})
-    finally:
-        jax.profiler.stop_trace()
     after = (chat.service.stats(), telemetry.stage_snapshot("lm."))
     spans = tracing.get_tracer().recent_spans(limit=1 << 20)
-    yield {"chat": chat, "params": params, "reply": reply, "before": before, "after": after, "spans": spans}
+    yield {"chat": chat, "params": params, "reply": reply, "before": before, "after": after, "spans": spans,
+           "port": port}
     tracing.reset_tracing()
     mp.undo()
 
@@ -144,16 +157,75 @@ def test_zz_counters_and_spans_carry_what_the_request_implies(served):
     steps = by_kind["lm.decode_step"]
     assert generate["attrs"]["prompt_tokens"] == n_prompt and prefill["attrs"]["tokens"] == n_prompt
     assert prefill["parent_id"] == generate["span_id"] and prefill["trace_id"] == generate["trace_id"]
-    # the generation is a child of the commit that evaluated the chat
-    [commit] = [s for s in by_kind["commit"] if s["span_id"] == generate["parent_id"]]
-    assert commit["attrs"]["queries"] == 1
+    end = lambda s: s["ts_mono"] + s["duration_s"]
+    # the generation is the request's own: the commit that took the question handed the prompt out
+    # and ended, and the commit that carried the answer in links the same request
+    [rest] = [s for s in by_kind["rest"] if s["span_id"] == generate["parent_id"]]
+    assert rest["trace_id"] == generate["trace_id"] and rest["attrs"]["route"] == "/v2/answer"
+    took, carried = sorted((s for s in by_kind["commit"] if any(link["span_id"] == rest["span_id"] for link in s["links"])),
+                           key=lambda s: s["ts_mono"])
+    assert took["attrs"]["queries"] == carried["attrs"]["queries"] == 1
+    # (the reply may leave before the commit that resolved it has ended)
+    assert end(took) < end(generate) <= carried["ts_mono"] < end(rest)
+    queues = [s for s in by_kind["queue"] if s["parent_id"] == rest["span_id"]]
+    assert sorted(s["attrs"]["commit"] for s in queues) == [took["attrs"]["commit"], carried["attrs"]["commit"]]
     assert len(steps) == NEW_TOKENS - 1 and all(s["attrs"]["rows"] == 1 for s in steps)
     assert all(any(link["span_id"] == generate["span_id"] for link in s["links"]) for s in steps)
     waits = {s["parent_id"] for s in by_kind["lm.decode_step.device_wait"]}
     assert waits == {s["span_id"] for s in steps}
     assert [s["parent_id"] for s in by_kind["lm.prefill.device_wait"]] == [prefill["span_id"]]
     # prefill and every step lie inside the generation, one after the other
-    end = lambda s: s["ts_mono"] + s["duration_s"]
     inside = sorted([prefill] + steps, key=lambda s: s["ts_mono"])
     assert inside[0] is prefill and generate["ts_mono"] <= prefill["ts_mono"] and end(inside[-1]) <= end(generate)
     assert all(end(a) <= b["ts_mono"] for a, b in zip(inside, inside[1:]))
+
+
+def test_zz_a_second_question_is_answered_while_the_first_still_generates(served, tmp_path, monkeypatch):
+    """Two questions back to back, the first's tokens held back by the test: the
+    second is retrieved, prefilled and answered meanwhile, because no commit
+    holds the first's generation."""
+    chat, port = served["chat"], served["port"]
+    release, first_in, submitted = threading.Event(), threading.Event(), []
+    submit = chat.service.submit
+
+    def held_submit(ids, *, ctx=None):
+        future = submit(ids, ctx=ctx)
+        submitted.append(future)
+        if len(submitted) > 1:
+            return future
+        gated: concurrent.futures.Future = concurrent.futures.Future()
+        threading.Thread(target=lambda: (release.wait(120), gated.set_result(future.result(120))), daemon=True).start()
+        first_in.set()
+        return gated
+
+    monkeypatch.setattr(chat.service, "submit", held_submit)
+    counted = lambda: telemetry.stage_snapshot("eval.fully_async_")
+    before = counted()
+    replies: dict = {}
+    first = threading.Thread(target=lambda: replies.update(
+        first=_post(port, "/v2/answer", {"prompt": "w011 w012 q2", "return_context_docs": True})))
+    try:
+        with profiler_session(tmp_path):
+            first.start()
+            assert first_in.wait(60)
+            replies["second"] = _post(port, "/v2/answer", {"prompt": "w021 w022 q3", "return_context_docs": True})
+            assert "first" not in replies and first.is_alive()
+            in_flight = counted()
+            release.set()
+            first.join(120)
+            retrieved = _post(port, "/v1/retrieve", {"query": "w001 w002", "k": 3})
+    finally:
+        release.set()
+    assert len(retrieved) == 3 and len(submitted) == 2
+    assert all(len(chat.reply_ids(replies[k]["response"])) == NEW_TOKENS for k in ("first", "second"))
+    # one call handed out and one result back a question, nothing for a retrieval
+    grew = lambda now: {k.rsplit("_", 1)[1]: now[k] - before.get(k, 0.0) for k in now}
+    assert grew(in_flight) == {"rows": 2.0, "returned": 1.0} and grew(counted()) == {"rows": 2.0, "returned": 2.0}
+
+    end = lambda s: s["ts_mono"] + s["duration_s"]
+    spans = tracing.get_tracer().recent_spans(limit=1 << 20)
+    # the ring still holds the fixture's request: this session's two are the newest
+    held, other = sorted((s for s in spans if s["kind"] == "generate"), key=lambda s: s["ts_mono"])[-2:]
+    [prefill] = [s for s in spans if s["kind"] == "lm.prefill" and s["parent_id"] == other["span_id"]]
+    [rest] = [s for s in spans if s["kind"] == "rest" and s["span_id"] == other["parent_id"]]
+    assert held["ts_mono"] < prefill["ts_mono"] < end(held) and end(rest) < end(held)
