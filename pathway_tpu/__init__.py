@@ -20,7 +20,7 @@ def _place_compile_cache() -> None:
     nothing is set here. Otherwise the cache goes to ``<checkout>/.jax_cache``,
     derived from this package's own path: a directory that moves between runs
     (tempfile, pid, time) never hits. Every entry point (scripts, spawned ranks,
-    replica children, bench sections) passes this line by importing the package.
+    replica children, the benchmark) passes this line by importing the package.
     """
     import os
 

@@ -27,7 +27,7 @@ substrate the concurrency lint (PWA101–104) built:
   ``GraphCaptureInterrupt`` (and ``KeyboardInterrupt``). Error.
 - **PWA203 — write-only / dead attribute state.** An attribute of a runtime
   class that is written outside constructor-only code but never read anywhere
-  (any analyzed module, plus the tests/bench read index in tree mode) is the
+  (any analyzed module, plus the tests/benchmarks read index in tree mode) is the
   parked-continuation bug class: state that silently stops meaning anything.
   Constructor-reachability and the ``# noqa: PWA2xx (<why>)`` escape reuse the
   PWA103 machinery. Warning.
@@ -86,8 +86,8 @@ RESOURCE_MODULES: Tuple[str, ...] = RUNTIME_MODULES + (
 )
 
 #: files scanned (regex, not AST) for attribute reads in tree mode: an attr
-#: consumed only by tests/bench/examples is observability state, not dead
-_EXTERNAL_READ_GLOBS: Tuple[str, ...] = ("tests", "examples", "bench.py")
+#: consumed only by tests/benchmarks/examples is observability state, not dead
+_EXTERNAL_READ_GLOBS: Tuple[str, ...] = ("tests", "examples", "benchmarks", "chip_smoke.py")
 
 # -- PWA201 resource registry -------------------------------------------------
 
@@ -398,7 +398,7 @@ class ResourceAnalysisContext:
 
 
 def _scan_external_reads(root: str) -> Set[str]:
-    """Attribute names read by tests/bench/examples (regex scan: ``.name``
+    """Attribute names read by tests/benchmarks/examples (regex scan: ``.name``
     loads plus getattr/hasattr string literals). Coarse on purpose — an over-
     wide read index only makes PWA203 quieter, never noisier."""
     attr_re = re.compile(r"\.\s*([A-Za-z_]\w*)")
@@ -1024,7 +1024,7 @@ class DeadStatePass(ResourcePass):
                 d = self.diag(
                     Severity.WARNING,
                     f"{cls_name}.{attr} is written in {qual} but never read "
-                    "anywhere (analyzed modules + tests/bench): write-only "
+                    "anywhere (analyzed modules + tests/benchmarks): write-only "
                     "state is the parked-continuation bug class — delete it, "
                     "or wire the consumer it was meant for (`# noqa: PWA203 "
                     "(<why>)` if it is intentionally export-only)",

@@ -14,10 +14,10 @@ Rungs (driven by embed-queue occupancy, the fraction of
 rung  engages at           degradation
 ====  ==================  =============================================
 0     —                   none (normal serving)
-1     occupancy >= 0.60   REST admission cap x0.5, coalesce window x0.5
-2     occupancy >= 0.85   REST admission cap x0.25, coalesce window ->0,
-                          IVF ``n_probe`` halved (recall traded for
-                          latency — serving stays up)
+1     occupancy >= 0.60   REST admission cap x0.5
+2     occupancy >= 0.85   REST admission cap x0.25, IVF ``n_probe``
+                          halved (recall traded for latency — serving
+                          stays up)
 ====  ==================  =============================================
 
 Rungs RELEASE with hysteresis: occupancy must stay below ~70% of the engage
@@ -48,11 +48,10 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
-# (engage_occupancy, admission_scale, coalesce_window_scale, nprobe_shift)
-# per rung, rung 0 implicit
+# (engage_occupancy, admission_scale, nprobe_shift) per rung, rung 0 implicit
 _RUNGS = (
-    (0.60, 0.5, 0.5, 0),
-    (0.85, 0.25, 0.0, 1),
+    (0.60, 0.5, 0),
+    (0.85, 0.25, 1),
 )
 # occupancy must stay below engage * _RELEASE_RATIO for hold_s to disengage
 _RELEASE_RATIO = 0.7
@@ -109,12 +108,12 @@ class BrownoutState:
         events = []
         with self._lock:
             old = self._level
-            for i, (engage, _adm, _win, _np) in enumerate(_RUNGS):
+            for i, (engage, _adm, _np) in enumerate(_RUNGS):
                 if frac >= engage * _RELEASE_RATIO:
                     self._last_above[i] = now
             # engage the deepest rung whose threshold the sample crosses
             level = self._level
-            for i, (engage, _adm, _win, _np) in enumerate(_RUNGS):
+            for i, (engage, _adm, _np) in enumerate(_RUNGS):
                 if frac >= engage:
                     level = max(level, i + 1)
             # release any rung that stayed quiet for hold_s
@@ -171,20 +170,13 @@ class BrownoutState:
             level = self._level
         return _RUNGS[level - 1][1] if level > 0 else 1.0
 
-    def coalesce_window_scale(self) -> float:
-        """Multiplier on the query coalescer's ``max_wait_ms`` window (a
-        shorter window trades batching efficiency for latency under load)."""
-        with self._lock:
-            level = self._level
-        return _RUNGS[level - 1][2] if level > 0 else 1.0
-
     def nprobe_shift(self) -> int:
         """Right-shift applied to IVF ``n_probe`` at query time (rung 2:
         half the probes — recall degrades honestly instead of the queue
         growing without bound)."""
         with self._lock:
             level = self._level
-        return _RUNGS[level - 1][3] if level > 0 else 0
+        return _RUNGS[level - 1][2] if level > 0 else 0
 
     # -- quiesce window (membership transition) --------------------------------
 

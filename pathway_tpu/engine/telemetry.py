@@ -143,7 +143,6 @@ TRACE_SPAN_KINDS: "frozenset[str]" = frozenset({
     "barrier",       # exchange barrier wait (carries straggler attribution)
     "cache_fill",    # encoder tick: embed caches filled after the waiters left
     "checkpoint",    # coordinated checkpoint write inside a commit
-    "coalesce",      # query-coalescer admission wait
     "commit",        # one engine commit (deterministic cross-rank trace id)
     "embed_wait",    # commit thread inside embed_query_rows, until rows come back
     "encode",        # encoder-service tick (links N parent query spans)

@@ -2,7 +2,7 @@
 
 The PR-5 metrics plane answers "how much / how slow" per rank; this module
 answers "why was THIS query slow". Every hop of a request — REST admission,
-the coalescer/encoder tick that batched it, the commit that served it, the
+the encoder tick that batched it, the commit that served it, the
 exchange barrier it waited behind, the replica that answered — records a
 :class:`Span` carrying (trace_id, span_id, parent_id, rank, kind, wall +
 monotonic stamps, attrs, links), and the per-rank rings merge offline into one

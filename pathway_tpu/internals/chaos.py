@@ -40,9 +40,9 @@ Environment contract::
          "sched": {"seed": 7}}
 
 ``load`` shapes a DETERMINISTIC synthetic offered-load profile for the
-autoscaler/backpressure tests and the ``bench.py autoscale`` section — load
-generators consult :meth:`Chaos.load_rate` the way the engine consults kill
-schedules, so an overload scenario replays exactly. Ops: ``load_spike``
+autoscaler/backpressure tests — load generators consult
+:meth:`Chaos.load_rate` the way the engine consults kill schedules, so an
+overload scenario replays exactly. Ops: ``load_spike``
 (``low`` rows/s, stepping to ``high`` at ``at_s`` for ``duration_s``),
 ``oscillating_load`` (square wave between ``low``/``high`` every
 ``period_s`` — the flap-lock scenario), and ``noisy_neighbor`` (flood

@@ -3,10 +3,11 @@
 Each model is a small, faithful port of one hand-written thread protocol from
 the runtime — the epoch fence/rejoin install (``parallel/cluster.py``), the
 snapshot→ack→manifest→compact coordinated checkpoint (``engine/runner.py`` +
-``persistence/engine.py``), and the query-coalescer admission/shed path
-(``models/embed_pipeline.py``) — rewritten against ``internals/sched.py``
-primitives so EVERY interleaving decision is scheduler-controlled. Run them
-under :func:`~pathway_tpu.internals.sched.explore` (bounded-exhaustive DFS) or
+``persistence/engine.py``), and the encoder service's admission/tick/shutdown
+protocol (``models/encoder_service.py``) — rewritten against
+``internals/sched.py`` primitives so EVERY interleaving decision is
+scheduler-controlled. Run them under
+:func:`~pathway_tpu.internals.sched.explore` (bounded-exhaustive DFS) or
 :func:`~pathway_tpu.internals.sched.sweep_seeds` (seeded walks) and the
 invariants below hold on every schedule — or fail with a replayable choice
 sequence:
@@ -17,9 +18,6 @@ sequence:
 - **checkpoint**: at most one manifest per commit id, compaction only behind
   a durable manifest, and an aborted attempt leaves the previous manifest
   intact;
-- **coalescer**: every request is shed XOR answered, admission slots are
-  always released (queued rows return to zero), and close never strands a
-  waiter;
 - **encoder service**: the continuous-batching admission/tick/shutdown
   protocol (``models/encoder_service.py``) — every request shed XOR answered,
   waiting and in-flight row counts return to zero, shutdown drains the queue,
@@ -28,7 +26,7 @@ sequence:
 Each model takes a ``bug=`` knob that plants a realistic regression
 (``"no_purge"`` skips the install-time inbox purge, ``"toctou_commit"``
 releases the manifest lock between the read-back check and the write,
-``"leak_slot"`` drops the queued-row release on the encode error path,
+``"leak_inflight"`` drops the in-flight release on the encode error path,
 ``"no_timeout"`` makes a wait unabortable). The broken variants exist so the
 model-check suite can prove it DETECTS the bug class with a replayable
 schedule — the safety net ROADMAP item 1's membership protocol will run
@@ -352,114 +350,11 @@ def checkpoint_model(
 
 
 # ---------------------------------------------------------------------------
-# query-coalescer admission / shed (models/embed_pipeline.py)
-# ---------------------------------------------------------------------------
-
-
-def coalescer_model(
-    n_clients: int = 3,
-    *,
-    cap: int = 2,
-    fail_batch: bool = False,
-    bug: Optional[str] = None,
-) -> Callable[[DeterministicScheduler], Callable[[], None]]:
-    """The QueryCoalescer admission protocol: clients admit one row each
-    against ``cap`` queued rows (past it they shed), a worker batches the
-    queue and answers every taken request, close() wakes everyone. With
-    ``fail_batch`` the encoder raises on the first batch — the error must
-    propagate to exactly the taken requests WITH their admission slots
-    released (``bug="leak_slot"`` drops the release on that path, the real
-    regression class behind a permanently-429 coalescer)."""
-
-    def model(sched: DeterministicScheduler) -> Callable[[], None]:
-        lock = sched.lock("coalescer")
-        cv = sched.condition(lock, name="coalescer.cv")
-        state: Dict[str, Any] = {
-            "queue": [],  # request ids waiting for the worker
-            "queued_rows": 0,
-            "shed": set(),
-            "answered": set(),
-            "errored": set(),
-            "closed": False,
-            "batches": 0,
-        }
-
-        def client_body(req: int) -> None:
-            with cv:
-                if state["queued_rows"] + 1 > cap:
-                    state["shed"].add(req)
-                    cv.notify_all()  # a shed is a terminal outcome too
-                    return
-                state["queue"].append(req)
-                state["queued_rows"] += 1
-                cv.notify_all()
-
-        def worker_body() -> None:
-            while True:
-                with cv:
-                    # notify-driven idle wait (every queue/closed transition
-                    # notifies): an untimed wait here also makes the deadlock
-                    # detector prove no state change can be missed
-                    while not state["queue"]:
-                        if state["closed"]:
-                            return
-                        cv.wait()
-                    take = list(state["queue"])
-                    state["queue"] = []
-                fail = fail_batch and state["batches"] == 0
-                state["batches"] += 1
-                sched.yield_point("encode")
-                with cv:
-                    if fail:
-                        state["errored"].update(take)
-                        if bug != "leak_slot":
-                            state["queued_rows"] -= len(take)
-                    else:
-                        state["answered"].update(take)
-                        state["queued_rows"] -= len(take)
-                    cv.notify_all()
-
-        def closer_body() -> None:
-            # close after every client's request reached a terminal state
-            with cv:
-                while (
-                    len(state["shed"]) + len(state["answered"]) + len(state["errored"])
-                    < n_clients
-                ):
-                    cv.wait()
-                state["closed"] = True
-                cv.notify_all()
-
-        sched.spawn(worker_body, name="worker")
-        for req in range(n_clients):
-            sched.spawn(client_body, req, name=f"client{req}")
-        sched.spawn(closer_body, name="closer")
-
-        def check() -> None:
-            outcomes = [state["shed"], state["answered"], state["errored"]]
-            seen: set = set()
-            for group in outcomes:
-                assert not (seen & group), f"request answered twice: {seen & group}"
-                seen |= group
-            assert seen == set(range(n_clients)), (
-                f"requests stranded with no outcome: {set(range(n_clients)) - seen}"
-            )
-            assert state["queued_rows"] == 0, (
-                f"admission slots leaked: {state['queued_rows']} rows still "
-                "counted after every request terminated"
-            )
-
-        return check
-
-    return model
-
-
-# ---------------------------------------------------------------------------
 # encoder-service admission / tick / shutdown (models/encoder_service.py)
 # ---------------------------------------------------------------------------
 
 
-def encoder_service_model(
+def encsvc_model(
     n_clients: int = 3,
     *,
     cap: int = 2,
@@ -477,10 +372,10 @@ def encoder_service_model(
     with their request still queued (the self-heal/abort path of
     ``EncoderService._await``).
 
-    All waits are modeled UNTIMED (notify-driven, like the coalescer model):
-    under the deadlock detector that PROVES every state transition notifies
-    its waiters — the real implementation's timed tick/poll bounds are
-    defense-in-depth on top of a protocol shown to need no timeout wakeups.
+    All waits are modeled UNTIMED (notify-driven): under the deadlock detector
+    that PROVES every state transition notifies its waiters — the real
+    implementation's timed tick/poll bounds are defense-in-depth on top of a
+    protocol shown to need no timeout wakeups.
 
     Invariants: no deadlock, every request shed XOR answered XOR errored
     (none aborted/dropped under the correct protocol), and slots always

@@ -1,8 +1,8 @@
 """Deterministic schedule exploration for the cluster protocols (loom-style).
 
 The fence/quiesce/rejoin dance, the aligned checkpoint sequence, and the
-coalescer's admission protocol are hand-written thread protocols whose bugs
-live in *interleavings* — and until now the only interleavings ever tested
+encoder service's admission protocol are hand-written thread protocols whose
+bugs live in *interleavings* — and until now the only interleavings ever tested
 were whatever the OS scheduler produced (chaos testing). This module is a
 loom/shuttle-style deterministic scheduler: protocol *models* (see
 ``internals/protocol_models.py``) run on real Python threads, but every

@@ -278,7 +278,7 @@ class RestServerSubject:
         self.max_pending = max(0, int(max_pending))
         self.shed_stage = shed_stage
         self._retry_after = retry_after
-        # secondary admission probe (e.g. the embed coalescer's row-queue cap):
+        # secondary admission probe (e.g. the encoder service's row cap):
         # sheds on downstream queue depth, not just this route's request count
         self._overload_probe = overload_probe
         self.shed_requests = 0

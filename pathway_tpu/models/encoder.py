@@ -422,9 +422,10 @@ class JaxSentenceEncoder:
         FLOPs. Dispatches are JAX-async: the loop never blocks on a forward, so
         tokenization of sub-batch k+1 runs while the device works on k (double
         buffering without explicit streams); the single sync point is the final
-        fetch. Per-row results are bitwise-identical to :meth:`encode` (masked
-        attention/pooling make each row invariant to pad width — regression-
-        tested on CPU).
+        fetch. Per-row results equal :meth:`encode`'s within float32 rounding,
+        not bit for bit: masked attention/pooling make a row's value independent
+        of its pad width, but each (batch, seq) bucket is its own XLA program,
+        and two programs may order a reduction differently.
 
         Returns ``(embeddings (n, dim) float32 in input order, stats)`` where
         stats carries ``padded_tokens``/``real_tokens`` (the pad-waste ratio),

@@ -1,29 +1,32 @@
 """Persistent on-device encoder service: continuous batching + warm jit caches.
 
-The PR-4 :class:`~pathway_tpu.models.embed_pipeline.QueryCoalescer` is a
-*deadline* micro-batcher: the first request at an empty queue anchors a
-``max_wait_ms`` window, so a **solo** query always pays the window plus a cold
-dispatch — coalescing only helps under concurrency, and ``/v1/retrieve`` solo
-p50 stayed embed-bound (~392 ms, ROADMAP item 2). This module replaces the
-deadline loop with a *continuously-batched* encoder worker, the ragged-serving
-shape of the Ragged Paged Attention recipe (PAPERS.md) applied to the query
-tower:
+The one way a query's text reaches the encoder
+(:meth:`~pathway_tpu.models.embed_pipeline.EmbedPipeline.embed_query_rows` ->
+:meth:`EncoderService.submit` -> tick), and its own admission point. A
+*continuously-batched* encoder worker, the ragged-serving shape of the Ragged
+Paged Attention recipe (PAPERS.md) applied to the query tower:
 
-1. **Ragged admission queue.** Requests (solo or coalesced) append to a FIFO of
-   variable-length text lists and wake the worker immediately — no deadline
-   wait. Whatever is queued when the worker comes around is packed
+1. **Ragged admission queue.** Requests (solo or a commit's batch) append to a
+   FIFO of variable-length text lists and wake the worker immediately — no
+   deadline wait. Whatever is queued when the worker comes around is packed
    length-sorted into the next in-flight batch, capped at ``max_in_flight``
    rows; requests arriving while the device is busy ride the *next* tick, so
    concurrency still amortizes into one dispatch without any solo request ever
    waiting for a window to close.
-2. **Always-warm pow2-bucketed forward.** The jitted forward only ever sees
+2. **One admission point.** ``max_queue_rows`` caps the rows admitted but not
+   yet answered (waiting + in flight). :meth:`EncoderService.overloaded` is the
+   REST plane's lock-free pre-admission probe (429 + ``Retry-After`` from
+   :meth:`EncoderService.retry_after_s`, and the brownout ladder's occupancy
+   sample); :meth:`EncoderService.submit` sheds direct callers with a typed
+   :class:`EmbedOverloadError` and counts ``embed.shed``.
+3. **Always-warm pow2-bucketed forward.** The jitted forward only ever sees
    power-of-two (batch, seq) buckets (``JaxSentenceEncoder._dispatch``), so the
    whole reachable shape set is finite and enumerable. A background pre-warm
    thread compiles every bucket at service start (the Compiler-First caching
    argument: compiled state stays resident across requests) and records the
    wall cost as ``embed.svc.prewarm_s`` — compilation is reported at startup,
    never silently billed to the first query.
-3. **Semantic query cache** (:class:`SemanticQueryCache`) sits ABOVE the PR-4
+4. **Semantic query cache** (:class:`SemanticQueryCache`) sits ABOVE the
    content-hash cache in :class:`~pathway_tpu.models.embed_pipeline.EmbedPipeline`:
    exact mode (default) keys on the tokenizer's canonical form
    (``JaxSentenceEncoder.canonicalize``: whitespace collapse + case fold for
@@ -39,25 +42,23 @@ queue on :func:`stop_all_workers` (wired into ``GraphRunner.finish`` so
 next submit; :meth:`close` is the permanent variant. Every wait is timed and
 abortable (the PWA102 contract) and the module lives in ``RUNTIME_MODULES`` so
 PWA101-104 police its locks; the admission/tick/shutdown protocol is modeled
-in ``internals/protocol_models.encoder_service_model`` and explored under
+in ``internals/protocol_models.encsvc_model`` and explored under
 ``internals/sched.py`` (no deadlock, no dropped request, slots always
 released) — the model was written and checked BEFORE this implementation, per
 the PR-9 discipline.
 
-Knobs (ctor args, env defaults): ``PATHWAY_ENCSVC`` (``on``/``off`` — the
-pipeline-level gate), ``PATHWAY_ENCSVC_TICK_MS`` (idle poll bound; wakeups are
-notify-driven, the tick only bounds how long a lost wakeup could park the
-worker), ``PATHWAY_ENCSVC_MAX_INFLIGHT`` (rows packed per tick),
-``PATHWAY_ENCSVC_PREWARM`` (``1``/``0``), ``PATHWAY_ENCSVC_PREWARM_MAX_BATCH``
-(largest batch bucket pre-compiled), ``PATHWAY_ENCSVC_SEMANTIC``
-(``exact``/``cosine``/``off``), ``PATHWAY_ENCSVC_SEMANTIC_SIZE``,
-``PATHWAY_ENCSVC_SEMANTIC_THRESHOLD``.
+Settings: the constants below (one value in use each); constructor arguments
+where tests substitute a value; ``PATHWAY_ENCSVC_PREWARM`` (``1``/``0``, the
+test harness turns the startup compile matrix off) and
+``PATHWAY_EMBED_WAIT_TIMEOUT_S`` (a safety bound on one submission's wait)
+from the environment.
 
 Telemetry (PR-5 plane): ``embed.svc.*`` stage counters (prewarm_s,
 prewarm_compiles, ticks, rows, batches, dedup_rows, encode_s,
-semantic_hits/misses) and three log-bucketed histograms on ``/metrics``:
-``pathway_encsvc_queue_depth_rows``, ``pathway_encsvc_tick_occupancy``
-(packed rows / max_in_flight), ``pathway_encsvc_tick_seconds``.
+semantic_hits/misses), ``embed.shed``, and three log-bucketed histograms on
+``/metrics``: ``pathway_encsvc_queue_depth_rows``,
+``pathway_encsvc_tick_occupancy`` (packed rows / max_in_flight),
+``pathway_encsvc_tick_seconds``.
 """
 
 from __future__ import annotations
@@ -73,29 +74,31 @@ import numpy as np
 
 from pathway_tpu.engine import telemetry
 from pathway_tpu.engine import tracing as _tracing
+from pathway_tpu.internals.config import env_float
 from pathway_tpu.models.device_worker import DeviceWorker, stop_all_workers  # noqa: F401 (re-exported)
 from pathway_tpu.models.encoder import fetch_rows
 
 
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
+#: idle poll bound of the worker loop, in ms. Wakeups are notify-driven: the
+#: tick only bounds how long a lost wakeup could park the worker
+TICK_MS = 50.0
+#: rows packed into one tick
+MAX_IN_FLIGHT = 256
+#: largest batch bucket compiled at start-up
+PREWARM_MAX_BATCH = 64
 
 
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
+class EmbedOverloadError(RuntimeError):
+    """The encoder service's admission queue is full; the caller should shed
+    load. Raised by :meth:`EncoderService.submit` for direct callers only —
+    the REST plane consults the same cap BEFORE admission
+    (:meth:`EncoderService.overloaded`, wired through ``rest_connector``) and
+    sheds with HTTP 429 + ``Retry-After`` there, so an admitted request never
+    dies inside an engine commit."""
 
-
-def _env_flag(name: str, default: bool) -> bool:
-    raw = os.environ.get(name)
-    if raw is None or raw == "":
-        return default
-    return raw.lower() not in ("0", "false", "no", "off")
+    def __init__(self, message: str, *, retry_after_s: float = 1.0):
+        super().__init__(message)
+        self.retry_after_s = float(retry_after_s)
 
 
 def default_canonicalize(text: str) -> str:
@@ -263,13 +266,13 @@ class EncoderService(DeviceWorker):
     everything queued at each tick — up to ``max_in_flight`` rows,
     length-sorted, duplicates encoded once — into one bucketed dispatch, so a
     solo request is dispatched the moment the worker is free (no deadline
-    window) and a burst coalesces exactly like the PR-4 path did under load.
+    window) and a burst coalesces into one tick.
 
-    The admission-cap/shed contract lives in the :class:`QueryCoalescer` shim
-    in front of this class (``max_queue_rows`` here defaults to 0 =
-    unbounded); ``queue_depth_rows`` feeds the shim's ``overloaded`` /
-    ``retry_after_s`` probes so the REST plane's 429 + Retry-After semantics
-    are unchanged."""
+    The service is its own admission point: ``max_queue_rows`` (0 =
+    unbounded) caps the rows admitted but not yet answered. ``overloaded`` /
+    ``retry_after_s`` are the REST plane's pre-admission probes (429 +
+    ``Retry-After``); ``submit`` sheds a direct caller with a typed
+    :class:`EmbedOverloadError`."""
 
     _thread_name = "pathway:encsvc-worker"
 
@@ -277,33 +280,35 @@ class EncoderService(DeviceWorker):
         self,
         encoder: Any,
         *,
-        tick_ms: float | None = None,
-        max_in_flight: int | None = None,
+        tick_ms: float = TICK_MS,
+        max_in_flight: int = MAX_IN_FLIGHT,
         sub_batch: int = 64,
         max_queue_rows: int = 0,
         prewarm: bool | None = None,
-        prewarm_max_batch: int | None = None,
+        prewarm_max_batch: int = PREWARM_MAX_BATCH,
         after_batch: Callable[[List[str], Sequence[Any]], None] | None = None,
     ):
         super().__init__()
         self.encoder = encoder
-        if tick_ms is None:
-            tick_ms = _env_float("PATHWAY_ENCSVC_TICK_MS", 50.0)
         # the tick is the IDLE poll bound, not a batching delay: admission
         # notifies the worker, so a solo request never waits for it — it only
         # bounds how long a (hypothetical) lost wakeup could park the loop,
         # which is also what makes the idle wait abortable (PWA102)
         self.tick_s = max(0.001, float(tick_ms) / 1000.0)
-        if max_in_flight is None:
-            max_in_flight = _env_int("PATHWAY_ENCSVC_MAX_INFLIGHT", 256)
         self.max_in_flight = max(1, int(max_in_flight))
         self.sub_batch = max(1, int(sub_batch))
+        # admission cap: rows allowed to be pending (waiting + in flight).
+        # Past it submit() sheds instead of queueing — an overloaded encoder
+        # otherwise grows the queue without bound
         self.max_queue_rows = max(0, int(max_queue_rows))
         self._after_batch = after_batch
-        self.wait_timeout_s = _env_float("PATHWAY_EMBED_WAIT_TIMEOUT_S", 0.0)
+        # hard bound on one submission's total wait (0 = no bound; the wait is
+        # still abortable, see _await). Covers a wedged device: the fence
+        # deadline must never sit behind an unbounded embed wait
+        self.wait_timeout_s = env_float("PATHWAY_EMBED_WAIT_TIMEOUT_S", 0.0)
         self._queued_rows = 0
         self._inflight_rows = 0
-        self._encode_ewma_s = 0.0
+        self._encode_ewma_s = 0.0  # smoothed tick encode time (Retry-After)
         # counters (mirrored batch-level into the telemetry stage counters)
         self.requests = 0
         self.ticks = 0
@@ -325,9 +330,9 @@ class EncoderService(DeviceWorker):
         self.prewarm_s = 0.0
         self.prewarm_compiles = 0
         if prewarm is None:
-            prewarm = _env_flag("PATHWAY_ENCSVC_PREWARM", True)
-        if prewarm_max_batch is None:
-            prewarm_max_batch = _env_int("PATHWAY_ENCSVC_PREWARM_MAX_BATCH", 64)
+            prewarm = os.environ.get("PATHWAY_ENCSVC_PREWARM", "1").lower() not in (
+                "0", "false", "no", "off",
+            )
         self.prewarm_max_batch = max(8, int(prewarm_max_batch))
         if prewarm and self._prewarm_shapes():
             self._prewarm_thread = threading.Thread(
@@ -398,7 +403,7 @@ class EncoderService(DeviceWorker):
 
     def wait_warm(self, timeout_s: float = 300.0) -> bool:
         """Block until the pre-warm pass finished (True) or ``timeout_s``
-        elapsed (False). The bench calls this before timing solo queries so
+        elapsed (False). The benchmark calls this before its window, so
         compilation is excluded from request latency by construction."""
         return self._warm.wait(timeout=timeout_s)
 
@@ -406,24 +411,43 @@ class EncoderService(DeviceWorker):
     def warm(self) -> bool:
         return self._warm.is_set()
 
-    # -- admission probes (consumed by the QueryCoalescer shim) --------------
+    # -- admission ------------------------------------------------------------
 
     def queue_depth_rows(self) -> int:
         """Rows admitted but not yet answered (waiting + in-flight). Lock-free
-        read — a soft probe with bounded staleness, same contract as the
-        coalescer's ``overloaded``."""
+        read — a soft probe with bounded staleness."""
         return self._queued_rows + self._inflight_rows
 
-    def encode_ewma_s(self) -> float:
-        return self._encode_ewma_s
+    def overloaded(self, extra_rows: int = 0) -> bool:
+        """Admission probe: would admitting ``extra_rows`` more rows exceed
+        ``max_queue_rows``? Lock-free read — a soft cap with bounded overshoot,
+        same contract as the REST ``max_pending`` check. Each probe also feeds
+        the brownout ladder (``engine/brownout.py``) one occupancy sample, so
+        the serving plane's degradation rungs engage from the same signal the
+        shed decision uses."""
+        if not self.max_queue_rows:
+            return False
+        pending = self.queue_depth_rows()
+        from pathway_tpu.engine.brownout import get_brownout
+
+        get_brownout().observe_occupancy(pending / self.max_queue_rows)
+        return pending + extra_rows >= self.max_queue_rows
+
+    def retry_after_s(self, extra_rows: int = 0) -> float:
+        """Honest Retry-After estimate: ticks needed to drain the pending rows
+        x smoothed tick encode time, floored at 1 s."""
+        ticks = max(1.0, (self.queue_depth_rows() + extra_rows) / self.max_in_flight)
+        return max(1.0, ticks * (self._encode_ewma_s or 0.05))
 
     # -- submission ----------------------------------------------------------
 
     def submit(self, texts: List[str], *, enforce_cap: bool = True) -> List[Any]:
-        """Blocking: one row value per input text, in order. Sheds with
-        :class:`~pathway_tpu.models.embed_pipeline.EmbedOverloadError` when a
-        local ``max_queue_rows`` cap is set and would be exceeded (the usual
-        deployment leaves this 0 and caps in the coalescer shim instead)."""
+        """Blocking: one row value per input text, in order. Raises
+        :class:`EmbedOverloadError` when ``max_queue_rows`` is set and
+        admitting these rows would exceed it. The engine serving path passes
+        ``enforce_cap=False``: its requests were already admitted against the
+        same cap at the REST boundary (``overloaded`` probe), and raising
+        mid-commit would tear down the run instead of shedding one request."""
         if not texts:
             return []
         sub = _Submission(list(texts))
@@ -436,17 +460,12 @@ class EncoderService(DeviceWorker):
                 and self.max_queue_rows
                 and pending + len(texts) > self.max_queue_rows
             ):
-                # same waiting+in-flight accounting and honest Retry-After the
-                # coalescer shim's probe uses — the two admission points must
-                # not disagree
                 self.shed_requests += 1
-                from pathway_tpu.models.embed_pipeline import EmbedOverloadError
-
-                ticks = max(1.0, (pending + len(texts)) / self.max_in_flight)
+                telemetry.stage_add("embed.shed")
                 raise EmbedOverloadError(
                     f"encoder service queue full ({pending} rows pending, "
                     f"cap {self.max_queue_rows})",
-                    retry_after_s=max(1.0, ticks * (self._encode_ewma_s or 0.05)),
+                    retry_after_s=self.retry_after_s(len(texts)),
                 )
             self._queue.append(sub)
             self._queued_rows += len(texts)

@@ -22,7 +22,7 @@ the frozen spill tier:
 - **Exact fp32 rescore epilogue.** The int8 pass only builds a
   ``PATHWAY_IVF_RESCORE_K``-deep shortlist; the scores a search RETURNS are
   recomputed from the fp32 source rows through :func:`rescore_pairs` — THE
-  pinned epilogue the store, the tests and ``bench.py quant`` all share, so
+  pinned epilogue the store and the tests share, so
   "returned scores are exact" holds by construction and a stale sidecar or
   a wrong gather is a bitwise diff, not a silent recall drop.
 

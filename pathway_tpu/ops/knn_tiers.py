@@ -49,7 +49,7 @@ Design
 Scoring is cluster-major exactly like the CPU BLAS path of ``knn_ivf``
 (identical metric epilogue), so **residency never changes results**: the same
 query over the same corpus is bitwise identical whatever tier each cluster
-sits in — the honesty key ``bench.py ivfscale`` carries. On non-CPU backends
+sits in (``tests/test_tiered_index.py``). On non-CPU backends
 hot blocks score through a jitted pow2-bucketed device GEMM
 (:func:`_score_block_kernel`); fusing the multi-page probe the PR-1 kernel
 runs for the untiered store is named upside in ROADMAP item 4.

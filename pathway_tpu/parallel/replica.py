@@ -30,7 +30,7 @@ independently of ingest:
   (``AutoscalePolicy.replica_from_env()``), without touching ingest ranks.
 
 Replica results are BITWISE-equal to the primary's at the same commit id
-(the ``bench.py replicas`` honesty key): fragments install through the same
+(``tests/test_replica.py``): fragments install through the same
 ``add_many`` path the primary ingested through, and quantized stores
 regenerate codes bit-identically per the ``quant_state`` contract.
 

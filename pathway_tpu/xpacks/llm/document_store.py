@@ -150,7 +150,7 @@ class DocumentStore:
 
         def _payload(c: Any, m: Any, i: Any) -> Json:
             payload = {"file_count": c or 0, "last_modified": m, "last_indexed": i}
-            # live embed-pipeline counters (cache hit/miss, coalescing, pad
+            # live embed-pipeline counters (cache hit/miss, encoder service, pad
             # waste) when the embedder exposes them — read at answer time so
             # /v1/statistics doubles as the serving-path observability endpoint
             stats_fn = getattr(

@@ -69,33 +69,15 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         call_kwargs: "dict | None" = None,
         device: str = "tpu",
         batch_size: int = 1024,
-        max_wait_ms: float = 2.0,
-        max_coalesce_batch: int = 256,
-        sub_batch: int = 128,
         embed_cache_size: int = 50_000,
         encoder_config: Any = None,
-        encoder_service: "bool | None" = None,
-        semantic_cache: "str | None" = None,
-        semantic_cache_size: "int | None" = None,
-        semantic_threshold: "float | None" = None,
-        encsvc_tick_ms: "float | None" = None,
-        encsvc_max_in_flight: "int | None" = None,
-        encsvc_prewarm: "bool | None" = None,
         **kwargs: Any,
     ):
-        """``max_wait_ms``/``max_coalesce_batch``: legacy query-coalescer batch
-        window (only used with the encoder service off); ``sub_batch``:
-        length-sorted ingest sub-batch rows; ``embed_cache_size``:
-        content-hash LRU entries (0 disables); ``encoder_config``: override
-        ``EncoderConfig`` (tests use a tiny architecture);
-        ``encoder_service``: persistent continuously-batched encoder worker on
-        the query path (None = ``PATHWAY_ENCSVC`` env, default on);
-        ``semantic_cache``: ``exact``/``cosine``/``off`` (None =
-        ``PATHWAY_ENCSVC_SEMANTIC``, default exact — bitwise-honest) with
-        ``semantic_cache_size``/``semantic_threshold``;
-        ``encsvc_tick_ms``/``encsvc_max_in_flight``/``encsvc_prewarm``:
-        service tick bound, rows packed per tick, and startup jit pre-warm
-        (None = ``PATHWAY_ENCSVC_TICK_MS``/``_MAX_INFLIGHT``/``_PREWARM``)."""
+        """``embed_cache_size``: content-hash LRU entries (0 disables the
+        content and the semantic query cache); ``encoder_config``: override
+        ``EncoderConfig`` (tests use a tiny architecture). Query texts go
+        through the pipeline's caches into the persistent encoder service,
+        whose settings are its own (``models/encoder_service.py``)."""
         super().__init__(**kwargs)
         from pathway_tpu.models.embed_pipeline import EmbedPipeline
         from pathway_tpu.models.encoder import JaxSentenceEncoder
@@ -119,19 +101,7 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         self.encoder = JaxSentenceEncoder(model, config=encoder_config)
         self.batch_size = batch_size
         self.pipeline = EmbedPipeline(
-            self.encoder,
-            model=model,
-            max_wait_ms=max_wait_ms,
-            max_batch=max_coalesce_batch,
-            sub_batch=sub_batch,
-            cache_size=embed_cache_size,
-            service_mode=encoder_service,
-            semantic_mode=semantic_cache,
-            semantic_size=semantic_cache_size,
-            semantic_threshold=semantic_threshold,
-            tick_ms=encsvc_tick_ms,
-            max_in_flight=encsvc_max_in_flight,
-            prewarm=encsvc_prewarm,
+            self.encoder, model=model, cache_size=embed_cache_size
         )
 
         def embed_one(text: str) -> np.ndarray:
@@ -161,10 +131,10 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         (views of the one array an encoder tick fetched), which the KNN search
         stacks, pads and ships to the device in one transfer.
         Runs through the pipeline's content-hash + semantic caches and submits
-        misses into the persistent encoder service's continuous batch (the
-        coalescer admission shim), so a solo query dispatches immediately into
-        a pre-warmed jit bucket, concurrent retrieve queries share one encoder
-        dispatch, and repeated/equivalent texts skip the forward entirely.
+        misses into the persistent encoder service's continuous batch, so a
+        solo query dispatches immediately into a pre-warmed jit bucket,
+        concurrent retrieve queries share one encoder dispatch, and
+        repeated/equivalent texts skip the forward entirely.
 
         Declared ``deterministic=False`` so the engine memoizes each query row's
         embedding and REPLAYS it on retraction (the rest connector's
@@ -188,8 +158,8 @@ class SentenceTransformerEmbedder(BaseEmbedder):
         )
 
     def pipeline_stats(self) -> dict:
-        """Cache/coalescer/pad-waste counters (surfaced by
-        ``DocumentStore.statistics_query`` and the bench's embedpipe section)."""
+        """Cache/encoder-service/pad-waste counters (surfaced by
+        ``DocumentStore.statistics_query``)."""
         return self.pipeline.stats()
 
     def get_embedding_dimension(self, **kwargs: Any) -> int:

@@ -104,10 +104,10 @@ class VectorStoreServer:
         import os as _os
 
         max_pending = int(_os.environ.get("PATHWAY_EMBED_MAX_PENDING", "1024"))
-        coalescer = getattr(
+        pipeline = getattr(
             getattr(self.store, "embedder", None) or self.embedder, "pipeline", None
         )
-        coalescer = getattr(coalescer, "coalescer", None)
+        service = getattr(pipeline, "service", None)
         retrieve_queries, retrieve_writer = rest_connector(
             webserver=webserver,
             route="/v1/retrieve",
@@ -116,16 +116,12 @@ class VectorStoreServer:
             delete_completed_queries=True,
             max_pending=max_pending,
             shed_stage="embed.shed",
-            retry_after=(
-                coalescer.retry_after_s if coalescer is not None else None
-            ),
-            # second line of defense: the coalescer's row-queue cap
+            retry_after=service.retry_after_s if service is not None else None,
+            # second line of defense: the encoder service's row cap
             # (PATHWAY_EMBED_MAX_QUEUE_ROWS) probed pre-admission, so a slow
-            # encoder sheds on queued ROWS even while fewer than max_pending
+            # encoder sheds on pending ROWS even while fewer than max_pending
             # REQUESTS are in flight
-            overload_probe=(
-                coalescer.overloaded if coalescer is not None else None
-            ),
+            overload_probe=service.overloaded if service is not None else None,
         )
         retrieve_writer(self.retrieve_query(retrieve_queries))
 

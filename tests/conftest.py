@@ -82,8 +82,8 @@ def clear_graph():
 @pytest.fixture(autouse=True)
 def clear_brownout():
     """The brownout ladder is a process-wide singleton fed by admission
-    probes; a shed test saturating one coalescer must not leave a rung
-    engaged (tightened caps, shrunken coalesce windows) for the next test."""
+    probes; a shed test saturating one encoder service must not leave a rung
+    engaged (tightened caps, halved probes) for the next test."""
     from pathway_tpu.engine.brownout import reset_brownout
 
     reset_brownout()

@@ -7,7 +7,7 @@ Five layers under test:
   TYPED refusal backoff (at most one retry per window), and the flap lock
   under the chaos ``oscillating_load`` profile;
 - brownout ladder (``engine/brownout.py``): occupancy-driven rungs with
-  hysteresis, admission/coalesce/n_probe degradation factors, the quiesce
+  hysteresis, admission/n_probe degradation factors, the quiesce
   window, and the REST plane shedding 429 + honest Retry-After on both;
 - supervisor wiring: the hardened control endpoint (``err <reason>`` for
   malformed commands, the read-only ``status`` command, concurrent ``scale``
@@ -330,11 +330,9 @@ def test_brownout_rungs_engage_and_release_with_hysteresis():
     assert bo.admission_scale() == 1.0
     assert bo.observe_occupancy(0.7, now=t0 + 1) == 1
     assert bo.admission_scale() == 0.5
-    assert bo.coalesce_window_scale() == 0.5
     assert bo.nprobe_shift() == 0
     assert bo.observe_occupancy(0.9, now=t0 + 2) == 2
     assert bo.admission_scale() == 0.25
-    assert bo.coalesce_window_scale() == 0.0
     assert bo.nprobe_shift() == 1
     # oscillating just below the threshold does NOT release inside hold_s
     assert bo.observe_occupancy(0.5, now=t0 + 2.1) == 2
@@ -820,31 +818,3 @@ def test_chaos_scale_refused_backs_off_typed_under_spawn(tmp_path):
     )
     assert "membership change complete: cluster is n=4" not in err
     assert "restarting the cluster" not in err
-
-
-# -- bench registration satellites --------------------------------------------
-
-
-def test_bench_sections_all_have_deadlines():
-    """Satellite: section registration auto-derives both deadline tables —
-    a section can no longer be added without them (the orchestrator used to
-    KeyError at run time)."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    assert set(bench.SUB_BENCHES) == set(bench._DEADLINES_FULL)
-    assert set(bench.SUB_BENCHES) == set(bench._DEADLINES_SMALL)
-    assert "autoscale" in bench.SUB_BENCHES
-
-
-def test_bench_positional_name_is_loud_usage_error():
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "not-a-section"],
-        capture_output=True, text=True, timeout=60,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode == 2
-    assert "unknown section" in proc.stderr
-    assert "autoscale" in proc.stderr  # usage lists the sections

@@ -1,5 +1,5 @@
 """Bring-up contracts that hold without a chip: where the compile cache goes, and
-that the two chip-facing scripts refuse the CPU platform instead of falling back.
+that the two chip-facing scripts (chip_smoke.py, benchmarks/run.py) refuse the CPU platform instead of falling back.
 
 Each check needs a fresh interpreter (the cache directory is decided at import,
 the scripts decide at start-up); they are started together, so the whole file
@@ -37,14 +37,16 @@ def fresh(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("bringup")
     asked = str(tmp / "asked-for")
     print_dir = [sys.executable, "-c", _PRINT_CACHE_DIR]
-    bench_env = _env()
-    bench_env.pop("PW_BENCH_SMOKE", None)
+    run_py = [
+        sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+        "--workload", "serve-dense-2m", "--seed", "1", "--seconds", "3", "--trace", "0",
+    ]
     launches = {
         "cache_env": (print_dir, str(tmp), _env(JAX_COMPILATION_CACHE_DIR=asked)),
         "cache_default_elsewhere": (print_dir, str(tmp), _env()),
         "cache_default_in_repo": (print_dir, REPO, _env()),
         "chip_smoke": ([sys.executable, os.path.join(REPO, "chip_smoke.py")], REPO, _env()),
-        "bench": ([sys.executable, os.path.join(REPO, "bench.py")], REPO, bench_env),
+        "benchmark": (run_py, REPO, _env()),
     }
     procs = {
         name: subprocess.Popen(
@@ -81,8 +83,8 @@ def test_compile_cache_dir_default_is_fixed_under_the_checkout(fresh):
 
 
 def test_no_other_code_sets_a_compile_cache_dir():
-    """One function in one place (pathway_tpu/__init__.py) decides it; bench.py,
-    chip_smoke.py and everything under the package only inherit."""
+    """One function in one place (pathway_tpu/__init__.py) decides it;
+    chip_smoke.py, benchmarks/ and everything under the package only inherit."""
     hits = []
     for base, dirs, files in os.walk(REPO):
         dirs[:] = [
@@ -110,13 +112,14 @@ def test_chip_smoke_refuses_the_cpu_platform(fresh):
     assert "encoder layers" not in proc.stdout  # no phase started
 
 
-def test_bench_refuses_to_measure_without_a_chip(fresh):
-    """`python bench.py` with no chip and no explicit toy-scale request stops
-    non-zero at the first section; it does not fall back."""
-    proc = fresh["bench"]
+def test_benchmark_refuses_to_measure_without_a_chip(fresh):
+    """`python benchmarks/run.py` on the CPU platform without `--rehearse` stops
+    non-zero before any set-up and says it has no result; it does not fall back."""
+    proc = fresh["benchmark"]
     assert proc.returncode != 0
-    assert "no accelerator" in proc.stderr
-    assert "knn_query_qps" not in proc.stdout  # no metric line under a device name
+    assert "no result" in proc.stderr
+    assert "device: cpu" in proc.stdout + proc.stderr  # its first act: say what JAX found
+    assert "retrieve_p50_ms" not in proc.stdout  # no metric line under a device name
 
 
 def test_cluster_rank_on_an_accelerator_stops_with_the_cause(monkeypatch):

@@ -1,7 +1,8 @@
-"""Encoder service tests (ISSUE 11): continuous batching, pre-warmed jit
-buckets, the semantic query cache's honesty contract (exact mode bitwise;
-retraction/re-ingest isolation), the preserved shed/backpressure contract
-through the coalescer shim, and the fence-replay exactly-once extension for
+"""Encoder service tests: continuous batching, pre-warmed jit buckets, the
+semantic query cache's honesty contract (exact mode bitwise;
+retraction/re-ingest isolation), the one admission point (row cap, probe,
+typed shed, Retry-After, ``embed.shed``) reached directly and through
+``EmbedPipeline``, and the fence-replay exactly-once extension for
 service-queued in-flight queries. All tier-1 (CPU, tiny encoder config)."""
 
 from __future__ import annotations
@@ -61,6 +62,43 @@ class _HashEncoder:
     def encode_device(self, texts):
         self.calls.append(list(texts))
         return np.stack(_hash_rows(texts))
+
+
+class _GatedHashEncoder(_HashEncoder):
+    """Holds its first forward until ``release`` is set, so a burst piles up
+    behind tick 1."""
+
+    def __init__(self):
+        super().__init__()
+        self.release = threading.Event()
+        self._held = False
+
+    def encode_device(self, texts):
+        if not self._held:
+            self._held = True
+            self.release.wait(timeout=10)
+        return super().encode_device(texts)
+
+
+ENTRIES = ("submit", "embed_query_rows")
+
+
+def _service_and_entry(encoder, entry, **kwargs):
+    """The service and the call that reaches it: ``submit`` directly, or
+    ``EmbedPipeline.embed_query_rows`` with the caches off, which is how a
+    commit reaches it."""
+    if entry == "submit":
+        svc = EncoderService(encoder, prewarm=False, **kwargs)
+        return svc, svc.submit
+    pipe = EmbedPipeline(encoder, model=entry, cache_size=0, **kwargs)
+    return pipe.service, pipe.embed_query_rows
+
+
+def _wait_depth(svc, rows, what):
+    deadline = time.monotonic() + 5.0
+    while svc.queue_depth_rows() != rows:
+        assert time.monotonic() < deadline, what
+        time.sleep(0.01)
 
 
 # ---------------------------------------------------------------------------
@@ -133,70 +171,50 @@ def test_service_solo_submit_no_deadline_wait():
     svc.close()
 
 
-def test_service_concurrent_clients_coalesce_and_get_own_rows():
-    release = threading.Event()
-    first_gate = [True]
-
-    class _GatedHashEncoder(_HashEncoder):
-        def encode_device(self, texts):
-            if first_gate[0]:
-                first_gate[0] = False
-                release.wait(timeout=10)  # hold tick 1 so a burst piles up
-            return super().encode_device(texts)
-
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_service_concurrent_clients_coalesce_and_get_own_rows(entry):
     enc = _GatedHashEncoder()
-    svc = EncoderService(enc, prewarm=False)
+    svc, embed = _service_and_entry(enc, entry)
     results: dict = {}
 
     def client(i: int) -> None:
-        results[i] = svc.submit([f"query {i}"])[0]
+        results[i] = embed([f"query {i}"])[0]
 
     threads = [threading.Thread(target=client, args=(i,)) for i in range(16)]
     threads[0].start()
     time.sleep(0.2)  # worker now held inside tick 1
     for t in threads[1:]:
         t.start()
-    deadline = time.monotonic() + 5.0
-    while svc.queue_depth_rows() < 16 and time.monotonic() < deadline:
-        time.sleep(0.01)
-    release.set()
+    _wait_depth(svc, 16, "the burst never queued")
+    enc.release.set()
     for t in threads:
         t.join(timeout=10)
     for i in range(16):  # every client got exactly ITS row
         assert np.array_equal(results[i], _hash_rows([f"query {i}"])[0]), i
     assert svc.ticks < svc.requests  # the pile-up coalesced into fewer ticks
     assert svc.max_tick_rows > 1
+    assert svc.total_rows == 16
+    assert sum(len(b) for b in enc.calls) + svc.dedup_rows == 16
     assert svc.queue_depth_rows() == 0  # slots always released
     svc.close()
 
 
-def test_service_dedups_identical_texts_within_tick():
-    release = threading.Event()
-    first_gate = [True]
-
-    class _GatedHashEncoder(_HashEncoder):
-        def encode_device(self, texts):
-            if first_gate[0]:
-                first_gate[0] = False
-                release.wait(timeout=10)
-            return super().encode_device(texts)
-
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_service_dedups_identical_texts_within_tick(entry):
     enc = _GatedHashEncoder()
-    svc = EncoderService(enc, prewarm=False)
+    svc, embed = _service_and_entry(enc, entry)
     out: list = [None] * 8
 
     def client(i: int) -> None:
-        out[i] = svc.submit(["same question"])[0]
+        out[i] = embed(["same question"])[0]
 
     threads = [threading.Thread(target=client, args=(i,)) for i in range(8)]
     threads[0].start()
     time.sleep(0.2)
     for t in threads[1:]:
         t.start()
-    deadline = time.monotonic() + 5.0
-    while svc.queue_depth_rows() < 8 and time.monotonic() < deadline:
-        time.sleep(0.01)
-    release.set()
+    _wait_depth(svc, 8, "the burst never queued")
+    enc.release.set()
     for t in threads:
         t.join(timeout=10)
     expect = _hash_rows(["same question"])[0]
@@ -207,16 +225,33 @@ def test_service_dedups_identical_texts_within_tick():
     svc.close()
 
 
-def test_service_error_propagates_and_releases_slots():
+@pytest.mark.parametrize(
+    "exc_type,waiters", [(RuntimeError, 1), (ValueError, 1), (RuntimeError, 3)]
+)
+def test_service_error_propagates_and_releases_slots(exc_type, waiters):
+    """A failing tick hands its exception, typed, to every waiter it took."""
+
     class _FailingEncoder:
         dim = 4
 
         def encode_device(self, texts):
-            raise RuntimeError("encoder exploded")
+            raise exc_type("encoder exploded")
 
     svc = EncoderService(_FailingEncoder(), prewarm=False)
-    with pytest.raises(RuntimeError, match="encoder exploded"):
-        svc.submit(["x"])
+    errors = []
+
+    def client(i: int) -> None:
+        try:
+            svc.submit([f"q{i}"])
+        except exc_type as exc:
+            errors.append(str(exc))
+
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(waiters)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+    assert errors == ["encoder exploded"] * waiters
     assert svc.queue_depth_rows() == 0  # the leak_inflight invariant, live
     # the worker survives a failing tick
     svc.encoder = _HashEncoder()
@@ -301,7 +336,7 @@ def _wait_cache_fill(pipe: EmbedPipeline, n: int, timeout: float = 10.0) -> None
 
 
 def test_exact_mode_hit_is_bitwise_identical_to_direct_encode(tiny_encoder):
-    pipe = EmbedPipeline(tiny_encoder, model="t", cache_size=64, prewarm=False)
+    pipe = EmbedPipeline(tiny_encoder, model="t", cache_size=64)
     pipe.embed_query_rows(["What is a Vector  Index?"])
     _wait_cache_fill(pipe, 1)
     variant = "  what IS a vector index?  "
@@ -315,7 +350,7 @@ def test_exact_mode_hit_is_bitwise_identical_to_direct_encode(tiny_encoder):
 
 
 def test_semantic_hit_skips_the_forward_entirely(tiny_encoder):
-    pipe = EmbedPipeline(tiny_encoder, model="t2", cache_size=64, prewarm=False)
+    pipe = EmbedPipeline(tiny_encoder, model="t2", cache_size=64)
     calls = []
     orig = tiny_encoder.encode_device
     tiny_encoder.encode_device = lambda t: (calls.append(list(t)), orig(t))[1]
@@ -331,10 +366,10 @@ def test_semantic_hit_skips_the_forward_entirely(tiny_encoder):
 
 
 def test_cosine_mode_is_opt_in_and_off_by_default(tiny_encoder):
-    pipe = EmbedPipeline(tiny_encoder, model="t3", cache_size=64, prewarm=False)
+    pipe = EmbedPipeline(tiny_encoder, model="t3", cache_size=64)
     assert pipe.semantic_cache.mode == "exact"
     pipe2 = EmbedPipeline(
-        tiny_encoder, model="t4", cache_size=64, prewarm=False,
+        tiny_encoder, model="t4", cache_size=64,
         semantic_mode="cosine", semantic_threshold=0.8,
     )
     assert pipe2.semantic_cache.mode == "cosine"
@@ -345,7 +380,7 @@ def test_reingest_never_served_from_semantic_cache(tiny_encoder):
     """The ingest path (encode_batch) must not consult the semantic cache: a
     poisoned semantic entry for the same canonical text must never leak into
     document embeddings on re-ingest."""
-    pipe = EmbedPipeline(tiny_encoder, model="t5", cache_size=64, prewarm=False)
+    pipe = EmbedPipeline(tiny_encoder, model="t5", cache_size=64)
     text = "document chunk about cats"
     truth = pipe.encode_batch([text])[0]
     # plant a poisoned semantic entry under the same canonical key
@@ -369,7 +404,7 @@ def test_retractions_never_reach_semantic_cache():
     from pathway_tpu.engine.runner import GraphRunner
     from pathway_tpu.internals import parse_graph as pg
 
-    emb = _tiny_embedder(embed_cache_size=64, encsvc_prewarm=False)
+    emb = _tiny_embedder(embed_cache_size=64)
     forwards = []
     orig = emb.encoder.encode_device
     emb.encoder.encode_device = lambda t: (forwards.append(list(t)), orig(t))[1]
@@ -403,66 +438,154 @@ def test_retractions_never_reach_semantic_cache():
 
 
 # ---------------------------------------------------------------------------
-# shed/backpressure contract preserved through the coalescer shim
+# the one admission point: row cap, probe, typed shed, Retry-After
 # ---------------------------------------------------------------------------
 
 
-def test_shim_sheds_with_honest_retry_after_when_service_backed_up():
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_service_sheds_past_the_row_cap_with_honest_retry_after(entry):
+    """Past ``max_queue_rows`` pending rows (waiting + in flight) ``submit``
+    sheds a direct caller with a typed EmbedOverloadError carrying an honest
+    Retry-After and counts ``embed.shed``; the REST probe says the same; the
+    engine path (admitted at the REST boundary) is never refused; and
+    admission opens again once the queue drains. Same contract whether the
+    service was built bare or by ``EmbedPipeline`` with its cap."""
     from pathway_tpu.engine import telemetry
 
-    release = threading.Event()
-
-    class _GatedEncoder:
-        dim = 4
-
-        def encode_device(self, texts):
-            release.wait(timeout=10)
-            return np.zeros((len(texts), 4), dtype=np.float32)
-
-    pipe = EmbedPipeline(
-        _GatedEncoder(), model="shed", cache_size=0, max_queue_rows=2,
-        prewarm=False,
-    )
-    assert pipe.coalescer._service is pipe.service  # shim mode active
+    enc = _GatedHashEncoder()
+    svc, embed = _service_and_entry(enc, entry, max_queue_rows=3)
     done: dict = {}
 
     def client(name, texts):
-        done[name] = pipe.coalescer.embed(texts)
+        done[name] = svc.submit(texts)
 
+    # a: taken by the worker and held inside encode_device (in flight)
     ta = threading.Thread(target=client, args=("a", ["a"]))
     ta.start()
-    deadline = time.perf_counter() + 5.0
-    # row a is in flight (worker holds it inside encode_device)
-    while pipe.service.queue_depth_rows() != 1:
-        assert time.perf_counter() < deadline, "worker never picked up row a"
-        time.sleep(0.01)
-    tb = threading.Thread(target=client, args=("b", ["b"]))
+    _wait_depth(svc, 1, "worker never picked up row a")
+    # b: fills the cap exactly (two rows waiting behind the held tick)
+    tb = threading.Thread(target=client, args=("b", ["b1", "b2"]))
     tb.start()
-    while pipe.service.queue_depth_rows() != 2:
-        assert time.perf_counter() < deadline, "row b never queued"
-        time.sleep(0.01)
+    _wait_depth(svc, 3, "rows b never queued")
 
-    assert pipe.coalescer.overloaded()
+    assert svc.overloaded()
     shed_before = telemetry.stage_snapshot("embed.").get("embed.shed", 0.0)
     with pytest.raises(EmbedOverloadError) as exc_info:
-        pipe.coalescer.embed(["c"])
+        svc.submit(["c"])
     assert exc_info.value.retry_after_s >= 1.0
-    assert pipe.coalescer.shed_requests == 1
+    assert svc.shed_requests == 1
     assert telemetry.stage_snapshot("embed.").get("embed.shed", 0.0) == shed_before + 1
-    # the engine path (already admitted at the REST boundary) still bypasses
-    done["d"] = None
+    # the engine path (already admitted at the REST boundary) is not refused
     td = threading.Thread(
-        target=lambda: done.update(d=pipe.coalescer.embed(["d"], enforce_cap=False))
+        target=lambda: done.update(
+            d=embed(["d"]) if entry == "embed_query_rows"
+            else svc.submit(["d"], enforce_cap=False)
+        )
     )
     td.start()
-    release.set()
+    _wait_depth(svc, 4, "row d was not admitted past the cap")
+    enc.release.set()
     for t in (ta, tb, td):
         t.join(timeout=10)
-    assert all(done[k] is not None for k in ("a", "b", "d"))
-    # queue drained: admission opens again, no sticky overload
-    assert not pipe.coalescer.overloaded()
-    assert len(pipe.coalescer.embed(["e"])) == 1
+    assert np.array_equal(done["a"][0], _hash_rows(["a"])[0])
+    assert np.array_equal(done["b"][1], _hash_rows(["b2"])[0])
+    assert np.array_equal(done["d"][0], _hash_rows(["d"])[0])
+    # the queue drained: admission opens again, no sticky overload state
+    assert not svc.overloaded()
+    assert np.array_equal(svc.submit(["e"])[0], _hash_rows(["e"])[0])
+    assert svc.shed_requests == 1
+    svc.close()
+
+
+def test_service_retry_after_scales_with_queue_depth():
+    """Retry-After must be an estimate, not a constant: a deeper queue names a
+    later retry (ticks-to-drain x smoothed tick time, floored at 1 s)."""
+    svc = EncoderService(_HashEncoder(), max_in_flight=2, prewarm=False)
+    svc._encode_ewma_s = 2.0  # pretend the encoder runs 2 s ticks
+    shallow = svc.retry_after_s(extra_rows=2)    # 1 tick to drain
+    deep = svc.retry_after_s(extra_rows=20)      # 10 ticks to drain
+    assert shallow >= 1.0
+    assert deep > shallow * 5
+    svc.close()
+
+
+def test_service_overload_probe_and_engine_path_bypass():
+    """``overloaded`` is the REST pre-admission probe for the row cap;
+    ``submit(enforce_cap=False)`` (the engine serving path — its request was
+    already admitted against the cap at the REST boundary) never raises even
+    past the cap, so a race between admission and the commit cannot tear the
+    run down."""
+    svc = EncoderService(_HashEncoder(), max_queue_rows=2, prewarm=False)
+    assert not svc.overloaded()
+    svc._queued_rows = 2  # simulate a full queue without racing the worker
+    assert svc.overloaded()
+    assert svc.overloaded(extra_rows=1)
+    svc._queued_rows = 0
+    assert not svc.overloaded()
+    assert svc.overloaded(extra_rows=2)
+    svc._queued_rows = 5  # past the cap: enforce_cap=False must still admit
+    got = svc.submit(["x", "y", "z"], enforce_cap=False)
+    assert np.array_equal(got[2], _hash_rows(["z"])[0])
+    assert svc.shed_requests == 0
+    svc.close()
+
+    unbounded = EncoderService(_HashEncoder(), prewarm=False)
+    assert not unbounded.overloaded(extra_rows=10**9)  # cap 0 = disabled
+    unbounded.close()
+
+
+def test_shed_is_counted_once_at_the_one_admission_point():
+    """``embed.shed`` and ``stats()["svc_shed_requests"]`` move together: the
+    probe counts nothing, a refused ``submit`` counts once in both."""
+    from pathway_tpu.engine import telemetry
+
+    svc = EncoderService(_HashEncoder(), max_queue_rows=2, prewarm=False)
+    svc._queued_rows = 2  # a full queue, without racing the worker
+    before = telemetry.stage_snapshot("embed.").get("embed.shed", 0.0)
+    for n in range(1, 4):
+        assert svc.overloaded()
+        with pytest.raises(EmbedOverloadError):
+            svc.submit([f"refused {n}"])
+        counted = telemetry.stage_snapshot("embed.").get("embed.shed", 0.0) - before
+        assert counted == n == svc.stats()["svc_shed_requests"]
+    svc._queued_rows = 0
+    svc.close()
+
+
+def test_overloaded_feeds_the_brownout_ladder_from_the_service_depth():
+    """Each probe hands the ladder one occupancy sample, pending rows over
+    the cap: a service at 90 % engages rung 2 with no other signal."""
+    from pathway_tpu.engine.brownout import get_brownout
+
+    svc = EncoderService(_HashEncoder(), max_queue_rows=10, prewarm=False)
+    assert get_brownout().level() == 0
+    svc._queued_rows, svc._inflight_rows = 2, 1  # 30 %
+    assert not svc.overloaded()
+    assert get_brownout().level() == 0
+    svc._queued_rows, svc._inflight_rows = 5, 4  # 90 %: waiting + in flight
+    assert not svc.overloaded()
+    assert get_brownout().level() == 2
+    assert svc.overloaded(extra_rows=1)
+    svc._queued_rows = svc._inflight_rows = 0
+    svc.close()
+
+    unbounded = EncoderService(_HashEncoder(), prewarm=False)
+    unbounded._queued_rows = 10**6
+    assert not unbounded.overloaded()  # no cap: no occupancy to report
+    unbounded._queued_rows = 0
+    unbounded.close()
+
+
+def test_embed_pipeline_wires_queue_cap_from_env(monkeypatch, tiny_encoder):
+    """EmbedPipeline hands PATHWAY_EMBED_MAX_QUEUE_ROWS to its service, and
+    an explicit argument wins over the env."""
+    monkeypatch.setenv("PATHWAY_EMBED_MAX_QUEUE_ROWS", "17")
+    pipe = EmbedPipeline(tiny_encoder, model="t")
+    assert pipe.service.max_queue_rows == 17
     pipe.service.close()
+    pipe2 = EmbedPipeline(tiny_encoder, model="t", max_queue_rows=0)
+    assert pipe2.service.max_queue_rows == 0
+    pipe2.service.close()
 
 
 # ---------------------------------------------------------------------------
@@ -480,8 +603,7 @@ def test_fence_replay_service_inflight_queries_exactly_once():
     not have answered any retraction."""
     from pathway_tpu.engine.expression_evaluator import evaluate
 
-    emb = _tiny_embedder(embed_cache_size=64, encsvc_prewarm=False)
-    assert emb.pipeline.service is not None  # the service path is under test
+    emb = _tiny_embedder(embed_cache_size=64)
     forwards = []
     orig = emb.encoder.encode_device
     emb.encoder.encode_device = lambda t: (forwards.append(list(t)), orig(t))[1]

@@ -568,8 +568,11 @@ def test_fused_rejoin_replays_bit_identical(tmp_path):
             sys.executable, str(prog),
         ],
         env=env, cwd=str(tmp_path), start_new_session=True,
-        stdout=subprocess.DEVNULL, stderr=subprocess.PIPE, text=True,
+        stdout=subprocess.DEVNULL, stderr=open(tmp_path / "spawn.err", "w"),
     )
+
+    def supervisor_log() -> str:
+        return (tmp_path / "spawn.err").read_text()
 
     def read_merged() -> dict:
         merged: dict = {}
@@ -602,33 +605,39 @@ def test_fused_rejoin_replays_bit_identical(tmp_path):
     for w, v in late_rows:
         fold(expected, w, v)
 
-    err = ""
+    def wait_for(done, what) -> None:
+        deadline = time.time() + 120
+        while not done():
+            if proc.poll() is not None:
+                raise AssertionError(f"spawn exited early: {supervisor_log()[-3000:]}")
+            assert time.time() < deadline, f"{what()}\n{supervisor_log()[-3000:]}"
+            time.sleep(0.3)
+
     try:
-        time.sleep(10)  # kill + fence + rejoin window
+        # a wait on state, not on the clock: the late file is written once the
+        # supervisor has said the failover is over. A fixed sleep lost the race
+        # under load in two ways: ranks still importing when the file landed
+        # ingested all four files before commit 3, and a run that converged
+        # before the rejoin was logged was torn down mid-fence
+        wait_for(
+            lambda: "rejoined the cluster" in supervisor_log()
+            or "restarting the cluster" in supervisor_log(),
+            lambda: "no recovery happened — the kill never fired?",
+        )
         (tmp_path / "in" / "late.csv").write_text(
             "word,v\n" + "\n".join(f"{w},{v}" for w, v in late_rows) + "\n"
         )
-        deadline = time.time() + 120
-        merged: dict = {}
-        while time.time() < deadline:
-            if proc.poll() is not None:
-                _, err = proc.communicate()
-                raise AssertionError(f"spawn exited early: {err[-3000:]}")
-            merged = read_merged()
-            if merged == expected:
-                break
-            time.sleep(0.3)
-        assert merged == expected, f"got {merged}, want {expected}"
+        wait_for(
+            lambda: read_merged() == expected,
+            lambda: f"never converged: got {read_merged()}, want {expected}",
+        )
     finally:
         try:
             os.killpg(proc.pid, signal.SIGTERM)
         except ProcessLookupError:
             pass
         try:
-            _, err = proc.communicate(timeout=20)
+            proc.wait(timeout=20)
         except subprocess.TimeoutExpired:
             os.killpg(proc.pid, signal.SIGKILL)
-            _, err = proc.communicate()
-    assert "rejoined the cluster" in (err or "") or "restarting the cluster" in (
-        err or ""
-    ), f"no recovery happened — the kill never fired?\n{err}"
+            proc.wait()

@@ -139,43 +139,6 @@ def test_checkpoint_toctou_double_commit_caught_with_seed():
 
 
 # ---------------------------------------------------------------------------
-# coalescer admission / shed
-# ---------------------------------------------------------------------------
-
-
-def test_coalescer_invariants_hold_exhaustive():
-    result = explore(
-        pm.coalescer_model(3, cap=2), max_schedules=N_SCHEDULES, name="coal"
-    )
-    assert result.ok, f"{result.failing_schedule}: {result.failure}"
-    assert result.distinct_schedules >= N_SCHEDULES
-
-
-def test_coalescer_error_path_releases_slots():
-    result = explore(
-        pm.coalescer_model(3, cap=2, fail_batch=True),
-        max_schedules=N_SCHEDULES,
-        name="coal-err",
-    )
-    assert result.ok, f"{result.failing_schedule}: {result.failure}"
-
-
-def test_coalescer_slot_leak_bug_caught_and_replayable():
-    result = explore(
-        pm.coalescer_model(3, cap=2, fail_batch=True, bug="leak_slot"),
-        max_schedules=300,
-        name="coal-leak",
-    )
-    assert isinstance(result.failure, InvariantViolation)
-    assert "admission slots leaked" in str(result.failure)
-    with pytest.raises(InvariantViolation, match="admission slots leaked"):
-        run_once(
-            pm.coalescer_model(3, cap=2, fail_batch=True, bug="leak_slot"),
-            choices=result.failing_schedule,
-        )
-
-
-# ---------------------------------------------------------------------------
 # encoder service admission / tick / shutdown
 # ---------------------------------------------------------------------------
 
@@ -184,7 +147,7 @@ def test_coalescer_slot_leak_bug_caught_and_replayable():
 def test_encoder_service_invariants_hold_exhaustive():
     t0 = time.monotonic()
     result = explore(
-        pm.encoder_service_model(3, cap=2, max_inflight=2),
+        pm.encsvc_model(3, cap=2, max_inflight=2),
         max_schedules=N_SCHEDULES,
         name="encsvc",
     )
@@ -199,7 +162,7 @@ def test_encoder_service_invariants_hold_exhaustive():
 @pytest.mark.encsvc
 def test_encoder_service_invariants_hold_seeded():
     result = sweep_seeds(
-        pm.encoder_service_model(3, cap=2, max_inflight=2),
+        pm.encsvc_model(3, cap=2, max_inflight=2),
         n_seeds=100,
         base_seed=21,
         name="encsvc-seeded",
@@ -211,7 +174,7 @@ def test_encoder_service_invariants_hold_seeded():
 @pytest.mark.encsvc
 def test_encoder_service_error_path_releases_slots():
     result = explore(
-        pm.encoder_service_model(3, cap=3, max_inflight=2, fail_batch=True),
+        pm.encsvc_model(3, cap=3, max_inflight=2, fail_batch=True),
         max_schedules=N_SCHEDULES,
         name="encsvc-err",
     )
@@ -221,7 +184,7 @@ def test_encoder_service_error_path_releases_slots():
 @pytest.mark.encsvc
 def test_encoder_service_inflight_leak_bug_caught_and_replayable():
     result = explore(
-        pm.encoder_service_model(3, cap=3, max_inflight=2, fail_batch=True,
+        pm.encsvc_model(3, cap=3, max_inflight=2, fail_batch=True,
                                  bug="leak_inflight"),
         max_schedules=400,
         name="encsvc-leak",
@@ -230,7 +193,7 @@ def test_encoder_service_inflight_leak_bug_caught_and_replayable():
     assert "in-flight slots leaked" in str(result.failure)
     with pytest.raises(InvariantViolation, match="in-flight slots leaked"):
         run_once(
-            pm.encoder_service_model(3, cap=3, max_inflight=2, fail_batch=True,
+            pm.encsvc_model(3, cap=3, max_inflight=2, fail_batch=True,
                                      bug="leak_inflight"),
             choices=result.failing_schedule,
         )
@@ -240,7 +203,7 @@ def test_encoder_service_inflight_leak_bug_caught_and_replayable():
 def test_encoder_service_drop_on_close_bug_caught_and_replayable():
     # shutdown racing admitted requests: the no-drain worker strands them
     result = sweep_seeds(
-        pm.encoder_service_model(3, cap=3, max_inflight=1, bug="drop_on_close"),
+        pm.encsvc_model(3, cap=3, max_inflight=1, bug="drop_on_close"),
         n_seeds=300,
         base_seed=31,
         name="encsvc-drop",
@@ -251,7 +214,7 @@ def test_encoder_service_drop_on_close_bug_caught_and_replayable():
     assert "dropped at shutdown" in str(result.failure)
     with pytest.raises(InvariantViolation, match="dropped at shutdown"):
         run_once(
-            pm.encoder_service_model(3, cap=3, max_inflight=1, bug="drop_on_close"),
+            pm.encsvc_model(3, cap=3, max_inflight=1, bug="drop_on_close"),
             seed=result.failing_seed,
         )
 
@@ -261,7 +224,7 @@ def test_encoder_service_lost_close_wakeup_deadlocks():
     # a notify-less stop against the notify-driven idle wait = the lost-wakeup
     # class (the real service's timed tick is the defense); proven a deadlock
     result = explore(
-        pm.encoder_service_model(2, cap=2, max_inflight=2,
+        pm.encsvc_model(2, cap=2, max_inflight=2,
                                  bug="lost_close_wakeup"),
         max_schedules=400,
         name="encsvc-lostwake",
@@ -269,7 +232,7 @@ def test_encoder_service_lost_close_wakeup_deadlocks():
     assert isinstance(result.failure, DeadlockError), result.failure
     with pytest.raises(DeadlockError):
         run_once(
-            pm.encoder_service_model(2, cap=2, max_inflight=2,
+            pm.encsvc_model(2, cap=2, max_inflight=2,
                                      bug="lost_close_wakeup"),
             choices=result.failing_schedule,
         )

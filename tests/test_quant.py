@@ -65,7 +65,7 @@ def _int8_store(dim, n_clusters, n_probe, **kw):
 
 
 def _assert_rescore_bitwise(store, queries, scores, idx):
-    """The external honesty recompute ``bench.py quant`` also runs: every
+    """The external honesty recompute: every
     returned score must equal the pinned epilogue over the returned pair's
     fp32 source row, bit for bit."""
     qn = np.sum(queries * queries, axis=1)
@@ -173,7 +173,7 @@ def test_rescore_depth_follows_env_and_clamps_to_k(monkeypatch):
     caller asked for). Pinned via the rescore-depth histogram the epilogue
     observes, not via recall: at depth 4 near-ties in a crowded dim-8 set
     legitimately land outside the shortlist, which is WHY the default is
-    64 — recall-at-depth is bench.py's honesty key, not a unit invariant."""
+    64 — recall-at-depth is a measurement, not a unit invariant."""
     from pathway_tpu.engine.profile import histogram
 
     monkeypatch.setenv("PATHWAY_IVF_RESCORE_K", "4")

@@ -31,7 +31,7 @@ class _Path:
         self.encoder = JaxSentenceEncoder(config=EncoderConfig(
             vocab_size=30522, hidden_size=DIM, num_layers=1, num_heads=2,
             intermediate_size=64))
-        self.pipe = EmbedPipeline(self.encoder, model="host-rows", prewarm=False)
+        self.pipe = EmbedPipeline(self.encoder, model="host-rows")
         corpus = np.random.default_rng(7).normal(size=(DOCS, DIM)).astype(np.float32)
         self.corpus = corpus / np.linalg.norm(corpus, axis=1, keepdims=True)
         self.index = BruteForceKnnIndex(DIM, metric="cos", initial_capacity=256)

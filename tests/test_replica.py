@@ -5,8 +5,7 @@ What the suite proves, layer by layer:
 
 - **feed round-trip** — a replica bootstrapped from the primary's
   read-back-verified export and caught up through the frame tail answers
-  BITWISE-identically to the primary at the same commit id (the ``bench.py
-  replicas`` honesty key);
+  BITWISE-identically to the primary at the same commit id;
 - **bounded bootstrap** — the export streams in bounded row fragments, so
   a replica's peak install memory is one fragment, never the corpus;
 - **typed refusal** — a torn bootstrap (chaos ``replica_torn_bootstrap``)

@@ -704,8 +704,6 @@ def _code_knobs() -> set:
                 continue
             with open(os.path.join(base, name), "r", encoding="utf-8") as f:
                 out.update(_KNOB_RE.findall(f.read()))
-    with open(os.path.join(REPO, "bench.py"), "r", encoding="utf-8") as f:
-        out.update(_KNOB_RE.findall(f.read()))
     return out
 
 

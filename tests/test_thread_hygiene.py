@@ -2,9 +2,9 @@
 ``pw.run`` / stepped-run teardown and after a monitoring/REST server stop, no
 non-daemon thread beyond the main thread survives — a leaked non-daemon
 thread blocks interpreter shutdown and holds its resources across back-to-back
-runs. Plus regression tests for the PWA102 fix in ``QueryCoalescer``: the
-previously-untimed ``event.wait()`` now aborts typed instead of wedging the
-engine thread when the coalescer dies with the request still queued."""
+runs. Plus the PWA102 contract of ``EncoderService``: a submission's wait is
+bounded and abortable, and fails typed instead of wedging the engine thread
+when the service dies with the submission still queued."""
 
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 import pathway_tpu as pw
-from pathway_tpu.models.embed_pipeline import QueryCoalescer
+from pathway_tpu.models.encoder_service import EncoderService, _Submission
 
 
 def _non_daemon_threads():
@@ -100,77 +100,9 @@ def test_no_nondaemon_threads_after_rest_webserver_stop():
 
 
 # ---------------------------------------------------------------------------
-# QueryCoalescer PWA102 regression: the wait is bounded and abortable
-# ---------------------------------------------------------------------------
-
-
-def _rows(texts):
-    return [np.zeros(4, dtype=np.float32) for _ in texts]
-
-
-def test_coalescer_close_with_live_worker_still_answers():
-    co = QueryCoalescer(_rows, max_wait_ms=1.0, max_batch=8)
-    out = co.embed(["a", "b"])
-    assert len(out) == 2
-    co.close()
-    co.close()  # idempotent
-
-
-def test_coalescer_close_with_dead_worker_fails_typed_not_wedged():
-    """A request stranded in the queue with no worker to drain it must fail
-    typed within the poll interval — before the fix, embed() sat in an
-    untimed event.wait() forever (the PWA102 finding)."""
-    co = QueryCoalescer(_rows, max_wait_ms=1.0, max_batch=8)
-    # plant a stranded request: queued, no worker thread, coalescer closed —
-    # the state a worker crash (or an exec-env teardown) leaves behind
-    from pathway_tpu.models.embed_pipeline import _Request
-
-    req = _Request(["stuck"])
-    with co._cond:
-        co._queue.append(req)
-        co._queued_rows += 1
-        co._closed = True
-    t0 = time.monotonic()
-    with pytest.raises(RuntimeError, match="closed before this request"):
-        co._await(req)
-        raise req.error  # _await sets the typed error; embed() re-raises it
-    assert time.monotonic() - t0 < 5.0, "abort took longer than the poll bound"
-    assert co._queued_rows == 0, "admission slot leaked on the abort path"
-
-
-def test_coalescer_wait_timeout_knob(monkeypatch):
-    """PATHWAY_EMBED_WAIT_TIMEOUT_S bounds the total wait against a wedged
-    encoder device."""
-    release = threading.Event()
-
-    def wedged_encoder(texts):
-        release.wait(timeout=30)
-        return _rows(texts)
-
-    monkeypatch.setenv("PATHWAY_EMBED_WAIT_TIMEOUT_S", "1")
-    co = QueryCoalescer(wedged_encoder, max_wait_ms=1.0, max_batch=8)
-    assert co.wait_timeout_s == 1.0
-    t0 = time.monotonic()
-    with pytest.raises(TimeoutError, match="PATHWAY_EMBED_WAIT_TIMEOUT_S"):
-        co.embed(["x"])
-    assert time.monotonic() - t0 < 10.0
-    release.set()  # un-wedge the worker so it exits
-    co.close()
-
-
-def test_coalescer_error_propagation_still_works():
-    def failing(texts):
-        raise ValueError("encoder down")
-
-    co = QueryCoalescer(failing, max_wait_ms=1.0, max_batch=8)
-    with pytest.raises(ValueError, match="encoder down"):
-        co.embed(["x"])
-    co.close()
-
-
-# ---------------------------------------------------------------------------
-# EncoderService worker hygiene: clean shutdown on service stop/close and on
-# pw.run teardown (the leaked-thread check for the service worker)
+# EncoderService PWA102 contract: the wait is bounded and abortable. Worker
+# hygiene: clean shutdown on service stop/close and on pw.run teardown (the
+# leaked-thread check for the service worker)
 # ---------------------------------------------------------------------------
 
 
@@ -181,9 +113,73 @@ class _InstantEncoder:
         return np.zeros((len(texts), 4), dtype=np.float32)
 
 
-def test_encoder_service_worker_stops_on_stop_and_close():
-    from pathway_tpu.models.encoder_service import EncoderService
+class _GatedEncoder(_InstantEncoder):
+    def __init__(self):
+        self.release = threading.Event()
 
+    def encode_device(self, texts):
+        self.release.wait(timeout=30)
+        return super().encode_device(texts)
+
+
+def test_encoder_service_close_with_live_worker_still_answers():
+    """close() racing an admitted submission: the live worker drains the queue
+    before it exits, so the submission is answered, not dropped."""
+    enc = _GatedEncoder()
+    svc = EncoderService(enc, prewarm=False)
+    got = []
+    t = threading.Thread(target=lambda: got.append(svc.submit(["a", "b"])))
+    t.start()
+    deadline = time.monotonic() + 5.0
+    while svc.queue_depth_rows() != 2 or not svc.worker_alive():
+        assert time.monotonic() < deadline, "submission never admitted"
+        time.sleep(0.01)
+    closer = threading.Thread(target=svc.close)  # joins the worker: off-thread
+    closer.start()
+    enc.release.set()
+    t.join(timeout=10)
+    closer.join(timeout=10)
+    assert got and len(got[0]) == 2, "admitted submission dropped at close"
+    assert not svc.worker_alive()
+    svc.close()  # idempotent
+
+
+def test_encoder_service_close_with_dead_worker_fails_typed_not_wedged():
+    """A submission stranded in the queue of a closed service with no worker
+    to drain it must fail typed within the poll interval, not sit in an
+    untimed event.wait() forever (the PWA102 finding)."""
+    svc = EncoderService(_InstantEncoder(), prewarm=False)
+    # plant a stranded submission: queued, no worker thread, service closed —
+    # the state a worker crash (or an exec-env teardown) leaves behind
+    sub = _Submission(["stuck"])
+    with svc._cond:
+        svc._queue.append(sub)
+        svc._queued_rows += 1
+        svc._closed = True
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError, match="closed before this submission"):
+        svc._await(sub)
+        raise sub.error  # _await sets the typed error; submit() re-raises it
+    assert time.monotonic() - t0 < 5.0, "abort took longer than the poll bound"
+    assert svc.queue_depth_rows() == 0, "admission slot leaked on the abort path"
+
+
+def test_encoder_service_wait_timeout_knob(monkeypatch):
+    """PATHWAY_EMBED_WAIT_TIMEOUT_S bounds the total wait against a wedged
+    encoder device."""
+    enc = _GatedEncoder()
+    monkeypatch.setenv("PATHWAY_EMBED_WAIT_TIMEOUT_S", "1")
+    svc = EncoderService(enc, prewarm=False)
+    assert svc.wait_timeout_s == 1.0
+    t0 = time.monotonic()
+    with pytest.raises(TimeoutError, match="PATHWAY_EMBED_WAIT_TIMEOUT_S"):
+        svc.submit(["x"])
+    assert time.monotonic() - t0 < 10.0
+    enc.release.set()  # un-wedge the worker so it exits
+    svc.close()
+
+
+def test_encoder_service_worker_stops_on_stop_and_close():
     svc = EncoderService(_InstantEncoder(), prewarm=False)
     assert not svc.worker_alive()  # lazy spawn: no thread before first submit
     out = svc.submit(["a", "b"])
@@ -204,18 +200,8 @@ def test_encoder_service_worker_stops_on_stop_and_close():
 def test_encoder_service_stop_with_inflight_request_still_answers():
     """stop_all_workers racing an admitted request must drain, not drop (the
     drop_on_close bug class from the protocol model, checked on real threads)."""
-    from pathway_tpu.models.encoder_service import EncoderService
-
-    release = threading.Event()
-
-    class _GatedEncoder:
-        dim = 4
-
-        def encode_device(self, texts):
-            release.wait(timeout=10)
-            return np.zeros((len(texts), 4), dtype=np.float32)
-
-    svc = EncoderService(_GatedEncoder(), prewarm=False)
+    enc = _GatedEncoder()
+    svc = EncoderService(enc, prewarm=False)
     got = []
     t = threading.Thread(target=lambda: got.append(svc.submit(["x"])))
     t.start()
@@ -224,7 +210,7 @@ def test_encoder_service_stop_with_inflight_request_still_answers():
         time.sleep(0.01)
     stopper = threading.Thread(target=svc.stop_worker)
     stopper.start()
-    release.set()
+    enc.release.set()
     t.join(timeout=10)
     stopper.join(timeout=10)
     assert got and len(got[0]) == 1, "admitted request dropped at stop"
@@ -244,8 +230,7 @@ def test_no_encoder_service_worker_after_pw_run():
         intermediate_size=64,
     )
     emb = SentenceTransformerEmbedder(
-        model="pw-test-tiny", encoder_config=tiny, encoder_service=True,
-        encsvc_prewarm=False,
+        model="pw-test-tiny", encoder_config=tiny,
     )
     before = _non_daemon_threads()
     t = pw.debug.table_from_rows(pw.schema_builder({"q": str}), [("hygiene query",)])
@@ -256,7 +241,6 @@ def test_no_encoder_service_worker_after_pw_run():
     assert len(got) == 1
     _assert_no_leaks(before, "pw.run with encoder service")
     svc = emb.pipeline.service
-    assert svc is not None
     deadline = time.monotonic() + 5.0
     while svc.worker_alive() and time.monotonic() < deadline:
         time.sleep(0.05)

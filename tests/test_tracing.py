@@ -139,7 +139,7 @@ def test_trace_defaults_off_when_env_unset(monkeypatch):
 def test_trace_span_nests_and_lands_in_ring(tracer):
     with tracer.trace_span("rest", "GET /v1/retrieve") as root:
         assert tracing.current_context().span_id == root.span_id
-        with tracer.trace_span("coalesce", "coalesce 2") as child:
+        with tracer.trace_span("embed_wait", "embed_wait 2") as child:
             pass
     assert child.parent_id == root.span_id
     assert child.trace_id == root.trace_id
@@ -153,7 +153,7 @@ def test_slow_root_promotes_buffered_children(monkeypatch, tracer):
     monkeypatch.setenv("PATHWAY_TRACE_SLOW_MS", "0")
     _sync_env(tracer)
     with tracer.trace_span("rest", "GET /slow") as root:
-        with tracer.trace_span("coalesce", "admit"):
+        with tracer.trace_span("embed_wait", "admit"):
             pass
     assert root.sampled  # promoted at finish: slow roots always sample
     ids = {s["span_id"] for s in tracer.recent_spans()}
@@ -168,7 +168,7 @@ def test_fast_root_drops_buffered_children(monkeypatch, tracer):
     monkeypatch.setenv("PATHWAY_TRACE_SLOW_MS", "60000")
     _sync_env(tracer)
     with tracer.trace_span("rest", "GET /fast"):
-        with tracer.trace_span("coalesce", "admit"):
+        with tracer.trace_span("embed_wait", "admit"):
             pass
     assert tracer.recent_spans() == []
     assert telemetry.stage_snapshot("trace.")["trace.dropped"] == 1.0
@@ -182,7 +182,7 @@ def test_epoch_bump_never_orphans_pending_spans(monkeypatch, tracer):
     monkeypatch.setenv("PATHWAY_TRACE_SLOW_MS", "0")
     _sync_env(tracer)
     with tracer.trace_span("rest", "GET /bump") as root:
-        with tracer.trace_span("coalesce", "admit") as child:
+        with tracer.trace_span("embed_wait", "admit") as child:
             pass
         tracer.set_epoch(7)
     spans = {s["span_id"]: s for s in tracer.recent_spans()}

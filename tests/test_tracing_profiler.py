@@ -74,7 +74,7 @@ def test_ring_keeps_a_traced_span_of_200_requests_a_second(sampled):
         for n in (2 * commit, 2 * commit + 1):  # the queries' commit, the retractions'
             ctx = tracing.commit_trace_context(0, n)
             with tracer.trace_span("commit", self_ctx=ctx) as span:
-                for kind in ("embed_wait", "coalesce", "search", "search.prepare",
+                for kind in ("embed_wait", "search", "search.prepare",
                              "search.prepare", "search.device_wait", "search.assemble",
                              "encode", "encode.dispatch", "tokenize", "encode.device_wait",
                              "cache_fill"):

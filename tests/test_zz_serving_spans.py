@@ -25,6 +25,9 @@ pytestmark = pytest.mark.trace
 
 ROUTE = "/v1/retrieve"
 
+#: the live server of the ``traced`` fixture, for the tests that talk to it again
+_LIVE: dict = {}
+
 
 def _post(port: int, route: str, payload: dict) -> dict:
     req = urllib.request.Request(
@@ -93,7 +96,9 @@ def traced(tmp_path_factory):
         jax.profiler.stop_trace()
     assert len(reply) == 3
     spans = tracing.get_tracer().recent_spans(limit=1 << 20)
+    _LIVE.update(port=port, embedder=embedder)
     yield directory, spans
+    _LIVE.clear()
     tracing.reset_tracing()
     mp.undo()
 
@@ -143,7 +148,7 @@ def test_zz_ring_holds_one_requests_stages_and_they_add_up(traced):
     assert commit["attrs"]["queries"] == 1
     assert commit["attrs"]["commit"] == mine["queue"]["attrs"]["commit"]
     under_commit = {s["kind"]: s for s in spans if s["trace_id"] == commit["trace_id"]}
-    assert {"embed_wait", "coalesce", "search", "search.prepare", "search.device_wait",
+    assert {"embed_wait", "search", "search.prepare", "search.device_wait",
             "search.assemble"} <= set(under_commit)
     assert under_commit["search"]["attrs"]["queries"] == 1
     assert under_commit["search.device_wait"]["parent_id"] == under_commit["search"]["span_id"]
@@ -188,3 +193,30 @@ def test_zz_cli_trace_reads_a_profiler_directory(traced):
     assert result.exit_code == 0, result.output
     assert "by the innermost pw.<kind>" in result.output
     assert "search.device_wait" in result.output and "0 device plane(s)" in result.output
+
+
+def test_zz_retrieve_sheds_429_when_the_services_rows_are_over_the_cap(traced):
+    """``VectorStoreServer`` hands ``rest_connector`` the encoder service's own
+    ``overloaded`` and ``retry_after_s``: with the service's pending rows at
+    ``PATHWAY_EMBED_MAX_QUEUE_ROWS`` a ``/v1/retrieve`` is refused before it
+    costs a commit, 429 with an integer ``Retry-After``, counted once on
+    ``embed.shed`` by the REST plane (the service refused nothing itself)."""
+    import urllib.error
+
+    from pathway_tpu.engine import telemetry
+
+    port, svc = _LIVE["port"], _LIVE["embedder"].pipeline.service
+    assert svc.max_queue_rows == 4096  # the env's default, handed down by EmbedPipeline
+    shed_before = telemetry.stage_snapshot("embed.").get("embed.shed", 0.0)
+    svc._queued_rows = svc.max_queue_rows  # a full queue, without racing the worker
+    try:
+        with pytest.raises(urllib.error.HTTPError) as refused:
+            _post(port, ROUTE, {"query": "subject 2 refused", "k": 3})
+    finally:
+        svc._queued_rows = 0
+    assert refused.value.code == 429
+    assert int(refused.value.headers["Retry-After"]) >= 1
+    assert telemetry.stage_snapshot("embed.").get("embed.shed", 0.0) == shed_before + 1
+    assert svc.shed_requests == 0
+    # the queue drained: the route answers again
+    assert len(_post(port, ROUTE, {"query": "subject 2 admitted", "k": 3})) == 3
