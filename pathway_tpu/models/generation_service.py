@@ -10,7 +10,29 @@ way to stop it; the encoder service is the sibling that shares it):
 
     loop:  admit waiting prompts into free slots, one prefill each
            one decode step over all slots, for the slots that hold a request
-           resolve each request that has its max_new_tokens, free its slot
+           after each call is enqueued: fetch the call BEFORE it, hand its
+           tokens to their requests, resolve each that has its max_new_tokens
+
+**One call ahead, fetched one call behind.** Nothing the loop does next
+depends on a token's value: the slot state never leaves the device (the two
+programs write ``last`` themselves), a request gets a fixed number of tokens,
+and which slots the next step advances follows from how many tokens each
+request has been *given* (its prefill is one, each step it was active in one
+more), which the host counts without reading any. So the loop enqueues call
+N+1 and only then reads call N's result: the device always has its next
+program queued and the host's work a step hides behind it. ``_AHEAD`` (one)
+is how many calls may stand unread once a call has been enqueued: the host's
+work a step is well under the shortest device step, so one keeps the device
+fed, and every further call ahead would put one more step before an arrival's
+prefill. A request that has been given its last token leaves its slot at
+once, its tokens still unread: a prefill enqueued after that step runs after
+it on the device (one thread enqueues, the runtime keeps the order), and a
+call's token arrays are fresh outputs that the donated state does not alias.
+When no slot holds a request the loop reads what is still unread at once, so
+the last token of the last request waits for nothing, and the worker never
+parks or exits with a call unread. A device error surfaces where the result
+is read, one call late: every request that any unread call or any slot holds
+fails with it, the unread calls are dropped, and the service goes on.
 
 It takes submissions at any time, between any two steps: nothing here knows of
 the engine's commits. Greedy only, a fixed number of tokens a request, no stop
@@ -23,18 +45,23 @@ outlives hundreds of device calls and admission depends on which slots are
 free. The two share the skeleton (``models/device_worker.py``) and nothing of
 each other's loop.
 
-Spans (while something records): ``lm.prefill`` (a child of the request's
-``generate`` span where the submitter had one) and ``lm.decode_step`` (linking
-the requests it advanced), each with a ``.device_wait`` child around the fetch
-that blocks on the device. Counters: ``stats()`` and the ``lm.*`` stage counters
-on ``/metrics``.
+Spans (while something records): one ``lm.prefill`` a prompt (a child of the
+request's ``generate`` span where the submitter had one) and one
+``lm.decode_step`` a step (linking the requests it advances), each around the
+call it enqueues, with one ``.device_wait`` child around the fetch made inside
+it, which is the wait for the call *before* the span's own (nothing, for the
+first call after an idle loop). Counters: ``stats()`` and the ``lm.*`` stage
+counters on ``/metrics``, each moved when its call's result is read;
+``lm_calls_enqueued_ahead`` counts the calls enqueued while the call before
+them was still unread (all but the first after an idle loop).
 """
 
 from __future__ import annotations
 
 import concurrent.futures
 import logging
-from typing import Any, Dict, List, Optional, Sequence
+from collections import deque
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -42,22 +69,45 @@ from pathway_tpu.engine import telemetry
 from pathway_tpu.engine import tracing as _tracing
 from pathway_tpu.models.device_worker import DeviceWorker
 
+#: device calls that may stand unread once a call has been enqueued: one keeps the
+#: device fed, and each further one would put a step more before an arrival's prefill
+_AHEAD = 1
+
 
 class _Request:
-    __slots__ = ("ids", "future", "ctx", "tokens")
+    __slots__ = ("ids", "future", "ctx", "tokens", "given")
 
     def __init__(self, ids: List[int], ctx: Optional[_tracing.TraceContext]):
         self.ids = ids
         self.future: "concurrent.futures.Future[List[int]]" = concurrent.futures.Future()
         self.ctx = ctx
-        self.tokens: List[int] = []
+        self.tokens: List[int] = []  # read from the device
+        self.given = 0  # produced by the calls enqueued so far, read or not
+
+
+class _Call:
+    """A device call whose result the host has not read: its tokens (one a
+    slot from a step, one alone from a prefill) and experts touched, still on
+    the device; the (slot, request) pairs the tokens belong to; the prompt's
+    (tokens, padded tokens) for a prefill, None for a step; whether the call
+    before it was unread when it was enqueued."""
+
+    __slots__ = ("tokens", "touched", "rows", "prompt", "ahead")
+
+    def __init__(self, result: Tuple[Any, Any], rows: List[Tuple[int, _Request]],
+                 prompt: Optional[Tuple[int, int]], ahead: bool):
+        self.tokens, self.touched = result
+        self.rows = rows
+        self.prompt = prompt
+        self.ahead = ahead
 
 
 class GenerationService(DeviceWorker):
     """``decoder`` is the device side (``models/lfm2.Lfm2Decoder``): it gives
     ``slots``, ``max_prompt_tokens``, ``max_new_tokens``, ``bucket_of``,
-    ``prefill(slot, ids)``, ``decode(active)`` and ``compiled_programs()``.
-    Only this service's thread calls it."""
+    ``prefill(slot, ids)``, ``decode(active)`` and ``compiled_programs()``;
+    ``prefill`` and ``decode`` enqueue and return device arrays without
+    waiting. Only this service's thread calls it."""
 
     _thread_name = "pathway:lm-worker"
     _IDLE_WAIT_S = 0.05  # bounds how long a lost wakeup could park the loop (the wait stays abortable)
@@ -65,7 +115,9 @@ class GenerationService(DeviceWorker):
     def __init__(self, decoder: Any):
         super().__init__()
         self.decoder = decoder
+        # only the worker's thread fills and frees slots and touches the unread calls
         self._slots: List[Optional[_Request]] = [None] * int(decoder.slots)
+        self._unread: Deque[_Call] = deque()
         self.prefill_calls = 0
         self.prefill_tokens = 0
         self.prefill_padded_tokens = 0
@@ -73,6 +125,7 @@ class GenerationService(DeviceWorker):
         self.decode_steps = 0
         self.decode_rows = 0
         self.experts_touched = 0
+        self.calls_enqueued_ahead = 0
 
     # -- admission -------------------------------------------------------------
 
@@ -96,10 +149,11 @@ class GenerationService(DeviceWorker):
     # -- worker ----------------------------------------------------------------
 
     def _admit(self) -> Optional[List[int]]:
-        """Wait until there is something to do; move waiting requests into free
-        slots. Returns the slots just filled, or None when the worker is to exit."""
+        """Wait until there is something to do (a prompt to admit, a slot to
+        advance, a call to read); move waiting requests into free slots.
+        Returns the slots just filled, or None when the worker is to exit."""
         with self._cond:
-            while not self._queue and not any(self._slots):
+            while not self._queue and not any(self._slots) and not self._unread:
                 if self._exit_if_stopping_locked():
                     return None
                 self._cond.wait(timeout=self._IDLE_WAIT_S)
@@ -113,60 +167,91 @@ class GenerationService(DeviceWorker):
                         filled.append(slot)
             return filled
 
-    def _release(self, done: Dict[int, Optional[BaseException]]) -> None:
-        """Free the slots in ``done`` and resolve their requests (an exception
-        fails them). The next prefill into a freed slot overwrites its state."""
+    def _enqueued(self, result: Tuple[Any, Any], rows: List[Tuple[int, _Request]], wait_kind: str,
+                  prompt: Optional[Tuple[int, int]] = None) -> Optional[Tuple[_Call, Any, int]]:
+        """A call has just been enqueued for ``rows``: count its token as given
+        to each request (one given its last leaves its slot, which the next
+        prefill may overwrite), then fetch the call before it (for the caller
+        to hand out once its span is closed)."""
+        self._unread.append(_Call(result, rows, prompt, ahead=bool(self._unread)))
+        for slot, request in rows:
+            request.given += 1
+            if request.given >= self.decoder.max_new_tokens:
+                self._slots[slot] = None  # noqa: PWA103 (only the worker's thread reads or writes a slot)
+        with _tracing.get_tracer().trace_span(wait_kind):
+            return self._fetch(keep=_AHEAD)
+
+    def _fetch(self, keep: int) -> Optional[Tuple[_Call, Any, int]]:
+        """Wait for the oldest unread call and bring its result to the host;
+        None where no more than ``keep`` calls are unread."""
+        if len(self._unread) <= keep:
+            return None
+        call = self._unread[0]  # stays listed until it is read: a failed read fails its requests
+        # a prefill's one token stands for every slot, so a row's slot indexes either kind
+        tokens = np.broadcast_to(np.asarray(call.tokens), (len(self._slots),))
+        touched = int(call.touched)
+        self._unread.popleft()
+        return call, tokens, touched
+
+    def _hand_out(self, fetched: Optional[Tuple[_Call, Any, int]]) -> None:
+        """Give a fetched call's tokens to their requests, move its counters,
+        resolve every request that now holds all its tokens."""
+        if fetched is None:
+            return
+        call, tokens, touched = fetched
+        for slot, request in call.rows:
+            request.tokens.append(int(tokens[slot]))
         with self._cond:
-            requests = [(self._slots[slot], error) for slot, error in done.items()]
-            for slot in done:
-                self._slots[slot] = None
-            self._cond.notify_all()
-        for request, error in requests:
-            if error is not None:
-                request.future.set_exception(error)
+            self.calls_enqueued_ahead += call.ahead
+            if call.prompt is not None:
+                n, padded = call.prompt
+                self.prefill_calls += 1
+                self.prefill_tokens += n
+                self.prefill_padded_tokens += padded
+                self.prefill_experts_touched += touched
+                counts = {"lm.prefill_calls": 1.0, "lm.prefill_tokens": float(n),
+                          "lm.prefill_padded_tokens": float(padded)}
             else:
+                self.decode_steps += 1
+                self.decode_rows += len(call.rows)
+                self.experts_touched += touched
+                counts = {"lm.decode_steps": 1.0, "lm.decode_rows": float(len(call.rows)),
+                          "lm.experts_touched": float(touched)}
+            counts["lm.calls_enqueued_ahead"] = float(call.ahead)
+        telemetry.stage_add_many(counts)
+        want = self.decoder.max_new_tokens
+        for _, request in call.rows:
+            if len(request.tokens) >= want:
                 request.future.set_result(request.tokens)
 
     def _prefill(self, slot: int) -> None:
         request = self._slots[slot]
-        tracer = _tracing.get_tracer()
         n = len(request.ids)
-        with tracer.trace_span("lm.prefill", ctx=request.ctx, attrs={"slot": slot, "tokens": n}):
-            token, touched = self.decoder.prefill(slot, request.ids)
-            with tracer.trace_span("lm.prefill.device_wait"):
-                token, touched = int(token), int(touched)
-        request.tokens.append(token)
-        padded = self.decoder.bucket_of(n)
-        with self._cond:
-            self.prefill_calls += 1
-            self.prefill_tokens += n
-            self.prefill_padded_tokens += padded
-            self.prefill_experts_touched += touched
-        telemetry.stage_add_many({"lm.prefill_calls": 1.0, "lm.prefill_tokens": float(n),
-                                  "lm.prefill_padded_tokens": float(padded)})
+        with _tracing.get_tracer().trace_span("lm.prefill", ctx=request.ctx, attrs={"slot": slot, "tokens": n}):
+            fetched = self._enqueued(self.decoder.prefill(slot, request.ids), [(slot, request)],
+                                     "lm.prefill.device_wait", prompt=(n, self.decoder.bucket_of(n)))
+        self._hand_out(fetched)
 
-    def _decode_step(self, active: List[int]) -> None:
-        tracer = _tracing.get_tracer()
-        mask = np.zeros((len(self._slots),), bool)
-        mask[active] = True
-        links = tuple(r.ctx for r in (self._slots[s] for s in active) if r.ctx is not None)
-        with tracer.trace_span("lm.decode_step", links=links, attrs={"rows": len(active)}) as span:
+    def _decode_step(self) -> None:
+        rows = [(slot, held) for slot, held in enumerate(self._slots) if held is not None]
+        mask = np.array([held is not None for held in self._slots], bool)
+        links = tuple(r.ctx for _, r in rows if r.ctx is not None)
+        with _tracing.get_tracer().trace_span("lm.decode_step", links=links, attrs={"rows": len(rows)}) as span:
             if span is not None and any(link.sampled for link in links):
                 span.sampled = True
-            tokens, touched = self.decoder.decode(mask)
-            with tracer.trace_span("lm.decode_step.device_wait"):
-                tokens, touched = np.asarray(tokens), int(touched)
-        for slot in active:
-            self._slots[slot].tokens.append(int(tokens[slot]))
-        with self._cond:
-            self.decode_steps += 1
-            self.decode_rows += len(active)
-            self.experts_touched += touched
-        telemetry.stage_add_many({"lm.decode_steps": 1.0, "lm.decode_rows": float(len(active)),
-                                  "lm.experts_touched": float(touched)})
+            fetched = self._enqueued(self.decoder.decode(mask), rows, "lm.decode_step.device_wait")
+        self._hand_out(fetched)
+
+    def _fail(self, error: BaseException) -> None:
+        """Fail every request an unread call or a slot holds, and forget them."""
+        held = {id(r): r for call in self._unread for _, r in call.rows}
+        held.update((id(r), r) for r in self._slots if r is not None)
+        self._unread.clear()
+        self._slots[:] = [None] * len(self._slots)  # noqa: PWA103 (only the worker's thread reads or writes a slot)
+        for request in held.values():
+            request.future.set_exception(error)
 
     def _run(self) -> None:
-        want = int(self.decoder.max_new_tokens)
         while True:
             filled = self._admit()
             if filled is None:
@@ -174,15 +259,14 @@ class GenerationService(DeviceWorker):
             try:
                 for slot in filled:
                     self._prefill(slot)
-                # only this thread fills and frees slots, so it may read them unlocked
-                active = [s for s, r in enumerate(self._slots) if r is not None and len(r.tokens) < want]
-                if active:
-                    self._decode_step(active)
+                if any(self._slots):
+                    self._decode_step()
+                else:  # nothing to enqueue: the last tokens wait for no further call
+                    while self._unread:
+                        self._hand_out(self._fetch(keep=0))
             except Exception as exc:  # a failed device call fails every request it could have touched
                 logging.getLogger(__name__).exception("generation step failed")
-                self._release({s: exc for s, r in enumerate(self._slots) if r is not None})
-                continue
-            self._release({s: None for s, r in enumerate(self._slots) if r is not None and len(r.tokens) >= want})
+                self._fail(exc)
 
     # -- reporting -------------------------------------------------------------
 
@@ -196,6 +280,7 @@ class GenerationService(DeviceWorker):
                 "lm_decode_steps": self.decode_steps,
                 "lm_decode_rows": self.decode_rows,
                 "lm_experts_touched": self.experts_touched,
+                "lm_calls_enqueued_ahead": self.calls_enqueued_ahead,
                 "lm_slots": len(self._slots),
                 "lm_compiled_programs": self.decoder.compiled_programs(),
             }

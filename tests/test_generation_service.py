@@ -1,13 +1,14 @@
 """The slot-based generation service (``models/generation_service.py``): its
 loop over a fake decoder (admission, the prompt's crop, draining, a failed
-device call) and over the tiny ``lfm2_moe`` decoder of ``test_lfm2.py``, where
-requests that arrive at any time, between any two steps, each get the tokens
-they get alone."""
+device call; one call enqueued ahead of the one whose result is read) and over
+the tiny ``lfm2_moe`` decoder of ``test_lfm2.py``, where requests that arrive
+at any time, between any two steps, each get the tokens they get alone."""
 
 import threading
 import time
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from pathway_tpu.models import lfm2
@@ -16,35 +17,74 @@ from pathway_tpu.models.generation_service import GenerationService
 from .test_lfm2 import CFG, assert_greedy, decoder, prompt_of
 
 
+class Lazy:
+    """A call's result as a device array is: there when it is read, and the
+    read is an event (``("read", call number)`` in the decoder's ``log``)."""
+
+    def __init__(self, decoder_, call, value):
+        self.decoder, self.call, self.value = decoder_, call, value
+
+    def _read(self):
+        assert self.decoder.may_read.wait(10)
+        self.decoder.log.append(("read", self.call))
+        if self.call == self.decoder.fail_on_read:
+            raise RuntimeError("the device call failed")
+        return self.value
+
+    def __array__(self, dtype=None, copy=None):
+        return np.array(self._read(), dtype=dtype)
+
+    def __int__(self):
+        return int(self._read())
+
+
 class FakeDecoder:
-    """Counts up from a prompt's last id: token i of a request is ``ids[-1] + 1 + i``."""
+    """Counts up from a prompt's last id: token i of a request is ``ids[-1] + 1 + i``.
+    ``calls`` holds the calls; ``log`` the calls and the reads of their results, in order."""
 
-    slots, max_prompt_tokens, max_new_tokens = 3, 8, 4
+    max_prompt_tokens = 8
 
-    def __init__(self, fail_on_step=None):
+    def __init__(self, fail_on_step=None, fail_on_read=None, slots=3, new=4):
+        self.slots, self.max_new_tokens = slots, new
         self.last = [0] * self.slots
-        self.calls = []
-        self.fail_on_step = fail_on_step
+        self.calls, self.log = [], []
+        self.fail_on_step, self.fail_on_read = fail_on_step, fail_on_read
+        self.may_read = threading.Event()  # a test clears it to hold a result back
+        self.may_read.set()
 
     def bucket_of(self, n):
         return 8
 
+    def _called(self, call, tokens, touched):
+        self.calls.append(call)
+        self.log.append(call)
+        n = len(self.calls) - 1
+        # fresh outputs: what a later call writes into the state is not seen through them
+        return Lazy(self, n, tokens), Lazy(self, n, touched)
+
     def prefill(self, slot, ids):
-        self.calls.append(("prefill", slot, list(ids)))
         self.last[slot] = ids[-1] + 1
-        return self.last[slot], 2
+        return self._called(("prefill", slot, list(ids)), self.last[slot], 2)
 
     def decode(self, active):
-        self.calls.append(("decode", [int(s) for s in active.nonzero()[0]]))
-        if self.fail_on_step is not None and sum(c[0] == "decode" for c in self.calls) == self.fail_on_step:
+        if self.fail_on_step is not None and sum(c[0] == "decode" for c in self.calls) + 1 == self.fail_on_step:
+            self._called(("decode", None), None, None)
             raise RuntimeError("the device call failed")
         for s in active.nonzero()[0]:
             self.last[s] += 1
-        return list(self.last), 3 * int(active.sum())
+        return self._called(("decode", [int(s) for s in active.nonzero()[0]]), list(self.last), 3 * int(active.sum()))
 
     @staticmethod
     def compiled_programs():
         return 2
+
+
+def gated(decoder_):
+    """Hold the decoder's first prefill until the returned event is set, so that
+    everything submitted meanwhile waits in the queue."""
+    gate, prefill = threading.Event(), decoder_.prefill
+    decoder_.prefill = lambda slot, ids: (gate.wait(10), prefill(slot, ids))[1]
+    return gate
 
 
 def test_more_requests_than_slots_all_resolve_and_the_counters_add_up():
@@ -90,9 +130,7 @@ def test_stop_drains_the_worker_respawns_and_close_refuses():
 
 def test_a_failed_device_call_fails_the_requests_it_held_and_the_service_goes_on():
     decoder_ = FakeDecoder(fail_on_step=2)
-    gate = threading.Event()
-    prefill = decoder_.prefill
-    decoder_.prefill = lambda slot, ids: (gate.wait(10), prefill(slot, ids))[1]
+    gate = gated(decoder_)
     svc = GenerationService(decoder_)
     first = [svc.submit([i]) for i in range(3)]
     gate.set()  # the worker waited in the first prefill: all three hold a slot by the failing step
@@ -103,11 +141,102 @@ def test_a_failed_device_call_fails_the_requests_it_held_and_the_service_goes_on
     svc.close()
 
 
+@pytest.mark.parametrize("requests,slots,new", [(2, 3, 4), (7, 3, 4), (3, 1, 3), (5, 2, 2)])
+def test_a_call_is_enqueued_before_the_call_before_it_is_read_and_never_two_before(requests, slots, new):
+    decoder_ = FakeDecoder(slots=slots, new=new)
+    gate = gated(decoder_)
+    svc = GenerationService(decoder_)
+    futures = [svc.submit([10 * i]) for i in range(requests)]
+    gate.set()  # everything waits by the first call: the loop has something to enqueue until the end
+    assert [f.result(timeout=10) for f in futures] == [[10 * i + 1 + j for j in range(new)] for i in range(requests)]
+    svc.close()
+    log, calls = decoder_.log, decoder_.calls
+    assert len(calls) >= requests + new - 1 and [e for e in log if e[0] != "read"] == calls
+    at = [i for i, e in enumerate(log) if e[0] != "read"]  # where call n stands in the log
+    reads = [[i for i, e in enumerate(log) if e == ("read", n)] for n in range(len(calls))]
+    assert all(len(r) == 2 for r in reads)  # every call's tokens and its count, once each
+    for n in range(1, len(calls)):
+        assert at[n] < reads[n - 1][0]  # enqueued while the call before it was unread
+        assert n < 2 or reads[n - 2][-1] < at[n]  # and only one: the one before that had been read
+    st = svc.stats()
+    assert st["lm_prefill_calls"] + st["lm_decode_steps"] == len(calls)
+    assert st["lm_calls_enqueued_ahead"] == len(calls) - 1  # all but the first call after the idle loop
+
+
+def test_an_idle_loop_starts_again_with_nothing_ahead():
+    svc = GenerationService(FakeDecoder())
+    for i in range(3):  # one at a time: each finds the loop idle and every result read
+        assert svc.submit([i]).result(timeout=10) == [i + 1 + j for j in range(4)]
+    st = svc.stats()
+    assert st["lm_prefill_calls"] + st["lm_decode_steps"] == 12 and st["lm_calls_enqueued_ahead"] == 9
+    svc.close()
+
+
+def test_a_slot_refilled_while_its_last_token_is_unread_gives_both_requests_their_tokens():
+    decoder_ = FakeDecoder(slots=1, new=3)
+    gate = gated(decoder_)
+    svc = GenerationService(decoder_)
+    first, second = svc.submit([10]), svc.submit([20])
+    gate.set()
+    assert first.result(timeout=10) == [11, 12, 13] and second.result(timeout=10) == [21, 22, 23]
+    log = decoder_.log
+    assert decoder_.calls[:4] == [("prefill", 0, [10]), ("decode", [0]), ("decode", [0]), ("prefill", 0, [20])]
+    # the second prompt went into the slot before the first request's last step had been read
+    assert log.index(("prefill", 0, [20])) < log.index(("read", 2))
+    svc.close()
+
+
+@pytest.mark.parametrize("slots,new,fail_on_read,fail", [(3, 4, 3, 3), (1, 2, 1, 2), (2, 3, 0, 1)])
+def test_an_error_at_read_time_fails_the_requests_of_the_calls_in_flight_and_the_service_goes_on(
+        slots, new, fail_on_read, fail):
+    """(1, 2, 1): the failing step was the first request's last, so it has left
+    its slot and only the unread call still holds it; the second request's
+    prefill is unread behind it. ``fail`` requests fail at least (how many
+    the worker had admitted by its first call is the timing's)."""
+    decoder_ = FakeDecoder(slots=slots, new=new, fail_on_read=fail_on_read)
+    gate = gated(decoder_)
+    svc = GenerationService(decoder_)
+    first = [svc.submit([i]) for i in range(slots + 2)]
+    gate.set()
+    failed = [f for f in first if isinstance(f.exception(timeout=10), RuntimeError)]
+    # every request a slot or an unread call held when the read failed, and no other: one still waiting is served
+    assert fail <= len(failed) < len(first) and failed == first[:len(failed)]
+    assert all("device call failed" in str(f.exception()) for f in failed)
+    assert all(f.result(timeout=10) == [first.index(f) + 1 + j for j in range(new)] for f in first if f not in failed)
+    assert svc.submit([50]).result(timeout=10) == [51 + j for j in range(new)]
+    svc.close()
+
+
+def test_one_new_token_resolves_with_no_decode_step():
+    svc = GenerationService(FakeDecoder(new=1))
+    futures = [svc.submit([10 * i]) for i in range(5)]
+    assert [f.result(timeout=10) for f in futures] == [[10 * i + 1] for i in range(5)]
+    st = svc.stats()
+    assert st["lm_prefill_calls"] == 5 and st["lm_decode_steps"] == 0
+    assert all(call[0] == "prefill" for call in svc.decoder.calls)
+    svc.close()
+
+
+def test_stop_with_a_call_in_flight_resolves_its_requests():
+    decoder_ = FakeDecoder(slots=2, new=3)
+    decoder_.may_read.clear()
+    svc = GenerationService(decoder_)
+    futures = [svc.submit([1]), svc.submit([5])]
+    deadline = time.monotonic() + 10
+    while len(decoder_.calls) < 2 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    # both prefills enqueued, the worker waits for the first's result with the second's unread behind it
+    assert len(decoder_.calls) == 2 and not any(f.done() for f in futures)
+    threading.Timer(0.2, decoder_.may_read.set).start()
+    svc.stop_worker(timeout_s=10)
+    assert not svc.worker_alive()
+    assert [f.result(timeout=0) for f in futures] == [[2, 3, 4], [6, 7, 8]]
+    svc.close()
+
+
 def test_a_cancelled_submission_takes_no_slot():
     decoder_ = FakeDecoder()
-    gate = threading.Event()
-    prefill = decoder_.prefill
-    decoder_.prefill = lambda slot, ids: (gate.wait(10), prefill(slot, ids))[1]
+    gate = gated(decoder_)
     svc = GenerationService(decoder_)
     running = [svc.submit([i]) for i in range(3)]  # fill the slots; the worker blocks in the first prefill
     time.sleep(0.1)
@@ -119,14 +248,17 @@ def test_a_cancelled_submission_takes_no_slot():
     assert not any(call[0] == "prefill" and call[2] == [90] for call in decoder_.calls)
 
 
-def test_requests_arriving_between_steps_get_the_tokens_they_get_alone():
+@pytest.mark.parametrize("slots,gap_s", [(3, 0.05), (1, 0.0)])
+def test_requests_arriving_between_steps_get_the_tokens_they_get_alone(slots, gap_s):
+    """One slot and no gap: every prompt but the first goes into the slot while
+    the last token of the request before it is still unread on the device."""
     params = lfm2.init_params(CFG, seed=3, dtype=jnp.float32)
-    svc = GenerationService(decoder(params, slots=3, new=6))
+    svc = GenerationService(decoder(params, slots=slots, new=6))
     prompts = [prompt_of(n, seed=50 + n) for n in (4, 31, 9, 16, 2, 23, 12)]
     futures = {}
 
     def client(i):
-        time.sleep(0.05 * i)  # while earlier requests are mid-generation
+        time.sleep(gap_s * i)  # while earlier requests are mid-generation
         futures[i] = svc.submit(prompts[i])
 
     threads = [threading.Thread(target=client, args=(i,)) for i in range(len(prompts))]

@@ -111,6 +111,14 @@ def served(tmp_path_factory):
 
     with profiler_session(tmp_path_factory.mktemp("profile")):
         reply = _post(port, "/v2/answer", {"prompt": QUESTION, "return_context_docs": True})
+        # the reply leaves before the commit that carried the answer in has ended, and that commit
+        # records the request's second ``queue`` span, and then itself, at its end, if the session
+        # is still on: ending the session at once races it (lost under the suite's six workers)
+        deadline = time.monotonic() + 60
+        while sum(s["kind"] == "commit" and s["attrs"].get("queries", 0) > 0
+                  for s in tracing.get_tracer().recent_spans(limit=1 << 20)) < 2:
+            assert time.monotonic() < deadline, "the commit that carried the answer in never ended"
+            time.sleep(0.01)
     after = (chat.service.stats(), telemetry.stage_snapshot("lm."))
     spans = tracing.get_tracer().recent_spans(limit=1 << 20)
     yield {"chat": chat, "params": params, "reply": reply, "before": before, "after": after, "spans": spans,
@@ -145,9 +153,11 @@ def test_zz_counters_and_spans_carry_what_the_request_implies(served):
     # four expert layers, two experts a token: one row a step chooses eight
     assert grew["lm_experts_touched"] == 8 * (NEW_TOKENS - 1) and grew["lm_compiled_programs"] == 0
     stage = {k: served["after"][1][k] - served["before"][1].get(k, 0.0) for k in served["after"][1]}
+    # the loop was idle, so every call but the prefill was enqueued with the call before it unread
+    assert grew["lm_calls_enqueued_ahead"] == NEW_TOKENS - 1
     assert stage == {"lm.prefill_calls": 1.0, "lm.prefill_tokens": float(n_prompt), "lm.prefill_padded_tokens": 256.0,
                      "lm.decode_steps": NEW_TOKENS - 1.0, "lm.decode_rows": NEW_TOKENS - 1.0,
-                     "lm.experts_touched": 8.0 * (NEW_TOKENS - 1)}
+                     "lm.experts_touched": 8.0 * (NEW_TOKENS - 1), "lm.calls_enqueued_ahead": NEW_TOKENS - 1.0}
 
     by_kind: dict = {}
     for s in spans:
@@ -158,26 +168,36 @@ def test_zz_counters_and_spans_carry_what_the_request_implies(served):
     assert generate["attrs"]["prompt_tokens"] == n_prompt and prefill["attrs"]["tokens"] == n_prompt
     assert prefill["parent_id"] == generate["span_id"] and prefill["trace_id"] == generate["trace_id"]
     end = lambda s: s["ts_mono"] + s["duration_s"]
-    # the generation is the request's own: the commit that took the question handed the prompt out
-    # and ended, and the commit that carried the answer in links the same request
+    # the generation is the request's own: the commit that took the question handed the prompt out,
+    # and the commit that carried the answer in links the same request
     [rest] = [s for s in by_kind["rest"] if s["span_id"] == generate["parent_id"]]
     assert rest["trace_id"] == generate["trace_id"] and rest["attrs"]["route"] == "/v2/answer"
     took, carried = sorted((s for s in by_kind["commit"] if any(link["span_id"] == rest["span_id"] for link in s["links"])),
                            key=lambda s: s["ts_mono"])
     assert took["attrs"]["queries"] == carried["attrs"]["queries"] == 1
-    # (the reply may leave before the commit that resolved it has ended)
-    assert end(took) < end(generate) <= carried["ts_mono"] < end(rest)
+    # only what one event causes in another is ordered here: a commit's span opens before it takes
+    # its rows (a push may land just inside it), and whether the first commit ENDS before the
+    # generation does is a race between two threads; that no commit holds a generation is the next
+    # test's, without a clock. (The reply may leave before the commit that resolved it has ended.)
+    assert took["ts_mono"] < generate["ts_mono"] and end(took) <= carried["ts_mono"]
+    assert end(generate) <= end(carried) and carried["ts_mono"] < end(rest)
     queues = [s for s in by_kind["queue"] if s["parent_id"] == rest["span_id"]]
     assert sorted(s["attrs"]["commit"] for s in queues) == [took["attrs"]["commit"], carried["attrs"]["commit"]]
     assert len(steps) == NEW_TOKENS - 1 and all(s["attrs"]["rows"] == 1 for s in steps)
     assert all(any(link["span_id"] == generate["span_id"] for link in s["links"]) for s in steps)
-    waits = {s["parent_id"] for s in by_kind["lm.decode_step.device_wait"]}
-    assert waits == {s["span_id"] for s in steps}
-    assert [s["parent_id"] for s in by_kind["lm.prefill.device_wait"]] == [prefill["span_id"]]
-    # prefill and every step lie inside the generation, one after the other
+    # each call's span holds exactly one fetch: that of the call before it (none before the prefill)
+    step_waits, [prefill_wait] = by_kind["lm.decode_step.device_wait"], by_kind["lm.prefill.device_wait"]
+    assert sorted(s["parent_id"] for s in step_waits) == sorted(s["span_id"] for s in steps)
+    assert prefill_wait["parent_id"] == prefill["span_id"]
+    # the service's spans are one thread's: the prefill, then every step, one after the other, none
+    # inside another; all of it inside the generation, which ends only after the last fetch, and that
+    # one (the last step's own tokens) is made after the last step's span, with nothing more to enqueue
     inside = sorted([prefill] + steps, key=lambda s: s["ts_mono"])
-    assert inside[0] is prefill and generate["ts_mono"] <= prefill["ts_mono"] and end(inside[-1]) <= end(generate)
-    assert all(end(a) <= b["ts_mono"] for a, b in zip(inside, inside[1:]))
+    assert inside[0] is prefill and all(end(a) <= b["ts_mono"] for a, b in zip(inside, inside[1:]))
+    by_id = {s["span_id"]: s for s in inside}
+    assert all(by_id[w["parent_id"]]["ts_mono"] <= w["ts_mono"] and end(w) <= end(by_id[w["parent_id"]])
+               for w in step_waits + [prefill_wait])
+    assert generate["ts_mono"] <= prefill["ts_mono"] and end(inside[-1]) <= end(generate)
 
 
 def test_zz_a_second_question_is_answered_while_the_first_still_generates(served, tmp_path, monkeypatch):
