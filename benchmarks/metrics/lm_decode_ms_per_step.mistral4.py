@@ -1,0 +1,5 @@
+"""Device time of the ``mistral4`` generator's decode program over its calls (``jit_lm_decode``: one generator a process, so the name is the other generator's too).
+The reader is ``metrics/lm_decode_ms_per_step.py``'s: the generation service, its spans and its counters are the same, and the
+work file has the same signatures."""
+
+from metrics.lm_decode_ms_per_step import read  # noqa: F401

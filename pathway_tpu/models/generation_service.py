@@ -53,7 +53,12 @@ it, which is the wait for the call *before* the span's own (nothing, for the
 first call after an idle loop). Counters: ``stats()`` and the ``lm.*`` stage
 counters on ``/metrics``, each moved when its call's result is read;
 ``lm_calls_enqueued_ahead`` counts the calls enqueued while the call before
-them was still unread (all but the first after an idle loop).
+them was still unread (all but the first after an idle loop). **What a call
+counts on the device is the decoder's to name**: it returns, beside its
+tokens, one array of as many numbers as its ``count_names`` has (experts
+touched, for one), and the service sums each under that name, a step's as
+``lm_<name>`` and a prefill's as ``lm_prefill_<name>``: two transfers a call
+whatever the decoder counts, and no model's name here.
 """
 
 from __future__ import annotations
@@ -87,27 +92,28 @@ class _Request:
 
 class _Call:
     """A device call whose result the host has not read: its tokens (one a
-    slot from a step, one alone from a prefill) and experts touched, still on
+    slot from a step, one alone from a prefill) and the decoder's counts, still on
     the device; the (slot, request) pairs the tokens belong to; the prompt's
     (tokens, padded tokens) for a prefill, None for a step; whether the call
     before it was unread when it was enqueued."""
 
-    __slots__ = ("tokens", "touched", "rows", "prompt", "ahead")
+    __slots__ = ("tokens", "counts", "rows", "prompt", "ahead")
 
     def __init__(self, result: Tuple[Any, Any], rows: List[Tuple[int, _Request]],
                  prompt: Optional[Tuple[int, int]], ahead: bool):
-        self.tokens, self.touched = result
+        self.tokens, self.counts = result
         self.rows = rows
         self.prompt = prompt
         self.ahead = ahead
 
 
 class GenerationService(DeviceWorker):
-    """``decoder`` is the device side (``models/lfm2.Lfm2Decoder``): it gives
-    ``slots``, ``max_prompt_tokens``, ``max_new_tokens``, ``bucket_of``,
-    ``prefill(slot, ids)``, ``decode(active)`` and ``compiled_programs()``;
-    ``prefill`` and ``decode`` enqueue and return device arrays without
-    waiting. Only this service's thread calls it."""
+    """``decoder`` is the device side (a ``models/slot_decoder.SlotDecoder``): it
+    gives ``slots``, ``max_prompt_tokens``, ``max_new_tokens``, ``count_names``,
+    ``bucket_of``, ``prefill(slot, ids)``, ``decode(active)`` and
+    ``compiled_programs()``; ``prefill`` and ``decode`` enqueue and return
+    (tokens, counts) as device arrays without waiting. Only this service's
+    thread calls it."""
 
     _thread_name = "pathway:lm-worker"
     _IDLE_WAIT_S = 0.05  # bounds how long a lost wakeup could park the loop (the wait stays abortable)
@@ -121,11 +127,12 @@ class GenerationService(DeviceWorker):
         self.prefill_calls = 0
         self.prefill_tokens = 0
         self.prefill_padded_tokens = 0
-        self.prefill_experts_touched = 0
         self.decode_steps = 0
         self.decode_rows = 0
-        self.experts_touched = 0
         self.calls_enqueued_ahead = 0
+        # the decoder's own per-call counts, summed: a prefill's and a step's apart
+        self.prefill_counts = np.zeros((len(decoder.count_names),), np.int64)
+        self.decode_counts = np.zeros((len(decoder.count_names),), np.int64)
 
     # -- admission -------------------------------------------------------------
 
@@ -168,7 +175,7 @@ class GenerationService(DeviceWorker):
             return filled
 
     def _enqueued(self, result: Tuple[Any, Any], rows: List[Tuple[int, _Request]], wait_kind: str,
-                  prompt: Optional[Tuple[int, int]] = None) -> Optional[Tuple[_Call, Any, int]]:
+                  prompt: Optional[Tuple[int, int]] = None) -> Optional[Tuple[_Call, Any, Any]]:
         """A call has just been enqueued for ``rows``: count its token as given
         to each request (one given its last leaves its slot, which the next
         prefill may overwrite), then fetch the call before it (for the caller
@@ -181,7 +188,7 @@ class GenerationService(DeviceWorker):
         with _tracing.get_tracer().trace_span(wait_kind):
             return self._fetch(keep=_AHEAD)
 
-    def _fetch(self, keep: int) -> Optional[Tuple[_Call, Any, int]]:
+    def _fetch(self, keep: int) -> Optional[Tuple[_Call, Any, Any]]:
         """Wait for the oldest unread call and bring its result to the host;
         None where no more than ``keep`` calls are unread."""
         if len(self._unread) <= keep:
@@ -189,16 +196,16 @@ class GenerationService(DeviceWorker):
         call = self._unread[0]  # stays listed until it is read: a failed read fails its requests
         # a prefill's one token stands for every slot, so a row's slot indexes either kind
         tokens = np.broadcast_to(np.asarray(call.tokens), (len(self._slots),))
-        touched = int(call.touched)
+        counts = np.asarray(call.counts, np.int64).reshape(-1)
         self._unread.popleft()
-        return call, tokens, touched
+        return call, tokens, counts
 
-    def _hand_out(self, fetched: Optional[Tuple[_Call, Any, int]]) -> None:
+    def _hand_out(self, fetched: Optional[Tuple[_Call, Any, Any]]) -> None:
         """Give a fetched call's tokens to their requests, move its counters,
         resolve every request that now holds all its tokens."""
         if fetched is None:
             return
-        call, tokens, touched = fetched
+        call, tokens, counts = fetched
         for slot, request in call.rows:
             request.tokens.append(int(tokens[slot]))
         with self._cond:
@@ -208,17 +215,17 @@ class GenerationService(DeviceWorker):
                 self.prefill_calls += 1
                 self.prefill_tokens += n
                 self.prefill_padded_tokens += padded
-                self.prefill_experts_touched += touched
-                counts = {"lm.prefill_calls": 1.0, "lm.prefill_tokens": float(n),
-                          "lm.prefill_padded_tokens": float(padded)}
+                self.prefill_counts += counts
+                stage = {"lm.prefill_calls": 1.0, "lm.prefill_tokens": float(n),
+                         "lm.prefill_padded_tokens": float(padded)}
             else:
                 self.decode_steps += 1
                 self.decode_rows += len(call.rows)
-                self.experts_touched += touched
-                counts = {"lm.decode_steps": 1.0, "lm.decode_rows": float(len(call.rows)),
-                          "lm.experts_touched": float(touched)}
-            counts["lm.calls_enqueued_ahead"] = float(call.ahead)
-        telemetry.stage_add_many(counts)
+                self.decode_counts += counts
+                stage = {"lm.decode_steps": 1.0, "lm.decode_rows": float(len(call.rows))}
+                stage.update(("lm." + name, float(n)) for name, n in zip(self.decoder.count_names, counts))
+            stage["lm.calls_enqueued_ahead"] = float(call.ahead)
+        telemetry.stage_add_many(stage)
         want = self.decoder.max_new_tokens
         for _, request in call.rows:
             if len(request.tokens) >= want:
@@ -272,14 +279,15 @@ class GenerationService(DeviceWorker):
 
     def stats(self) -> Dict[str, Any]:
         with self._cond:
+            names = self.decoder.count_names
             return {
                 "lm_prefill_calls": self.prefill_calls,
                 "lm_prefill_tokens": self.prefill_tokens,
                 "lm_prefill_padded_tokens": self.prefill_padded_tokens,
-                "lm_prefill_experts_touched": self.prefill_experts_touched,
+                **{"lm_prefill_" + name: int(n) for name, n in zip(names, self.prefill_counts)},
                 "lm_decode_steps": self.decode_steps,
                 "lm_decode_rows": self.decode_rows,
-                "lm_experts_touched": self.experts_touched,
+                **{"lm_" + name: int(n) for name, n in zip(names, self.decode_counts)},
                 "lm_calls_enqueued_ahead": self.calls_enqueued_ahead,
                 "lm_slots": len(self._slots),
                 "lm_compiled_programs": self.decoder.compiled_programs(),

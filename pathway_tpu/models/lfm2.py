@@ -38,12 +38,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Sequence, Tuple
-
-import numpy as np
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from pathway_tpu.models.moe import grouped_experts, precision as _precision
+from pathway_tpu.models.slot_decoder import SlotDecoder, random_params
 
 # https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json
 PUBLISHED_LAYER_TYPES = tuple(
@@ -123,30 +124,10 @@ def param_shapes(cfg: Lfm2Config, dtype: Any = jnp.bfloat16) -> Dict[str, Any]:
 
 
 def init_params(cfg: Lfm2Config, seed: int = 0, dtype: Any = jnp.bfloat16) -> Dict[str, Any]:
-    """Random parameters, made on the device one array at a time: matrices
-    normal at ``1/sqrt(fan in)``, the table at 0.02, norms around 1, the
-    experts' bias at 0.05. What a run serves when no parameter tree is given."""
-    shapes = param_shapes(cfg, dtype)
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-
-    @functools.partial(jax.jit, static_argnames=("shape", "dtype", "std", "mean"))
-    def draw(key, *, shape, dtype, std, mean):
-        return (mean + std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
-
-    out = []
-    for i, (path, leaf) in enumerate(leaves):
-        name = path[-1].key
-        if name.endswith("norm"):
-            std, mean = 0.1, 1.0
-        elif name == "expert_bias":
-            std, mean = 0.05, 0.0
-        elif name == "embed":
-            std, mean = 0.02, 0.0
-        else:  # a matrix: the axis before the last is the one summed over
-            std, mean = float(leaf.shape[-2 if name != "conv_w" else -1]) ** -0.5, 0.0
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
-        out.append(draw(key, shape=leaf.shape, dtype=leaf.dtype, std=std, mean=mean))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    """Random parameters (``slot_decoder.random_params``): matrices normal at
+    ``1/sqrt(fan in)``, the table at 0.02, norms around 1, the experts' bias at
+    0.05. What a run serves when no parameter tree is given."""
+    return random_params(param_shapes(cfg, dtype), seed)
 
 
 def init_state(cfg: Lfm2Config, slots: int, max_len: int, dtype: Any = jnp.bfloat16) -> Dict[str, Any]:
@@ -161,11 +142,6 @@ def init_state(cfg: Lfm2Config, slots: int, max_len: int, dtype: Any = jnp.bfloa
         "pos": jnp.zeros((slots,), jnp.int32),
         "last": jnp.zeros((slots,), jnp.int32),
     }
-
-
-def _precision(dtype: Any) -> Any:
-    # float32 parameters (the CPU tests) are multiplied exactly; bfloat16 operands are one pass anyway
-    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _mm(a: jax.Array, w: jax.Array) -> jax.Array:
@@ -195,9 +171,9 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 def _moe(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Config) -> Tuple[jax.Array, jax.Array]:
     """The expert layer over ``h`` (tokens, hidden, float32, already normed).
     Tokens outside ``valid`` choose no expert. Returns the float32 output and
-    how many distinct experts the valid tokens chose."""
-    n, k, e = h.shape[0], cfg.num_experts_per_tok, cfg.num_experts
-    dtype = p["w1"].dtype
+    how many distinct experts the valid tokens chose. Every expert is held
+    here, so a chosen expert's number is its place in the stack."""
+    k, e = cfg.num_experts_per_tok, cfg.num_experts
     with jax.named_scope("moe_route"):
         scores = jax.nn.sigmoid(jnp.dot(h, p["gate"], precision=jax.lax.Precision.HIGHEST))
         biased = scores + p["expert_bias"] if cfg.use_expert_bias else scores
@@ -206,19 +182,7 @@ def _moe(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Confi
         if cfg.norm_topk_prob:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
         weights = weights * cfg.routed_scaling_factor
-        # one row per (token, chosen expert), sorted by expert; expert ``e`` is "none" and sorts last
-        flat = jnp.where(valid[:, None], chosen, e).reshape(-1)
-        order = jnp.argsort(flat, stable=True)
-        group_sizes = jnp.sum(flat[:, None] == jnp.arange(e)[None, :], axis=0, dtype=jnp.int32)
-        rows = h.astype(dtype)[order // k]
-    with jax.named_scope("moe_experts"):
-        grouped = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes, precision=_precision(dtype),
-                                    preferred_element_type=jnp.float32)
-        mid = jax.nn.silu(grouped(rows, p["w1"])) * grouped(rows, p["w3"])
-        out = grouped(mid.astype(dtype), p["w2"])
-        # rows of no group hold whatever the product left there
-        out = jnp.where((flat[order] < e)[:, None], out * weights.reshape(-1)[order][:, None], 0.0)
-        out = out[jnp.argsort(order)].reshape(n, k, -1).sum(axis=1)
+    out, group_sizes = grouped_experts(p, h, jnp.where(valid[:, None], chosen, e), weights)
     return out, jnp.sum(group_sizes > 0, dtype=jnp.int32)
 
 
@@ -366,47 +330,11 @@ def lm_decode(params, state, active, *, cfg):
         return state, tokens, touched
 
 
-class Lfm2Decoder:
-    """The parameters, the slots and the two programs, as the generation
-    service drives them. Not thread-safe: one thread owns it."""
+class Lfm2Decoder(SlotDecoder):
+    """The ``lfm2_moe`` decoder as the generation service drives it
+    (``models/slot_decoder.py``); a call's one count is the distinct experts
+    its tokens chose, summed over the expert layers."""
 
-    def __init__(self, cfg: Lfm2Config, params: Dict[str, Any] | None = None, *, slots: int,
-                 max_prompt_tokens: int, max_new_tokens: int, prefill_buckets: Sequence[int], seed: int = 0):
-        if max(prefill_buckets) < max_prompt_tokens:
-            raise ValueError(f"the largest prefill bucket {max(prefill_buckets)} is under {max_prompt_tokens}")
-        self.cfg, self.slots = cfg, int(slots)
-        self.max_prompt_tokens, self.max_new_tokens = int(max_prompt_tokens), int(max_new_tokens)
-        self.prefill_buckets = tuple(sorted(int(b) for b in prefill_buckets))
-        self.weights_source = "given" if params is not None else "random-init"
-        self.params = params if params is not None else init_params(cfg, seed)
-        # room for the longest prompt and its tokens, to a multiple of 64
-        self.max_len = -(-(self.prefill_buckets[-1] + self.max_new_tokens) // 64) * 64
-        self.state = init_state(cfg, self.slots, self.max_len, self.params["embed"].dtype)
-
-    def bucket_of(self, n_tokens: int) -> int:
-        return next(b for b in self.prefill_buckets if b >= n_tokens)
-
-    def prefill(self, slot: int, ids: Sequence[int]) -> Tuple[jax.Array, jax.Array]:
-        """Enqueue one prompt's prefill into ``slot``: (first token, experts touched), on the device."""
-        padded = np.zeros((self.bucket_of(len(ids)),), np.int32)
-        padded[: len(ids)] = ids
-        self.state, token, touched = lm_prefill(self.params, self.state, padded, np.int32(len(ids)),
-                                                np.int32(slot), cfg=self.cfg)
-        return token, touched
-
-    def decode(self, active: Any) -> Tuple[jax.Array, jax.Array]:
-        """Enqueue one step over all slots: (a token a slot, experts touched), on the device."""
-        self.state, tokens, touched = lm_decode(self.params, self.state, active, cfg=self.cfg)
-        return tokens, touched
-
-    def warm(self) -> None:
-        """Compile every program the service can call: each prefill bucket, the step."""
-        for bucket in self.prefill_buckets:
-            self.prefill(0, [0] * min(bucket, self.max_prompt_tokens))
-        tokens, _ = self.decode(np.zeros((self.slots,), bool))
-        tokens.block_until_ready()
-
-    @staticmethod
-    def compiled_programs() -> int:
-        """Programs compiled so far, over every decoder of the process."""
-        return int(lm_prefill._cache_size() + lm_decode._cache_size())
+    count_names = ("experts_touched",)
+    lm_prefill, lm_decode = staticmethod(lm_prefill), staticmethod(lm_decode)
+    init_params, init_state = staticmethod(init_params), staticmethod(init_state)

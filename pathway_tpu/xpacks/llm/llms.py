@@ -2,9 +2,10 @@
 
 ``OpenAIChat`` (``:84``), ``LiteLLMChat`` (``:313``), ``HFPipelineChat`` (``:441``),
 ``CohereChat`` (``:544``) — async UDFs with capacity/retry/cache; clients gated at call time.
-``Lfm2Chat`` is the one that calls nothing out: an ``lfm2_moe`` decoder on this process's
-device behind a generation service (``models/lfm2.py``, ``models/generation_service.py``),
-and the one whose call outlives the commit that made it (``fully_async_executor``).
+``DeviceChat`` is the one that calls nothing out: a slot decoder on this process's device
+behind a generation service (``models/generation_service.py``), and the one whose call
+outlives the commit that made it (``fully_async_executor``); ``Lfm2Chat`` (``models/lfm2.py``)
+and ``Mistral4Chat`` (``models/mistral4.py``) are it over their decoders.
 """
 
 from __future__ import annotations
@@ -196,55 +197,37 @@ class CohereChat(BaseChat):
         self.func = chat
 
 
-class Lfm2Chat(BaseChat):
-    """A chat model on the device: the ``lfm2_moe`` decoder (LFM2-8B-A1B's
-    family, ``models/lfm2.py``) behind a slot-based ``GenerationService``.
+class DeviceChat(BaseChat):
+    """A chat model on this process's device: a slot decoder
+    (``models/slot_decoder.py``) behind a ``GenerationService``. It owns the
+    tokenizer, ``render``, the ``generate`` span, the executor and the
+    service; ``Lfm2Chat`` and ``Mistral4Chat`` differ only in the decoder they
+    build and hand over.
 
     Its limits: greedy decoding, exactly ``max_new_tokens`` tokens a reply, no
-    stop token; the tokenizer is the repository's ``HashTokenizer`` (one token a
-    lower-cased whitespace word, no vocabulary file), so the reply is the
-    generated ids written as words, ``t<id>`` each (``reply_ids`` reads them
-    back); the weights are random from ``seed`` unless ``params`` (a tree of
-    ``models/lfm2.param_shapes``) is given. ``config`` is a published
-    ``config.json`` as a dict, cut to what the chip holds: LFM2-8B-A1B's 24
-    layers are 16.7 GB in bfloat16, over one chip's 16, so there is no default
-    (``benchmarks/configs/lfm2-8b-a1b-rag.json`` serves its layers 0-13). A prompt
-    longer than ``max_prompt_tokens`` keeps its last tokens. The messages are
-    rendered as their contents, one a line: there is no chat template without
-    the checkpoint's tokenizer.
+    stop token; the tokenizer is the repository's ``HashTokenizer`` over the
+    decoder's vocabulary (one token a lower-cased whitespace word, no
+    vocabulary file), so the reply is the generated ids written as words,
+    ``t<id>`` each (``reply_ids`` reads them back). A prompt longer than
+    ``max_prompt_tokens`` keeps its last tokens. The messages are rendered as
+    their contents, one a line: there is no chat template without a
+    checkpoint's tokenizer.
 
     Its executor is ``fully_async_executor``: the commit that carries a prompt
     hands it to the service and ends, and the reply is a row of a later commit
     (a ``select`` that calls the chat has a row once its reply is there). The
     API chats above keep ``async_executor`` and are awaited inside the commit."""
 
-    def __init__(
-        self,
-        config: dict,
-        params: Any = None,
-        *,
-        slots: int = 16,
-        max_prompt_tokens: int = 1024,
-        max_new_tokens: int = 32,
-        prefill_buckets: tuple = (256, 512, 1024),
-        seed: int = 0,
-        cache_strategy: CacheStrategy | None = None,
-    ):
+    def __init__(self, decoder: Any, cache_strategy: CacheStrategy | None = None):
         # a reply takes a prefill and many steps of the service's own loop, which admits a
         # prompt between any two of them: the call leaves the commit that carried the prompt, and
         # the answer re-enters when it is ready (a wake-up and the REST connector's tick, no timer)
         super().__init__(executor=fully_async_executor(autocommit_duration_ms=1), cache_strategy=cache_strategy)
-        # the device is touched here, never at import: a process that builds no such chat loads no jax
         from pathway_tpu.models.encoder import HashTokenizer
         from pathway_tpu.models.generation_service import GenerationService
-        from pathway_tpu.models.lfm2 import Lfm2Config, Lfm2Decoder
 
-        self.config = Lfm2Config.from_dict(config)
-        self.decoder = Lfm2Decoder(
-            self.config, params, slots=slots, max_prompt_tokens=max_prompt_tokens,
-            max_new_tokens=max_new_tokens, prefill_buckets=prefill_buckets, seed=seed,
-        )
-        self.service = GenerationService(self.decoder)
+        self.config, self.decoder = decoder.cfg, decoder
+        self.service = GenerationService(decoder)
         self._tokenizer = HashTokenizer(vocab_size=self.config.vocab_size, max_length=1 << 30)
 
         async def chat(messages: Any, **kwargs: Any) -> str:
@@ -280,6 +263,69 @@ class Lfm2Chat(BaseChat):
     def reply_ids(reply: str) -> List[int]:
         """The generated ids of a reply of this chat, exactly."""
         return [int(word[1:]) for word in reply.split()]
+
+
+class Lfm2Chat(DeviceChat):
+    """``DeviceChat`` over the ``lfm2_moe`` decoder (LFM2-8B-A1B's family,
+    ``models/lfm2.py``); the shared chat has the limits, the tokenizer and the
+    executor. ``config`` is a published ``config.json`` as a dict, cut to what
+    the chip holds: LFM2-8B-A1B's 24 layers are 16.7 GB in bfloat16, over one
+    chip's 16, so there is no default (``benchmarks/configs/lfm2-8b-a1b-rag.json``
+    serves its layers 0-13). The weights are random from ``seed`` unless
+    ``params`` (a tree of ``models/lfm2.param_shapes``) is given."""
+
+    def __init__(
+        self,
+        config: dict,
+        params: Any = None,
+        *,
+        slots: int = 16,
+        max_prompt_tokens: int = 1024,
+        max_new_tokens: int = 32,
+        prefill_buckets: tuple = (256, 512, 1024),
+        seed: int = 0,
+        cache_strategy: CacheStrategy | None = None,
+    ):
+        # the device is touched here, never at import: a process that builds no such chat loads no jax
+        from pathway_tpu.models.lfm2 import Lfm2Config, Lfm2Decoder
+
+        super().__init__(Lfm2Decoder(
+            Lfm2Config.from_dict(config), params, slots=slots, max_prompt_tokens=max_prompt_tokens,
+            max_new_tokens=max_new_tokens, prefill_buckets=prefill_buckets, seed=seed,
+        ), cache_strategy)
+
+
+class Mistral4Chat(DeviceChat):
+    """``DeviceChat`` over the ``mistral4`` decoder (Mistral-Small-4-119B-2603's
+    family, ``models/mistral4.py``: latent attention over a compressed cache, a
+    shared expert beside the routed ones). ``config`` is a published
+    ``config.json`` as a dict, cut to one chip's share: ``num_hidden_layers``
+    the layers of its pipeline stage, ``n_routed_experts`` the experts held of
+    the router's ``n_router_experts`` from ``first_expert`` on, ``vocab_size``
+    the rows of the table and the head held (the whole model is 238 GB in
+    bfloat16, so there is no default;
+    ``benchmarks/configs/mistral-small-4-119b-rag.json`` serves one chip's share
+    of six layers). The weights are random from ``seed`` unless ``params`` (a
+    tree of ``models/mistral4.param_shapes``) is given."""
+
+    def __init__(
+        self,
+        config: dict,
+        params: Any = None,
+        *,
+        slots: int = 16,
+        max_prompt_tokens: int = 2048,
+        max_new_tokens: int = 64,
+        prefill_buckets: tuple = (1024, 1536, 2048),
+        seed: int = 0,
+        cache_strategy: CacheStrategy | None = None,
+    ):
+        from pathway_tpu.models.mistral4 import Mistral4Config, Mistral4Decoder
+
+        super().__init__(Mistral4Decoder(
+            Mistral4Config.from_dict(config), params, slots=slots, max_prompt_tokens=max_prompt_tokens,
+            max_new_tokens=max_new_tokens, prefill_buckets=prefill_buckets, seed=seed,
+        ), cache_strategy)
 
 
 def prompt_chat_single_qa(question: str) -> Json:

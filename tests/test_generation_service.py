@@ -43,6 +43,7 @@ class FakeDecoder:
     ``calls`` holds the calls; ``log`` the calls and the reads of their results, in order."""
 
     max_prompt_tokens = 8
+    count_names = ("experts_touched",)
 
     def __init__(self, fail_on_step=None, fail_on_read=None, slots=3, new=4):
         self.slots, self.max_new_tokens = slots, new
@@ -104,6 +105,35 @@ def test_more_requests_than_slots_all_resolve_and_the_counters_add_up():
             held.add(call[1])
         else:
             assert set(call[1]) <= held
+    svc.close()
+
+
+class TwoCountDecoder(FakeDecoder):
+    """A decoder that names two counts: each call returns both in one array."""
+
+    count_names = ("pairs_held", "pairs")
+
+    def _called(self, call, tokens, touched):
+        return super()._called(call, tokens, None if touched is None else [touched, 10 * touched])
+
+
+def test_a_decoder_that_names_two_counts_gets_both_summed_under_its_names_in_two_reads_a_call():
+    from pathway_tpu.engine import telemetry
+
+    before = telemetry.stage_snapshot("lm.")
+    svc = GenerationService(TwoCountDecoder())
+    futures = [svc.submit([10 * i]) for i in range(7)]
+    assert [f.result(timeout=10) for f in futures] == [[10 * i + 1 + j for j in range(4)] for i in range(7)]
+    st = svc.stats()
+    assert st["lm_pairs_held"] == 63 and st["lm_pairs"] == 630  # three a row and step, as FakeDecoder counts
+    assert st["lm_prefill_pairs_held"] == 14 and st["lm_prefill_pairs"] == 140
+    assert "lm_experts_touched" not in st and "lm_prefill_experts_touched" not in st
+    assert st["lm_prefill_calls"] == 7 and st["lm_decode_rows"] == 21
+    grew = {k: v - before.get(k, 0.0) for k, v in telemetry.stage_snapshot("lm.").items()}
+    assert grew["lm.pairs_held"] == 63.0 and grew["lm.pairs"] == 630.0 and not grew.get("lm.experts_touched")
+    # still two transfers a call: its tokens, and one array of its counts
+    log = svc.decoder.log
+    assert all(sum(e == ("read", n) for e in log) == 2 for n in range(len(svc.decoder.calls)))
     svc.close()
 
 

@@ -96,3 +96,29 @@ def test_the_generators_programs_lower_for_tpu_at_published_width(program):
         exported = _export_tpu(functools.partial(lfm2.prefill_logits, cfg=cfg), params, state,
                                S((256,), jnp.int32), S((), jnp.int32), S((), jnp.int32))
     assert exported.mlir_module().count("ragged_dot") >= 3 * 4  # w1, w3, w2 of the four expert layers
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_mistral4_programs_lower_for_tpu_at_published_width(program):
+    """``lm_decode`` (absorbed) and ``lm_prefill`` (expanded) of the ``mistral4``
+    decoder at Mistral-Small-4's widths (hidden 4,096, ranks 1,024 and 256, 32
+    heads of 64 + 64 and 128, 32 held experts of 2,048 under a router of 128,
+    32,768 vocabulary rows), two layers, 16 slots of 2,112 positions: shapes
+    only, nothing is allocated. The step never expands a key or a value: no
+    product of its module has the cache's positions beside a head's key size."""
+    from pathway_tpu.models import mistral4
+
+    cfg = mistral4.Mistral4Config(num_hidden_layers=2, n_routed_experts=32, n_router_experts=128, vocab_size=32768)
+    params = mistral4.param_shapes(cfg)
+    state = jax.eval_shape(lambda: mistral4.init_state(cfg, 16, 2112))
+    assert state["ckv"][0].shape == (16, 2112, 256) and state["kr"][0].shape == (16, 2112, 64)
+    if program == "decode":
+        exported = _export_tpu(functools.partial(mistral4.decode_logits, cfg=cfg), params, state, S((16,), jnp.bool_))
+        assert "16x2112x32x" not in exported.mlir_module()  # no per-head keys or values over the cache
+    else:
+        exported = _export_tpu(functools.partial(mistral4.prefill_logits, cfg=cfg), params, state,
+                               S((1536,), jnp.int32), S((), jnp.int32), S((), jnp.int32))
+        assert "1536x32x192" in exported.mlir_module()  # keys and values per head, from the latent
+        assert "tpu_custom_call" in exported.mlir_module()  # over them the flash kernel, not 32 x 1,536 x 1,536 scores
+        assert "32x1536x1536" not in exported.mlir_module()
+    assert exported.mlir_module().count("ragged_dot") >= 3 * 2  # w1, w3, w2 of both layers
