@@ -14,7 +14,8 @@ jitted programs that keep every request's state on the device, in *slots*:
   holds no request routes to no expert and writes no state.
 
 Both also return how many distinct experts their tokens chose, summed over the
-expert layers: the bytes a step has to read follow from it.
+expert layers (the bytes a step has to read follow from it), and how many of
+those layers' products ran batched (``COUNT_NAMES``).
 
 Two kinds of state live side by side in a slot: ``k``/``v`` of every attention
 layer ``(slots, max_len, kv heads, head size)`` and ``tail`` of every convolution
@@ -45,6 +46,10 @@ import jax.numpy as jnp
 
 from pathway_tpu.models.moe import grouped_experts, precision as _precision
 from pathway_tpu.models.slot_decoder import SlotDecoder, random_params
+
+# what a call counts beside its tokens, summed over the layers: distinct experts its tokens chose, and the expert
+# layers whose product ran batched (``models/moe.py``: a prefill's, unless an expert overflowed; never a step's)
+COUNT_NAMES = ("experts_touched", "batched_layers")
 
 # https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json
 PUBLISHED_LAYER_TYPES = tuple(
@@ -171,8 +176,10 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 def _moe(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Config) -> Tuple[jax.Array, jax.Array]:
     """The expert layer over ``h`` (tokens, hidden, float32, already normed).
     Tokens outside ``valid`` choose no expert. Returns the float32 output and
-    how many distinct experts the valid tokens chose. Every expert is held
-    here, so a chosen expert's number is its place in the stack."""
+    this call's ``COUNT_NAMES``: how many distinct experts the valid tokens
+    chose, and 1 where the product ran batched (``models/moe.py``). Every
+    expert is held here, so a chosen expert's number is its place in the stack
+    and the router's width is the stack's length."""
     k, e = cfg.num_experts_per_tok, cfg.num_experts
     with jax.named_scope("moe_route"):
         scores = jax.nn.sigmoid(jnp.dot(h, p["gate"], precision=jax.lax.Precision.HIGHEST))
@@ -182,8 +189,8 @@ def _moe(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Confi
         if cfg.norm_topk_prob:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
         weights = weights * cfg.routed_scaling_factor
-    out, group_sizes = grouped_experts(p, h, jnp.where(valid[:, None], chosen, e), weights)
-    return out, jnp.sum(group_sizes > 0, dtype=jnp.int32)
+    out, group_sizes, batched = grouped_experts(p, h, jnp.where(valid[:, None], chosen, e), weights, e)
+    return out, jnp.stack([jnp.sum(group_sizes > 0, dtype=jnp.int32), batched])
 
 
 def _ffn(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Config) -> Tuple[jax.Array, jax.Array]:
@@ -191,7 +198,7 @@ def _ffn(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Confi
         return _moe(p, h, valid, cfg)
     dtype = p["w1"].dtype
     mid = jax.nn.silu(_mm(h, p["w1"])) * _mm(h, p["w3"])
-    return _mm(mid.astype(dtype), p["w2"]), jnp.int32(0)
+    return _mm(mid.astype(dtype), p["w2"]), jnp.zeros((len(COUNT_NAMES),), jnp.int32)
 
 
 def _qkv(p: Dict[str, jax.Array], h: jax.Array, positions: jax.Array, cfg: Lfm2Config):
@@ -210,14 +217,14 @@ def prefill_logits(params: Dict[str, Any], state: Dict[str, Any], ids: jax.Array
                    slot: jax.Array, cfg: Lfm2Config) -> Tuple[Dict[str, Any], jax.Array, jax.Array]:
     """One prompt into one slot. ``ids`` (bucket,) holds ``length`` tokens and
     padding after them. Returns (the state, the logits of the prompt's last
-    token (vocab,), distinct experts chosen by the prompt's tokens)."""
+    token (vocab,), the counts (2,))."""
     t, dtype, eps = ids.shape[0], params["embed"].dtype, cfg.norm_eps
     valid = jnp.arange(t) < length
     x = params["embed"][ids].astype(jnp.float32)
     group = cfg.num_attention_heads // cfg.num_key_value_heads
     causal = jnp.tril(jnp.ones((t, t), bool))
     state = dict(state, k=list(state["k"]), v=list(state["v"]), tail=list(state["tail"]))
-    touched = jnp.int32(0)
+    counts = jnp.zeros((len(COUNT_NAMES),), jnp.int32)
     i_attn = i_conv = 0
     for kind, p in zip(cfg.layer_types, params["layers"]):
         h = _norm(x, p["operator_norm"], eps)
@@ -250,10 +257,10 @@ def prefill_logits(params: Dict[str, Any], state: Dict[str, Any], ids: jax.Array
         x = x + out
         out, n = _ffn(p, _norm(x, p["ffn_norm"], eps), valid, cfg)
         x = x + out
-        touched = touched + n
+        counts = counts + n
     last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=0, keepdims=False)
     logits = _mm(_norm(last, params["final_norm"], eps), params["embed"].T)
-    return state, logits, touched
+    return state, logits, counts
 
 
 def decode_logits(params: Dict[str, Any], state: Dict[str, Any], active: jax.Array,
@@ -261,7 +268,7 @@ def decode_logits(params: Dict[str, Any], state: Dict[str, Any], active: jax.Arr
     """One token for every slot: feeds ``state["last"]`` at ``state["pos"]``.
     Rows outside ``active`` write nothing and choose no expert. Returns (the
     state with keys, values and tails extended but ``pos``/``last`` as they were,
-    logits (slots, vocab), distinct experts chosen by the active rows)."""
+    logits (slots, vocab), the counts (2,))."""
     dtype, eps = params["embed"].dtype, cfg.norm_eps
     pos = state["pos"]
     slots, max_len = pos.shape[0], (state["k"][0].shape[1] if state["k"] else 0)
@@ -270,7 +277,7 @@ def decode_logits(params: Dict[str, Any], state: Dict[str, Any], active: jax.Arr
     x = params["embed"][state["last"]].astype(jnp.float32)
     group = cfg.num_attention_heads // cfg.num_key_value_heads
     state = dict(state, k=list(state["k"]), v=list(state["v"]), tail=list(state["tail"]))
-    touched = jnp.int32(0)
+    counts = jnp.zeros((len(COUNT_NAMES),), jnp.int32)
     i_attn = i_conv = 0
     for kind, p in zip(cfg.layer_types, params["layers"]):
         h = _norm(x, p["operator_norm"], eps)
@@ -303,38 +310,37 @@ def decode_logits(params: Dict[str, Any], state: Dict[str, Any], active: jax.Arr
         x = x + out
         out, n = _ffn(p, _norm(x, p["ffn_norm"], eps), active, cfg)
         x = x + out
-        touched = touched + n
+        counts = counts + n
     logits = _mm(_norm(x, params["final_norm"], eps), params["embed"].T)
-    return state, logits, touched
+    return state, logits, counts
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnames=("state",))
 def lm_prefill(params, state, ids, length, slot, *, cfg):
-    """The prefill program: the slot filled, its first greedy token, experts touched."""
+    """The prefill program: the slot filled, its first greedy token, the counts."""
     with jax.named_scope("lm_prefill"):
-        state, logits, touched = prefill_logits(params, state, ids, length, slot, cfg)
+        state, logits, counts = prefill_logits(params, state, ids, length, slot, cfg)
         token = jnp.argmax(logits).astype(jnp.int32)
         state["pos"] = state["pos"].at[slot].set(length)
         state["last"] = state["last"].at[slot].set(token)
-        return state, token, touched
+        return state, token, counts
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnames=("state",))
 def lm_decode(params, state, active, *, cfg):
-    """The decode program: one greedy token a slot (only ``active`` rows advance), experts touched."""
+    """The decode program: one greedy token a slot (only ``active`` rows advance), the counts."""
     with jax.named_scope("lm_decode"):
-        state, logits, touched = decode_logits(params, state, active, cfg)
+        state, logits, counts = decode_logits(params, state, active, cfg)
         tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         state["pos"] = jnp.where(active, state["pos"] + 1, state["pos"])
         state["last"] = jnp.where(active, tokens, state["last"])
-        return state, tokens, touched
+        return state, tokens, counts
 
 
 class Lfm2Decoder(SlotDecoder):
     """The ``lfm2_moe`` decoder as the generation service drives it
-    (``models/slot_decoder.py``); a call's one count is the distinct experts
-    its tokens chose, summed over the expert layers."""
+    (``models/slot_decoder.py``)."""
 
-    count_names = ("experts_touched",)
+    count_names = COUNT_NAMES
     lm_prefill, lm_decode = staticmethod(lm_prefill), staticmethod(lm_decode)
     init_params, init_state = staticmethod(init_params), staticmethod(init_state)

@@ -33,15 +33,18 @@ never read.
 ``n_routed_experts`` of them from ``first_expert`` on (one chip's share of a
 layer under expert parallelism). A token's four experts are chosen over the
 whole width and their weights normalised over all four; only the pairs whose
-expert is held enter the grouped product (``models/moe.py``), and what the
+expert is held enter the expert product (``models/moe.py``, which is told the
+router's width: a held expert's even share of a call's rows is over all the
+router chooses among, and the form of the product follows from it), and what the
 absent experts would add is left out, here and in the reference alike. The
 shared expert, which every chip of the layer computes, is added whole.
 ``vocab_size`` is the rows of the table and of the (untied) head held here: ids,
 logits and the greedy choice are over that slice.
 
-Both programs return three counts beside their tokens (``COUNT_NAMES``):
+Both programs return four counts beside their tokens (``COUNT_NAMES``):
 distinct held experts their tokens chose, summed over the layers; routed pairs
-whose expert is held; routed pairs in all.
+whose expert is held; routed pairs in all; the layers whose expert product ran
+batched (a prefill's, unless an expert overflowed its capacity; never a step's).
 
 Precision as ``models/lfm2.py``: weights, operands and the cache in the dtype of
 ``params["embed"]`` (bfloat16 as served); products accumulate in float32; the
@@ -61,7 +64,7 @@ import jax.numpy as jnp
 from pathway_tpu.models.moe import grouped_experts, precision as _precision
 from pathway_tpu.models.slot_decoder import SlotDecoder, random_params
 
-COUNT_NAMES = ("experts_touched", "routed_pairs_held", "routed_pairs")
+COUNT_NAMES = ("experts_touched", "routed_pairs_held", "routed_pairs", "batched_layers")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -297,7 +300,7 @@ def routed_experts(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array,
                    cfg: Mistral4Config) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the routed sum over ``h`` (tokens, hidden,
     float32, already normed). Tokens outside ``valid`` choose no expert.
-    Returns the float32 output and this call's ``COUNT_NAMES`` (3,)."""
+    Returns the float32 output and this call's ``COUNT_NAMES`` (4,)."""
     k, held, first = cfg.num_experts_per_tok, cfg.n_routed_experts, cfg.first_expert
     with jax.named_scope("moe_route"):
         probs = jax.nn.softmax(jnp.dot(h, p["gate"], precision=jax.lax.Precision.HIGHEST), axis=-1)
@@ -307,9 +310,9 @@ def routed_experts(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array,
         weights = weights * cfg.routed_scaling_factor
         here = valid[:, None] & (chosen >= first) & (chosen < first + held)
         local = jnp.where(here, chosen - first, held)
-    out, group_sizes = grouped_experts(p, h, local, weights)
+    out, group_sizes, batched = grouped_experts(p, h, local, weights, cfg.router_width)
     counts = jnp.stack([jnp.sum(group_sizes > 0, dtype=jnp.int32), jnp.sum(group_sizes, dtype=jnp.int32),
-                        k * jnp.sum(valid, dtype=jnp.int32)])
+                        k * jnp.sum(valid, dtype=jnp.int32), batched])
     return out, counts
 
 
@@ -322,7 +325,7 @@ def prefill_logits(params: Dict[str, Any], state: Dict[str, Any], ids: jax.Array
                    slot: jax.Array, cfg: Mistral4Config) -> Tuple[Dict[str, Any], jax.Array, jax.Array]:
     """One prompt into one slot, attention in the expanded form. ``ids``
     (bucket,) holds ``length`` tokens and padding after them. Returns (the
-    state, the logits of the prompt's last token (vocab,), the counts (3,))."""
+    state, the logits of the prompt's last token (vocab,), the counts (4,))."""
     t, dtype, eps = ids.shape[0], params["embed"].dtype, cfg.rms_norm_eps
     positions = jnp.arange(t)
     valid = positions < length
@@ -354,7 +357,7 @@ def decode_logits(params: Dict[str, Any], state: Dict[str, Any], active: jax.Arr
     latent cache: feeds ``state["last"]`` at ``state["pos"]``. Rows outside
     ``active`` write nothing and choose no expert. Returns (the state with the
     cache extended but ``pos``/``last`` as they were, logits (slots, vocab), the
-    counts (3,))."""
+    counts (4,))."""
     dtype, eps = params["embed"].dtype, cfg.rms_norm_eps
     pos = state["pos"]
     slots, max_len = pos.shape[0], state["ckv"][0].shape[1]

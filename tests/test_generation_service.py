@@ -304,3 +304,33 @@ def test_requests_arriving_between_steps_get_the_tokens_they_get_alone(slots, ga
     assert st["lm_prefill_tokens"] == sum(len(p) for p in prompts) and st["lm_decode_rows"] == 7 * 5
     assert st["lm_prefill_padded_tokens"] == sum(16 if len(p) <= 16 else 32 for p in prompts)
     svc.close()
+
+
+@pytest.mark.parametrize("family", ["lfm2", "mistral4"])
+def test_the_layers_whose_product_ran_batched_are_counted_for_prefills_and_stay_zero_over_steps(family):
+    """Both decoders name ``batched_layers``; the service sums it as it sums any
+    count. A bucket at the floor of sixteen rows an expert runs every expert
+    layer's product batched (four layers here, three there); a smaller bucket
+    and every step run the grouped product, and count nothing."""
+    from pathway_tpu.engine import telemetry
+
+    if family == "lfm2":
+        dec = decoder(lfm2.init_params(CFG, seed=3, dtype=jnp.float32), buckets=(16, 64), new=4)
+        large, layers = 40, 4
+    else:
+        from . import test_mistral4 as m4
+
+        whole = m4.mistral4.init_params(m4.WHOLE, seed=3, dtype=jnp.float32)
+        dec = m4.decoder(m4.share_of(whole, m4.CFG), buckets=(16, 128), new=4)
+        large, layers = 100, 3
+    assert dec.count_names[-1] == "batched_layers"
+    before = telemetry.stage_snapshot("lm.")
+    svc = GenerationService(dec)
+    futures = [svc.submit(prompt_of(n, seed=60 + n, vocab=1024)) for n in (large, 9, large + 5)]
+    assert all(len(f.result(timeout=120)) == 4 for f in futures)
+    st = svc.stats()
+    assert st["lm_prefill_calls"] == 3 and st["lm_decode_rows"] == 9
+    assert st["lm_prefill_batched_layers"] == 2 * layers and st["lm_batched_layers"] == 0
+    grew = {k: v - before.get(k, 0.0) for k, v in telemetry.stage_snapshot("lm.").items()}
+    assert grew["lm.decode_rows"] == 9.0 and grew["lm.batched_layers"] == 0.0
+    svc.close()

@@ -73,14 +73,14 @@ def prompt_of(n, seed=0, vocab=CFG.vocab_size):
 def run_through_cache(dec, slot, prompt, steps):
     """Prefill then ``steps`` decode steps of one slot, with the un-jitted
     cores so that the logits can be read: (logits per position, tokens,
-    experts touched per call)."""
+    each call's counts: experts touched, layers batched)."""
     state, logits, touched = PREFILL(
         dec.params, dec.state, jnp.asarray(prompt + [0] * (dec.bucket_of(len(prompt)) - len(prompt)), jnp.int32),
         jnp.int32(len(prompt)), jnp.int32(slot), cfg=dec.cfg)
     token = int(jnp.argmax(logits))
     state["pos"] = state["pos"].at[slot].set(len(prompt))
     state["last"] = state["last"].at[slot].set(token)
-    rows, tokens, counts = [np.asarray(logits)], [token], [int(touched)]
+    rows, tokens, counts = [np.asarray(logits)], [token], [touched.tolist()]
     active = np.zeros((dec.slots,), bool)
     active[slot] = True
     for _ in range(steps):
@@ -90,7 +90,7 @@ def run_through_cache(dec, slot, prompt, steps):
         state["last"] = state["last"].at[slot].set(token)
         rows.append(np.asarray(logits[slot]))
         tokens.append(token)
-        counts.append(int(touched))
+        counts.append(touched.tolist())
     dec.state = state
     return np.stack(rows), tokens, counts
 
@@ -103,6 +103,21 @@ def test_prefill_then_decode_gives_the_references_logits_at_every_position(param
     assert np.std(want) > 0.1  # the logits spread: a wrong program would pick other tokens
     assert_close(served, want)
     assert_greedy(params, prompt, tokens)
+
+
+@pytest.mark.parametrize("length", [64, 41])
+def test_a_prefill_bucket_with_sixteen_rows_an_expert_takes_the_batched_product_and_holds_the_reference(params, length):
+    """Buckets of 16 and 32 tokens (2 of 8 experts each: 4 and 8 rows an expert)
+    lower to the grouped product alone; 64 is at the floor, and each of the four
+    expert layers' products runs batched (64 places an expert for at most 64
+    rows: nothing can overflow). The steps after it are grouped as ever."""
+    prompt = prompt_of(length, seed=50)
+    served, tokens, counts = run_through_cache(decoder(params, buckets=(16, 64)), 2, prompt, 3)
+    full, chosen = reference(params, prompt + tokens, pad_to=80)
+    assert_close(served, full[length - 1 : length + 3])
+    assert_greedy(params, prompt, tokens)
+    assert counts[0] == [sum(len(set(c[:length].ravel().tolist())) for c in chosen), 4]
+    assert [c[1] for c in counts[1:]] == [0, 0, 0]
 
 
 def one_operator(kind, ffn):
@@ -164,7 +179,7 @@ def test_experts_are_chosen_by_biased_scores_and_weighted_by_unbiased_ones():
     h = jax.random.normal(jax.random.PRNGKey(0), (10, cfg.hidden_size), jnp.float32)
     out, touched = lfm2._moe(layer, h, jnp.ones((10,), bool), cfg)
     want, chosen = ref.moe_ffn(jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), layer), h, cfg)
-    assert sorted(set(np.asarray(chosen).ravel().tolist())) == [6, 7] and int(touched) == 2
+    assert sorted(set(np.asarray(chosen).ravel().tolist())) == [6, 7] and touched.tolist() == [2, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
     # weights taken from the biased scores would be 5.x / (5.x + 5.y), near a half each: not what is served
     scores = jax.nn.sigmoid(h @ layer["gate"])[:, 6:]
@@ -172,7 +187,7 @@ def test_experts_are_chosen_by_biased_scores_and_weighted_by_unbiased_ones():
     assert float(jnp.max(jnp.abs(unbiased - 0.5))) > 0.1
     # and a token that is not valid chooses nothing and gets nothing
     out, touched = lfm2._moe(layer, h, jnp.arange(10) < 0, cfg)
-    assert int(touched) == 0 and float(jnp.max(jnp.abs(out))) == 0.0
+    assert touched.tolist() == [0, 0] and float(jnp.max(jnp.abs(out))) == 0.0
 
 
 def test_prompts_of_unequal_length_in_the_slots_get_the_tokens_they_get_alone(params):
@@ -217,7 +232,7 @@ def test_the_distinct_expert_count_equals_the_references(params):
     for slot, ids in prompts.items():
         token, touched = dec.prefill(slot, ids)
         _, chosen = reference(params, ids)
-        assert int(touched) == sum(len(set(c.ravel().tolist())) for c in chosen)
+        assert np.asarray(touched).tolist() == [sum(len(set(c.ravel().tolist())) for c in chosen), 0]
         sequences[slot] = ids + [int(token)]
     active = np.array([False, True, True, False])
     for _ in range(4):
@@ -229,7 +244,7 @@ def test_the_distinct_expert_count_equals_the_references(params):
             for layer, c in enumerate(chosen):
                 per_layer[layer] |= set(c[-1].tolist())
             seq.append(int(tokens[slot]))
-        assert int(touched) == sum(len(s) for s in per_layer)
+        assert np.asarray(touched).tolist() == [sum(len(s) for s in per_layer), 0]
 
 
 def test_bfloat16_as_served_stays_near_the_float32_reference():

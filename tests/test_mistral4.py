@@ -125,6 +125,24 @@ def test_prefill_then_decode_through_the_latent_cache_gives_the_references_logit
     assert_greedy(params, prompt, tokens)
 
 
+@pytest.mark.parametrize("length", [128, 90])
+def test_a_prefill_bucket_with_sixteen_rows_an_expert_takes_the_batched_product_and_holds_the_reference(params, length):
+    """Buckets of 16 and 32 tokens (2 of the router's 16 each: 2 and 4 rows an
+    expert) lower to the grouped product alone; 128 is at the floor, and the
+    product of each of the three layers runs batched over the 4 held experts'
+    64 places, the pairs of the 12 absent ones "none". The steps stay grouped."""
+    prompt = prompt_of(length, seed=50)
+    served, tokens, counts = run_through_cache(decoder(params, buckets=(16, 128)), 2, prompt, 3)
+    full, chosen = reference(params, prompt + tokens, pad_to=144)
+    assert_close(served, full[length - 1 : length + 3])
+    assert_greedy(params, prompt, tokens)
+    held = [c[:length][(c[:length] >= CFG.first_expert) & (c[:length] < CFG.first_expert + CFG.n_routed_experts)]
+            for c in chosen]
+    assert max(np.bincount(c).max() for c in held) <= 64  # no held expert over its capacity: nothing falls back
+    assert counts[0] == [sum(len(set(c.tolist())) for c in held), sum(c.size for c in held), 3 * 2 * length, 3]
+    assert [c[3] for c in counts[1:]] == [0, 0, 0]
+
+
 def test_the_four_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_layer(whole):
     """Expert parallelism's bookkeeping: what each of four chips computes from
     its 4 of the 16 experts, summed, with the shared expert (which every chip
@@ -153,7 +171,7 @@ def test_the_four_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_u
     assert float(jnp.max(jnp.abs(mistral4._moe(share_of(whole, CFG)["layers"][1], h, valid, CFG)[0] - want))) > 0.05
     # and a token that is not valid chooses nothing and gets the shared expert alone
     out, counts = mistral4._moe(share_of(whole, CFG)["layers"][1], h, jnp.arange(10) < 0, CFG)
-    assert counts.tolist() == [0, 0, 0]
+    assert counts.tolist() == [0, 0, 0, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(mistral4.shared_expert(p, h)), atol=1e-6)
 
 
@@ -254,7 +272,7 @@ def test_the_counts_equal_the_references(params):
         token, counts = dec.prefill(slot, ids)
         _, chosen = reference(params, ids)
         assert np.asarray(counts).tolist() == [sum(len(set(held(c).tolist())) for c in chosen),
-                                               sum(held(c).size for c in chosen), 3 * 2 * len(ids)]
+                                               sum(held(c).size for c in chosen), 3 * 2 * len(ids), 0]
         sequences[slot] = ids + [int(token)]
     active = np.array([False, True, True, False])
     for _ in range(4):
@@ -265,7 +283,7 @@ def test_the_counts_equal_the_references(params):
             for layer, c in enumerate(chosen):
                 per_layer[layer] += held(c[-1]).tolist()
             seq.append(int(tokens[slot]))
-        assert np.asarray(counts).tolist() == [sum(len(set(c)) for c in per_layer), sum(len(c) for c in per_layer), 12]
+        assert np.asarray(counts).tolist() == [sum(len(set(c)) for c in per_layer), sum(len(c) for c in per_layer), 12, 0]
 
 
 def test_the_padding_bucket_changes_nothing(params):

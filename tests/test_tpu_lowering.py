@@ -79,12 +79,25 @@ def test_encoder_forward_lowers_for_tpu():
     assert exported.out_avals[0].shape == (8, 64)
 
 
+def _assert_batched_products(text, layers, rows_in, rows_mid):
+    """In ``layers`` expert layers a conditional whose one branch holds three
+    batched products from a padded buffer (``rows_in`` into w1 and w3,
+    ``rows_mid`` into w2) and whose other holds the grouped ones; with 0, neither."""
+    # the conditional's branches by their places in the text: the false one grouped, the true one batched
+    assert ("/moe_experts/cond/branch_0_fun/ragged_dot" in text) == ("/moe_experts/cond/branch_1_fun/" in text)
+    assert ("/moe_experts/cond/" in text) == bool(layers) and ("/moe_experts/ragged_dot" in text) == (not layers)
+    assert text.count(f"(tensor<{rows_in}>, ") == 2 * layers and text.count(f"(tensor<{rows_mid}>, ") == layers
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_the_generators_programs_lower_for_tpu_at_published_width(program):
     """``lm_decode`` and ``lm_prefill`` of the ``lfm2_moe`` decoder at LFM2-8B-A1B's
     widths (hidden 2,048, 32 experts of 1,792, top 4, vocabulary 65,536), one
     period of its layer pattern, 16 slots: shapes only, nothing is allocated.
-    The expert products stay one grouped product each (XLA's ragged dot)."""
+    A step's expert products are one grouped product each (XLA's ragged dot) and
+    nothing else; a prefill's 256 tokens give an expert 32 rows, so each of its
+    four expert layers holds a conditional over the batched products (32 experts
+    x 128 places) and the grouped ones (``models/moe.py``)."""
     from pathway_tpu.models import lfm2
 
     cfg = lfm2.Lfm2Config(num_hidden_layers=6, layer_types=lfm2.PUBLISHED_LAYER_TYPES[:6])
@@ -95,7 +108,9 @@ def test_the_generators_programs_lower_for_tpu_at_published_width(program):
     else:
         exported = _export_tpu(functools.partial(lfm2.prefill_logits, cfg=cfg), params, state,
                                S((256,), jnp.int32), S((), jnp.int32), S((), jnp.int32))
-    assert exported.mlir_module().count("ragged_dot") >= 3 * 4  # w1, w3, w2 of the four expert layers
+    text = exported.mlir_module()
+    assert text.count("@chlo.ragged_dot(") == 3 * 4  # w1, w3, w2 of the four expert layers
+    _assert_batched_products(text, 4 if program == "prefill" else 0, "32x128x2048xbf16", "32x128x1792xbf16")
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -121,4 +136,7 @@ def test_the_mistral4_programs_lower_for_tpu_at_published_width(program):
         assert "1536x32x192" in exported.mlir_module()  # keys and values per head, from the latent
         assert "tpu_custom_call" in exported.mlir_module()  # over them the flash kernel, not 32 x 1,536 x 1,536 scores
         assert "32x1536x1536" not in exported.mlir_module()
-    assert exported.mlir_module().count("ragged_dot") >= 3 * 2  # w1, w3, w2 of both layers
+    text = exported.mlir_module()
+    assert text.count("@chlo.ragged_dot(") == 3 * 2  # w1, w3, w2 of both layers
+    # 1,536 tokens x 4 over the router's 128 give a held expert 48 rows and 192 places; a step's 16 x 4 give it half a row
+    _assert_batched_products(text, 2 if program == "prefill" else 0, "32x192x4096xbf16", "32x192x2048xbf16")

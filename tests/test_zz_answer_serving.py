@@ -152,6 +152,8 @@ def test_zz_counters_and_spans_carry_what_the_request_implies(served):
     assert grew["lm_decode_steps"] == grew["lm_decode_rows"] == NEW_TOKENS - 1
     # four expert layers, two experts a token: one row a step chooses eight
     assert grew["lm_experts_touched"] == 8 * (NEW_TOKENS - 1) and grew["lm_compiled_programs"] == 0
+    # the 256 bucket gives an expert 64 rows: the four layers' products ran batched in the prefill, in no step
+    assert grew["lm_prefill_batched_layers"] == 4 and grew["lm_batched_layers"] == 0
     # another decoder of this process may have named counts of its own: they did not move here
     stage = {k: grown for k in served["after"][1] if (grown := served["after"][1][k] - served["before"][1].get(k, 0.0))}
     # the loop was idle, so every call but the prefill was enqueued with the call before it unread
