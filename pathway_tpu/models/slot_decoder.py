@@ -1,7 +1,8 @@
 """What the slot-based decoders share on the host: the parameters, the slots
 and the two jitted programs, as the generation service drives them.
 
-A model's file (``models/lfm2.py``, ``models/mistral4.py``) gives the two
+A model's file (``models/lfm2.py``, ``models/mistral4.py``,
+``models/falcon_h1.py``) gives the two
 programs, ``lm_prefill(params, state, ids, length, slot, *, cfg)`` and
 ``lm_decode(params, state, active, *, cfg)``, each returning (state, tokens,
 counts), with ``init_params`` and ``init_state``; a subclass names them and
@@ -12,6 +13,7 @@ one number or a vector of as many), which the service sums under those names.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, Dict, Sequence, Tuple
 
 import numpy as np
@@ -25,23 +27,42 @@ def _draw(key, *, shape, dtype, std, mean):
     return (mean + std * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "name"))
+def _draw_ssm_vector(key, *, shape, name):
+    """Mamba-2's own initialisation of a state-space mixer's per-head vectors:
+    ``A_log = log U(1, 16)``, ``dt_bias`` the inverse softplus of ``exp U(log
+    1e-3, log 1e-1)``, ``D = 1``."""
+    if name == "A_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    if name == "dt_bias":
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return jnp.ones(shape, jnp.float32)
+
+
 def random_params(shapes: Dict[str, Any], seed: int) -> Dict[str, Any]:
     """Random parameters for a tree of shapes, made on the device one array at
     a time, by each leaf's name: matrices normal at ``1/sqrt(fan in)``, the
-    table (``embed``) at 0.02, norms around 1, an ``expert_bias`` at 0.05."""
+    table (``embed``) at 0.02, norms around 1, an ``expert_bias`` at 0.05, a
+    convolution's bias (``conv_b``) at 0.02, a state-space mixer's ``A_log``,
+    ``dt_bias`` and ``D`` as ``_draw_ssm_vector`` has them. A leaf's key follows
+    from its place in its own tree, so a name added here moves no other draw."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     out = []
     for i, (path, leaf) in enumerate(leaves):
         name = path[-1].key
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+        if name in ("A_log", "dt_bias", "D"):
+            out.append(_draw_ssm_vector(key, shape=leaf.shape, name=name))
+            continue
         if name.endswith("norm"):
             std, mean = 0.1, 1.0
         elif name == "expert_bias":
             std, mean = 0.05, 0.0
-        elif name == "embed":
+        elif name in ("embed", "conv_b"):
             std, mean = 0.02, 0.0
         else:  # a matrix: the axis before the last is the one summed over
             std, mean = float(leaf.shape[-2 if name != "conv_w" else -1]) ** -0.5, 0.0
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
         out.append(_draw(key, shape=leaf.shape, dtype=leaf.dtype, std=std, mean=mean))
     return jax.tree_util.tree_unflatten(treedef, out)
 

@@ -4,8 +4,9 @@
 ``CohereChat`` (``:544``) — async UDFs with capacity/retry/cache; clients gated at call time.
 ``DeviceChat`` is the one that calls nothing out: a slot decoder on this process's device
 behind a generation service (``models/generation_service.py``), and the one whose call
-outlives the commit that made it (``fully_async_executor``); ``Lfm2Chat`` (``models/lfm2.py``)
-and ``Mistral4Chat`` (``models/mistral4.py``) are it over their decoders.
+outlives the commit that made it (``fully_async_executor``); ``Lfm2Chat`` (``models/lfm2.py``),
+``Mistral4Chat`` (``models/mistral4.py``) and ``FalconH1Chat`` (``models/falcon_h1.py``) are it
+over their decoders.
 """
 
 from __future__ import annotations
@@ -201,8 +202,8 @@ class DeviceChat(BaseChat):
     """A chat model on this process's device: a slot decoder
     (``models/slot_decoder.py``) behind a ``GenerationService``. It owns the
     tokenizer, ``render``, the ``generate`` span, the executor and the
-    service; ``Lfm2Chat`` and ``Mistral4Chat`` differ only in the decoder they
-    build and hand over.
+    service; ``Lfm2Chat``, ``Mistral4Chat`` and ``FalconH1Chat`` differ only in
+    the decoder they build and hand over.
 
     Its limits: greedy decoding, exactly ``max_new_tokens`` tokens a reply, no
     stop token; the tokenizer is the repository's ``HashTokenizer`` over the
@@ -324,6 +325,37 @@ class Mistral4Chat(DeviceChat):
 
         super().__init__(Mistral4Decoder(
             Mistral4Config.from_dict(config), params, slots=slots, max_prompt_tokens=max_prompt_tokens,
+            max_new_tokens=max_new_tokens, prefill_buckets=prefill_buckets, seed=seed,
+        ), cache_strategy)
+
+
+class FalconH1Chat(DeviceChat):
+    """``DeviceChat`` over the ``falcon_h1`` decoder (Falcon-H1-34B-Instruct's
+    family, ``models/falcon_h1.py``: a Mamba-2 state-space mixer and
+    grouped-query attention side by side in every block, the mixer's recurrent
+    state kept in the slot beside keys and values). ``config`` is a published
+    ``config.json`` as a dict, cut to what the chip holds: the whole model's 72
+    blocks with the table and the untied head are 67.3 GB in bfloat16, over any
+    chip's 16, so there is no default (``benchmarks/configs/falcon-h1-34b-rag.json``
+    serves its blocks 0-5). The weights are random from ``seed`` unless
+    ``params`` (a tree of ``models/falcon_h1.param_shapes``) is given."""
+
+    def __init__(
+        self,
+        config: dict,
+        params: Any = None,
+        *,
+        slots: int = 32,
+        max_prompt_tokens: int = 1024,
+        max_new_tokens: int = 128,
+        prefill_buckets: tuple = (256, 512, 1024),
+        seed: int = 0,
+        cache_strategy: CacheStrategy | None = None,
+    ):
+        from pathway_tpu.models.falcon_h1 import FalconH1Config, FalconH1Decoder
+
+        super().__init__(FalconH1Decoder(
+            FalconH1Config.from_dict(config), params, slots=slots, max_prompt_tokens=max_prompt_tokens,
             max_new_tokens=max_new_tokens, prefill_buckets=prefill_buckets, seed=seed,
         ), cache_strategy)
 

@@ -334,3 +334,27 @@ def test_the_layers_whose_product_ran_batched_are_counted_for_prefills_and_stay_
     grew = {k: v - before.get(k, 0.0) for k, v in telemetry.stage_snapshot("lm.").items()}
     assert grew["lm.decode_rows"] == 9.0 and grew["lm.batched_layers"] == 0.0
     svc.close()
+
+
+def test_a_state_space_decoders_state_rows_are_summed_for_prefills_and_grow_by_slots_x_layers_a_step():
+    """The third decoder names one count, ``state_rows``: the (slot, layer)
+    state-space states a call read and rewrote. The service sums it as it sums
+    any count: a prefill's as ``lm_prefill_state_rows`` (one a layer), a step's
+    as ``lm_state_rows`` (as the step is written, every slot's in every layer,
+    live or not), the latter also among the ``lm.*`` stage counters."""
+    from pathway_tpu.engine import telemetry
+
+    from . import test_falcon_h1 as fh
+
+    dec = fh.decoder(fh.falcon_h1.init_params(fh.CFG, seed=3, dtype=jnp.float32), slots=4, new=4)
+    assert dec.count_names == ("state_rows",)
+    before = telemetry.stage_snapshot("lm.")
+    svc = GenerationService(dec)
+    futures = [svc.submit(prompt_of(n, seed=70 + n, vocab=4096)) for n in (9, 20)]
+    assert all(len(f.result(timeout=120)) == 4 for f in futures)
+    st = svc.stats()
+    assert st["lm_prefill_calls"] == 2 and st["lm_prefill_state_rows"] == 2 * 3
+    assert st["lm_decode_steps"] >= 3 and st["lm_state_rows"] == st["lm_decode_steps"] * 4 * 3
+    grew = {k: v - before.get(k, 0.0) for k, v in telemetry.stage_snapshot("lm.").items()}
+    assert grew["lm.state_rows"] == float(st["lm_state_rows"])
+    svc.close()

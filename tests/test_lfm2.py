@@ -267,3 +267,38 @@ def test_config_from_the_published_json_and_its_cut():
     assert n_params == 4_667_077_376  # issue 28's arithmetic: 4,667M in layers 0-13 and the tied table
     with pytest.raises(ValueError):
         lfm2.Lfm2Config.from_dict({"num_hidden_layers": 3})
+
+
+def parents_random_params(shapes, seed):
+    """``slot_decoder.random_params`` as it stood before it learnt the state-space
+    mixer's four vector names (PR 35), spelled out: the rule by a leaf's name,
+    the key by its place in its own tree."""
+    from pathway_tpu.models.slot_decoder import _draw
+
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    out = []
+    for i, (path, leaf) in enumerate(leaves):
+        name = path[-1].key
+        if name.endswith("norm"):
+            std, mean = 0.1, 1.0
+        elif name == "expert_bias":
+            std, mean = 0.05, 0.0
+        elif name == "embed":
+            std, mean = 0.02, 0.0
+        else:
+            std, mean = float(leaf.shape[-2 if name != "conv_w" else -1]) ** -0.5, 0.0
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+        out.append(_draw(key, shape=leaf.shape, dtype=leaf.dtype, std=std, mean=mean))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def assert_bit_equal(got, want):
+    same = jax.tree.map(lambda a, b: a.dtype == b.dtype and np.array_equal(np.asarray(a).view(np.uint8),
+                                                                           np.asarray(b).view(np.uint8)), got, want)
+    assert all(jax.tree.leaves(same))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_init_params_at_a_seed_is_bit_equal_to_the_parents_draw(dtype):
+    """``random_params`` gained four names for another decoder; no leaf of this one moved."""
+    assert_bit_equal(lfm2.init_params(CFG, seed=3, dtype=dtype), parents_random_params(lfm2.param_shapes(CFG, dtype), 3))

@@ -357,3 +357,12 @@ def test_mistral4_chat_through_fully_async_in_a_select(whole):
 def continues(params, ids, tokens, cfg):
     logits, _ = reference(params, ids + tokens, cfg)
     return tokens == np.argmax(logits[len(ids) - 1 : -1], axis=-1).tolist()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_init_params_at_a_seed_is_bit_equal_to_the_parents_draw(dtype):
+    """``random_params`` gained four names for another decoder; no leaf of this one moved."""
+    from .test_lfm2 import assert_bit_equal, parents_random_params
+
+    assert_bit_equal(mistral4.init_params(WHOLE, seed=3, dtype=dtype),
+                     parents_random_params(mistral4.param_shapes(WHOLE, dtype), 3))

@@ -140,3 +140,31 @@ def test_the_mistral4_programs_lower_for_tpu_at_published_width(program):
     assert text.count("@chlo.ragged_dot(") == 3 * 2  # w1, w3, w2 of both layers
     # 1,536 tokens x 4 over the router's 128 give a held expert 48 rows and 192 places; a step's 16 x 4 give it half a row
     _assert_batched_products(text, 2 if program == "prefill" else 0, "32x192x4096xbf16", "32x192x2048xbf16")
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_256", "prefill_512", "prefill_1024"])
+def test_the_falcon_h1_programs_lower_for_tpu_at_published_width(program):
+    """``lm_decode`` (the recurrence) and ``lm_prefill`` (the chunked scan, each
+    bucket) of the ``falcon_h1`` decoder at Falcon-H1-34B's widths (hidden 5,120,
+    32 state-space heads of 128 with a state of 256 in 2 groups, 20 query and 4
+    key/value heads of 128, a SwiGLU of 21,504, vocabulary 261,120), the cell's
+    six blocks, 32 slots of 1,152 positions: shapes only, nothing is allocated."""
+    from pathway_tpu.models import falcon_h1
+
+    cfg = falcon_h1.FalconH1Config(num_hidden_layers=6)
+    params = falcon_h1.param_shapes(cfg)
+    state = jax.eval_shape(lambda: falcon_h1.init_state(cfg, 32, 1152))
+    assert state["ssm"][0].shape == (32, 32, 128, 256) and state["tail"][0].shape == (32, 3, 5120)
+    if program == "decode":
+        text = _export_tpu(functools.partial(falcon_h1.decode_logits, cfg=cfg), params, state,
+                           S((32,), jnp.bool_)).mlir_module()
+        assert "ssd_scan" not in text  # the step is the recurrence itself
+    else:
+        bucket = int(program.split("_")[1])
+        text = _export_tpu(functools.partial(falcon_h1.prefill_logits, cfg=cfg), params, state,
+                           S((bucket,), jnp.int32), S((), jnp.int32), S((), jnp.int32)).mlir_module()
+        chunks = bucket // 128
+        assert "ssd_scan" in text and f"tensor<{chunks}x2x16x128x128xf32>" in text  # the decays inside each chunk
+        assert f"tensor<{chunks}x2x16x128x256xf32>" in text  # a chunk's own state, a head: (d_head, state)
+    assert "ssm_op" in text and "attn_op" in text and "mlp_op" in text
+    assert "5120x261120xbf16" in text  # the untied head, whole
