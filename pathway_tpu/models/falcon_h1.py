@@ -19,10 +19,12 @@ device, in *slots* (``models/slot_decoder.py``, ``models/generation_service.py``
 - ``lm_decode``: one step over all slots, one greedy token a slot: the
   convolution over the slot's tail and the new input, **the recurrence itself**
   on the slot's state, attention over the cache. A slot that holds no request
-  writes no state. Written as LFM2's step is (all slots, ``where(active)``), so a
-  step reads and rewrites every slot's state-space state, live or not: the one
-  count both programs return (``COUNT_NAMES``: the (slot, layer) states a call
-  read and rewrote) says how many.
+  writes no state. The recurrence is one Pallas kernel a block (``ssm_step``)
+  that visits the live slots only and reads and writes each of their states
+  once, in place; the rest of the step is written as LFM2's is (all slots,
+  ``where(active)``). The one count both programs return (``COUNT_NAMES``: the
+  (slot, layer) states a call read and rewrote) is the live slots a block in a
+  step and the blocks in a prefill.
 
 **Three kinds of state live side by side in a slot**, for every layer: ``k``/``v``
 ``(slots, max_len, kv heads, head size)``, which grow with the context; ``ssm``
@@ -49,6 +51,8 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from pathway_tpu.models.lfm2 import _mm, _norm, _rope
 from pathway_tpu.models.mistral4 import _einsum
@@ -247,6 +251,68 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array, c: jax.Arr
     return y.reshape((-1,) + y.shape[2:])[:t], last
 
 
+def ssm_step(state: jax.Array, decay: jax.Array, dtx: jax.Array, b: jax.Array, c: jax.Array,
+             active: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One token of the recurrence for the live slots, as one Pallas kernel:
+    ``S' = decay S + dtx (outer) B``, ``y = S' C``, all float32. ``state``
+    (slots, heads, d_head, state) is rewritten in place (the caller donates it);
+    ``decay`` (slots, heads, 1), ``dtx`` (slots, heads, d_head), ``b`` and ``c``
+    (slots, heads, state) per head. Returns (the state, ``y`` (slots, heads,
+    d_head), 0 in a slot that is not live).
+
+    The grid walks the slots with the live ones first (their order and count
+    scalar-prefetched), a whole slot's state a place (blocks of 8 heads
+    measured slower on the chip, PR 36): it is fetched once,
+    advanced and read against ``C`` from the same copy in VMEM, and written
+    back once. Every place past the live count maps to the last live slot, so
+    the pipeline neither fetches nor writes back there and the body runs
+    nothing: a slot that holds no request is never touched. With no live slot
+    the first place copies slot ``order[0]`` through unchanged, so every block
+    the pipeline writes back is one the body wrote. Off the TPU it runs in the
+    Pallas interpreter."""
+    slots, heads, d_head, n = state.shape
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    n_live = jnp.sum(active, dtype=jnp.int32).reshape(1)
+
+    def kernel(order_ref, n_live_ref, decay_ref, dtx_ref, b_ref, c_ref, state_ref, out_ref, y_ref):
+        s = pl.program_id(0)
+
+        @pl.when(s < n_live_ref[0])
+        def _advance():
+            new = decay_ref[0][:, :, None] * state_ref[0] + dtx_ref[0][:, :, None] * b_ref[0][:, None, :]
+            out_ref[0] = new
+            y_ref[0] = jnp.sum(new * c_ref[0][:, None, :], axis=-1)
+
+        @pl.when((s == 0) & (n_live_ref[0] == 0))
+        def _copy_through():
+            out_ref[...] = state_ref[...]
+
+    def spec(*shape):  # one slot, whole
+        return pl.BlockSpec((1, heads) + shape,
+                            lambda s, order, n_live: (order[jnp.minimum(s, jnp.maximum(n_live[0], 1) - 1)], 0)
+                            + (0,) * len(shape))
+
+    # the state's block in and out, each double-buffered (16 MiB at 32 x 128 x 256), and room for the rest
+    block_bytes = heads * d_head * n * 4
+    new, y = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(slots,),
+            in_specs=[spec(1), spec(d_head), spec(n), spec(n), spec(d_head, n)],
+            out_specs=[spec(d_head, n), spec(d_head)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((slots, heads, d_head), jnp.float32)],
+        input_output_aliases={6: 0},  # the state: operand 6 counting the two prefetched
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             vmem_limit_bytes=4 * block_bytes + (4 << 20)),
+        interpret=jax.default_backend() != "tpu",
+        name="ssm_step",
+    )(order, n_live, decay, dtx, b, c, state)
+    return new, jnp.where(active[:, None, None], y, 0.0)
+
+
 def _mlp(p: Dict[str, jax.Array], u: jax.Array, cfg: FalconH1Config) -> jax.Array:
     with jax.named_scope("mlp_op"):
         gate_multiplier, down_multiplier = cfg.mlp_multipliers
@@ -325,7 +391,12 @@ def decode_logits(params: Dict[str, Any], state: Dict[str, Any], active: jax.Arr
     write_at = jnp.where(active, pos, max_len)  # past the end: dropped
     seen = jnp.arange(max_len)[None, :] <= pos[:, None]
     x = params["embed"][state["last"]].astype(jnp.float32) * cfg.embedding_multiplier
-    group = cfg.num_attention_heads // cfg.num_key_value_heads
+    group, heads = cfg.num_attention_heads // cfg.num_key_value_heads, cfg.mamba_n_heads
+    live = jnp.sum(active, dtype=jnp.int32)
+
+    def per_head(v):  # (slots, groups, state) as (slots, heads, state): a head's group's row
+        return jnp.repeat(v, heads // cfg.mamba_n_groups, axis=1)
+
     state = dict(state, **{name: list(state[name]) for name in ("k", "v", "ssm", "tail")})
     counts = jnp.zeros((len(COUNT_NAMES),), jnp.int32)
     for i, p in enumerate(params["layers"]):
@@ -337,17 +408,15 @@ def decode_logits(params: Dict[str, Any], state: Dict[str, Any], active: jax.Arr
             conv = jnp.einsum("bjc,cj->bc", window.astype(jnp.float32), p["conv_w"],
                               precision=jax.lax.Precision.HIGHEST) + p["conv_b"]
             xs, b, c = _split_xbc(jax.nn.silu(conv), cfg)
-            dt = _by_group(jax.nn.softplus(dt + p["dt_bias"]), cfg)
-            old = state["ssm"][i]
-            s = old.reshape((slots,) + xs.shape[1:] + (cfg.mamba_d_state,))  # (slots, groups, heads a group, d_head, state)
-            s = (jnp.exp(dt * _by_group(-jnp.exp(p["A_log"]), cfg))[..., None, None] * s
-                 + (dt[..., None] * xs)[..., None] * b[:, :, None, None, :])
-            y = jnp.sum(s * c[:, :, None, None, :], axis=-1) + _by_group(p["D"], cfg)[..., None] * xs
+            dt = _by_group(jax.nn.softplus(dt + p["dt_bias"]), cfg)  # (slots, groups, heads a group)
+            decay = jnp.exp(dt * _by_group(-jnp.exp(p["A_log"]), cfg)).reshape(slots, heads, 1)
+            dtx = (dt[..., None] * xs).reshape(slots, heads, cfg.mamba_d_head)
+            # only the live slots' states are read and rewritten, once each: the count says so
+            state["ssm"][i], y = ssm_step(state["ssm"][i], decay, dtx, per_head(b), per_head(c), active)
+            y = y.reshape(xs.shape) + _by_group(p["D"], cfg)[..., None] * xs
             mixed = cfg.ssm_out_multiplier * _ssm_out(p, y.reshape(slots, -1), z, cfg)
-            # every slot's state is read and rewritten, live or not: the count says so
-            state["ssm"][i] = jnp.where(active[:, None, None, None], s.reshape(old.shape), old)
             state["tail"][i] = jnp.where(active[:, None, None], window[:, 1:], tail)
-            counts = counts + slots
+            counts = counts + live
         with jax.named_scope("attn_op"):
             q, k, v = _qkv(p, cfg.attention_in_multiplier * h, pos, cfg)
             keys = state["k"][i] = state["k"][i].at[rows, write_at].set(k, mode="drop")

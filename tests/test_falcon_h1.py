@@ -143,12 +143,63 @@ def test_prefill_then_decode_through_the_state_gives_the_references_logits_at_ev
     assert np.std(want) > 0.1  # the logits spread: a wrong program would pick other tokens
     assert_close(served, want)
     assert_greedy(params, prompt, tokens)
-    # a prefill rewrites its slot's state in every block; a step as written every slot's
-    assert counts == [[3]] + [[4 * 3]] * 8
+    # a prefill rewrites its slot's state in every block, and so does a step with its one live slot
+    assert counts == [[3]] * 9
     # the slot's state after the eighth step is the reference's after the token that step fed
     _, before_last = reference(params, (prompt + tokens)[:-1])
     for got, want in zip(dec.state["ssm"], before_last):
         np.testing.assert_allclose(np.asarray(got[1]), want, atol=1e-5)
+
+
+STEP = jax.jit(falcon_h1.ssm_step)
+MASKS = {"none": [0, 0, 0, 0, 0], "all": [1, 1, 1, 1, 1], "scattered": [0, 1, 0, 1, 1], "first_only": [1, 0, 0, 0, 0],
+         "last_only": [0, 0, 0, 0, 1]}
+
+
+def parent_recurrence(state, dt, a, xs, b, c, active):
+    """The step's state-space update as the parent wrote it in XLA: every slot
+    advanced, ``y`` of every slot, the state kept by ``where(active)``."""
+    slots, n = state.shape[0], state.shape[-1]
+    s = state.reshape((slots,) + xs.shape[1:] + (n,))  # (slots, groups, heads a group, d_head, state)
+    s = jnp.exp(dt * a)[..., None, None] * s + (dt[..., None] * xs)[..., None] * b[:, :, None, None, :]
+    y = jnp.sum(s * c[:, :, None, None, :], axis=-1)
+    return jnp.where(active[:, None, None, None], s.reshape(state.shape), state), y
+
+
+@pytest.mark.parametrize("mask", sorted(MASKS))
+@pytest.mark.parametrize("groups,heads,d_head,n", [(2, 4, 16, 16), (2, 16, 16, 128)],
+                         ids=["tiny", "sixteen_heads_of_a_lane_wide_state"])
+def test_the_step_kernel_equals_the_parents_recurrence_and_leaves_other_slots_bit_identical(groups, heads, d_head, n,
+                                                                                           mask):
+    rng = np.random.default_rng(heads + len(mask))
+    slots, hpg = 5, heads // groups
+    state = rng.normal(size=(slots, heads, d_head, n)).astype(np.float32)
+    xs = rng.normal(size=(slots, groups, hpg, d_head)).astype(np.float32)
+    dt = np.log1p(np.exp(rng.normal(size=(slots, groups, hpg)))).astype(np.float32)
+    a = -np.exp(rng.uniform(0.0, 2.5, size=(groups, hpg))).astype(np.float32)
+    b, c = (rng.normal(size=(slots, groups, n)).astype(np.float32) for _ in range(2))
+    active = np.array(MASKS[mask], bool)
+    want_state, want_y = map(np.asarray, parent_recurrence(state, dt, a, xs, b, c, active))
+    # laid out as decode_logits lays them out: per head, B and C repeated over a group's heads
+    got_state, got_y = map(np.asarray, STEP(
+        jnp.asarray(state), jnp.exp(dt * a).reshape(slots, heads, 1), (dt[..., None] * xs).reshape(slots, heads, d_head),
+        jnp.repeat(b, hpg, axis=1), jnp.repeat(c, hpg, axis=1), jnp.asarray(active)))
+    np.testing.assert_allclose(got_state[active], want_state[active], rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(got_y[active], want_y.reshape(slots, heads, d_head)[active], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got_state[~active].view(np.int32), state[~active].view(np.int32))
+    assert not got_y[~active].any()
+
+
+def test_a_step_counts_the_live_slots_states_and_a_prefill_its_own(params):
+    dec = decoder(params)
+    state = dec.state
+    for slot, prompt in ((0, prompt_of(5, 70)), (2, prompt_of(9, 71)), (3, prompt_of(3, 72))):
+        ids = jnp.asarray(prompt + [0] * (16 - len(prompt)), jnp.int32)
+        state, _, counts = falcon_h1.lm_prefill(dec.params, state, ids, jnp.int32(len(prompt)), jnp.int32(slot), cfg=CFG)
+        assert np.asarray(counts).tolist() == [3]
+    for mask in ([0, 0, 0, 0], [0, 0, 1, 0], [1, 0, 1, 1]):
+        state, _, counts = falcon_h1.lm_decode(dec.params, state, jnp.asarray(mask, bool), cfg=CFG)
+        assert np.asarray(counts).tolist() == [sum(mask) * 3]
 
 
 def token_recurrence(x, dt, a, b, c):
@@ -244,7 +295,7 @@ def test_a_slot_freed_and_refilled_and_a_burst_of_twice_the_slots_equal_one_at_a
     for prompt, got in zip(prompts, burst):
         assert_greedy(params, prompt, got)
     assert stats["lm_prefill_calls"] == 8 and stats["lm_decode_rows"] == 8 * 5
-    assert stats["lm_prefill_state_rows"] == 8 * 3 and stats["lm_state_rows"] == stats["lm_decode_steps"] * 4 * 3
+    assert stats["lm_prefill_state_rows"] == 8 * 3 and stats["lm_state_rows"] == stats["lm_decode_rows"] * 3
 
 
 def last_logits(params, prompt, cfg):

@@ -336,12 +336,12 @@ def test_the_layers_whose_product_ran_batched_are_counted_for_prefills_and_stay_
     svc.close()
 
 
-def test_a_state_space_decoders_state_rows_are_summed_for_prefills_and_grow_by_slots_x_layers_a_step():
+def test_a_state_space_decoders_state_rows_are_summed_for_prefills_and_grow_by_live_rows_x_layers_a_step():
     """The third decoder names one count, ``state_rows``: the (slot, layer)
     state-space states a call read and rewrote. The service sums it as it sums
     any count: a prefill's as ``lm_prefill_state_rows`` (one a layer), a step's
-    as ``lm_state_rows`` (as the step is written, every slot's in every layer,
-    live or not), the latter also among the ``lm.*`` stage counters."""
+    as ``lm_state_rows`` (the live slots' in every layer: the step's kernel
+    moves no other), the latter also among the ``lm.*`` stage counters."""
     from pathway_tpu.engine import telemetry
 
     from . import test_falcon_h1 as fh
@@ -354,7 +354,7 @@ def test_a_state_space_decoders_state_rows_are_summed_for_prefills_and_grow_by_s
     assert all(len(f.result(timeout=120)) == 4 for f in futures)
     st = svc.stats()
     assert st["lm_prefill_calls"] == 2 and st["lm_prefill_state_rows"] == 2 * 3
-    assert st["lm_decode_steps"] >= 3 and st["lm_state_rows"] == st["lm_decode_steps"] * 4 * 3
+    assert st["lm_decode_steps"] >= 3 and st["lm_state_rows"] == st["lm_decode_rows"] * 3
     grew = {k: v - before.get(k, 0.0) for k, v in telemetry.stage_snapshot("lm.").items()}
     assert grew["lm.state_rows"] == float(st["lm_state_rows"])
     svc.close()

@@ -143,13 +143,17 @@ def test_the_mistral4_programs_lower_for_tpu_at_published_width(program):
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_256", "prefill_512", "prefill_1024"])
-def test_the_falcon_h1_programs_lower_for_tpu_at_published_width(program):
+def test_the_falcon_h1_programs_lower_for_tpu_at_published_width(program, monkeypatch):
     """``lm_decode`` (the recurrence) and ``lm_prefill`` (the chunked scan, each
     bucket) of the ``falcon_h1`` decoder at Falcon-H1-34B's widths (hidden 5,120,
     32 state-space heads of 128 with a state of 256 in 2 groups, 20 query and 4
     key/value heads of 128, a SwiGLU of 21,504, vocabulary 261,120), the cell's
-    six blocks, 32 slots of 1,152 positions: shapes only, nothing is allocated."""
+    six blocks, 32 slots of 1,152 positions: shapes only, nothing is allocated.
+    The step's recurrence is the ``ssm_step`` kernel of each block, as the chip
+    runs it (the backend the program asks for is the TPU here)."""
     from pathway_tpu.models import falcon_h1
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     cfg = falcon_h1.FalconH1Config(num_hidden_layers=6)
     params = falcon_h1.param_shapes(cfg)
@@ -159,6 +163,8 @@ def test_the_falcon_h1_programs_lower_for_tpu_at_published_width(program):
         text = _export_tpu(functools.partial(falcon_h1.decode_logits, cfg=cfg), params, state,
                            S((32,), jnp.bool_)).mlir_module()
         assert "ssd_scan" not in text  # the step is the recurrence itself
+        assert text.count("tpu_custom_call") == 6 and "ssm_step" in text  # in one kernel a block
+        assert "32x2x16x128x256xf32" not in text  # and not in XLA over every slot's state
     else:
         bucket = int(program.split("_")[1])
         text = _export_tpu(functools.partial(falcon_h1.prefill_logits, cfg=cfg), params, state,
