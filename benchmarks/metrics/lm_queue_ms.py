@@ -1,0 +1,13 @@
+"""Mean wait of a generated reply from its submission to the generation
+service until the loop moved it into a slot: growth of ``lm_queue_s`` over
+growth of ``lm_requests_done``, in ms, over every reply resolved in the window.
+None where the program has no such counters or resolved no request."""
+
+from metrics import _lm
+
+
+def read(ctx):
+    seconds, done = _lm.grew(ctx, "lm_queue_s"), _lm.grew(ctx, "lm_requests_done")
+    if seconds is None or not done:
+        return None
+    return 1e3 * seconds / done

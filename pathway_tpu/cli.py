@@ -283,10 +283,18 @@ def analyze(fmt, strict, runtime, program, arguments):
     show_default=True,
     help="max traces to render when --trace-id is not given",
 )
+@click.option(
+    "--spans",
+    "spans_path",
+    type=click.Path(exists=True, dir_okay=False),
+    default=None,
+    help="with a profiler directory: a trace-rank-N.jsonl of the same run, whose "
+    "rest spans split the idle by whether a request was in flight",
+)
 @click.argument(
     "directory", type=click.Path(exists=True, file_okay=False)
 )
-def trace(trace_id, limit, directory):
+def trace(trace_id, limit, spans_path, directory):
     """Merge per-rank trace files into causally-ordered trees.
 
     DIRECTORY is a supervise/flight dir holding ``trace-rank-N.jsonl``
@@ -300,7 +308,9 @@ def trace(trace_id, limit, directory):
     A DIRECTORY that a ``jax.profiler`` session wrote (it holds
     ``plugins/profile/<time>/*.xplane.pb``) is read as a device trace
     instead: for each ``pw.<kind>`` span the host had open, the seconds the
-    device sat idle under it."""
+    device sat idle under it. With ``--spans``, that idle splits into what
+    fell while a ``rest`` span of that file was open (a request in flight)
+    and what fell while none was (nothing to do)."""
     import glob
 
     from pathway_tpu.engine.tracing import (
@@ -310,13 +320,15 @@ def trace(trace_id, limit, directory):
         format_trace_tree,
         idle_by_span,
         load_profile_events,
+        load_trace_file,
         merge_trace_files,
     )
 
     profile = find_profile(directory)
     if profile is not None:
         click.echo(f"profile {profile}")
-        for line in format_idle_by_span(idle_by_span(load_profile_events(profile))):
+        spans = load_trace_file(spans_path)[1] if spans_path else None
+        for line in format_idle_by_span(idle_by_span(load_profile_events(profile), spans)):
             click.echo(line)
         return
     paths = sorted(glob.glob(os.path.join(directory, "trace-rank-*.jsonl")))

@@ -151,10 +151,13 @@ TRACE_SPAN_KINDS: "frozenset[str]" = frozenset({
     "exchange",      # mesh delta receive (links the sender's commit span)
     "fused_region",  # one fused chain executed as a single program
     "generate",      # chat UDF: a prompt's submission to its tokens resolved
+    "lm.decode",     # generation service: a request's first token read to its last (synthesized)
     "lm.decode_step",  # generation service: one step over the slots (links its requests)
     "lm.decode_step.device_wait",  # ... the fetch of the step's tokens
+    "lm.first_token",  # generation service: a request's slot to its first token read (synthesized)
     "lm.prefill",    # generation service: one prompt into one slot
     "lm.prefill.device_wait",  # ... the fetch of the first token
+    "lm.queue",      # generation service: a request's submission to its slot (synthesized)
     "loop_wait",     # commit loop between commits, waiting for a source's push
     "operator",      # one evaluator run (synthesized from CommitProfile ops)
     "queue",         # a query row's wait: source.push to its commit's start

@@ -989,6 +989,10 @@ ProfileEvent = Tuple[str, str, str, int, int]
 _ANNOTATION_PREFIX = "pw."
 _HOST_PLANE = "/host:CPU"
 _DEVICE_PLANE_PREFIX = "/device:"
+_TASK_PLANE = "Task Environment"
+#: where idle under no annotation goes once ring spans say when a request was in flight
+IDLE_IN_FLIGHT = "none (request in flight)"
+IDLE_NO_REQUEST = "none (no request in flight)"
 
 
 def find_profile(directory: str) -> Optional[str]:
@@ -1007,11 +1011,19 @@ def find_profile(directory: str) -> Optional[str]:
 def load_profile_events(path: str) -> List[ProfileEvent]:
     """The events ``idle_by_span`` reads from one ``.xplane.pb``: every event
     of the device planes, and this module's annotations (``pw.<kind>``) of the
-    host plane. Nothing but ``jax.profiler.ProfileData``."""
+    host plane. Nothing but ``jax.profiler.ProfileData``. The file holds its
+    events' starts relative to the session's start, which its ``Task
+    Environment`` plane gives in wall-clock nanoseconds; the events come back
+    on that clock, the one a ring span's ``ts`` is stamped on."""
     from jax.profiler import ProfileData
 
+    data = ProfileData.from_file(path)
+    origin = 0
+    for plane in data.planes:
+        if plane.name == _TASK_PLANE:
+            origin = int(dict(plane.stats).get("profile_start_time", 0))
     events: List[ProfileEvent] = []
-    for plane in ProfileData.from_file(path).planes:
+    for plane in data.planes:
         host = plane.name == _HOST_PLANE
         if not host and not plane.name.startswith(_DEVICE_PLANE_PREFIX):
             continue
@@ -1022,7 +1034,7 @@ def load_profile_events(path: str) -> List[ProfileEvent]:
                 if host and not ev.name.startswith(_ANNOTATION_PREFIX):
                     continue
                 events.append(
-                    (plane.name, line_name, ev.name, int(ev.start_ns),
+                    (plane.name, line_name, ev.name, origin + int(ev.start_ns),
                      int(ev.duration_ns))
                 )
     return events
@@ -1058,7 +1070,35 @@ def _innermost_segments(
     return segments
 
 
-def idle_by_span(events: List[ProfileEvent]) -> Dict[str, Any]:
+def _in_flight(spans: Iterable[Dict[str, Any]]) -> List[Tuple[int, int]]:
+    """The instants, in wall-clock nanoseconds, at which a ``rest`` span of the
+    ring was open: disjoint, sorted intervals."""
+    merged: List[Tuple[int, int]] = []
+    for lo, hi in sorted(
+        (int(s["ts"] * 1e9), int((s["ts"] + s["duration_s"]) * 1e9))
+        for s in spans if s.get("kind") == "rest"
+    ):
+        if merged and lo <= merged[-1][1]:
+            merged[-1] = (merged[-1][0], max(merged[-1][1], hi))
+        else:
+            merged.append((lo, hi))
+    return merged
+
+
+def _covered_ns(intervals: List[Tuple[int, int]], lo: int, hi: int) -> int:
+    """Nanoseconds of ``[lo, hi)`` inside the disjoint, sorted ``intervals``."""
+    import bisect
+
+    i, covered = max(bisect.bisect_right(intervals, (lo, float("inf"))) - 1, 0), 0
+    while i < len(intervals) and intervals[i][0] < hi:
+        covered += max(0, min(intervals[i][1], hi) - max(intervals[i][0], lo))
+        i += 1
+    return covered
+
+
+def idle_by_span(
+    events: List[ProfileEvent], spans: Optional[List[Dict[str, Any]]] = None
+) -> Dict[str, Any]:
     """Where the device's idle time fell, by what the host was doing.
 
     Per device plane, busy is the union of its ``XLA Ops`` events (``XLA
@@ -1069,7 +1109,15 @@ def idle_by_span(events: List[ProfileEvent]) -> Dict[str, Any]:
     {"idle_s", "open_s"}}}``: idle seconds under each kind, averaged over the
     device planes, beside the seconds that kind was the innermost one. A kind
     ending in ``.device_wait`` is the host waiting for the chip; idle under
-    any other kind, or under none, is the chip waiting for the host."""
+    any other kind, or under none, is the chip waiting for the host.
+
+    ``spans`` (the ring's spans, as ``trace-rank-N.jsonl`` holds them) say
+    when a request was in flight: while a ``rest`` span was open, on its
+    ``ts``, the clock ``load_profile_events`` puts the events on. Each kind
+    then also has ``in_flight_s``, its idle seconds that some request waited
+    through, and ``none`` splits into ``IDLE_IN_FLIGHT`` and
+    ``IDLE_NO_REQUEST``; the rest of the idle had nothing to do."""
+    flight = _in_flight(spans) if spans is not None else None
     annotations = [
         (start, start + dur, name[len(_ANNOTATION_PREFIX):])
         for plane, _line, name, start, dur in events
@@ -1078,8 +1126,9 @@ def idle_by_span(events: List[ProfileEvent]) -> Dict[str, Any]:
     ]
     segments = _innermost_segments(annotations)
     kinds: Dict[str, Dict[str, float]] = {}
+    blank = {"idle_s": 0.0, "open_s": 0.0, **({"in_flight_s": 0.0} if flight is not None else {})}
     for lo, hi, name in segments:
-        row = kinds.setdefault(name, {"idle_s": 0.0, "open_s": 0.0})
+        row = kinds.setdefault(name, dict(blank))
         row["open_s"] += (hi - lo) / 1e9
     planes = sorted(
         {e[0] for e in events if e[0].startswith(_DEVICE_PLANE_PREFIX)}
@@ -1109,12 +1158,21 @@ def idle_by_span(events: List[ProfileEvent]) -> Dict[str, Any]:
                 else:
                     upto = min(segments[i][0], gap_hi) if i < len(segments) else gap_hi
                     name = "none"
-                row = kinds.setdefault(name, {"idle_s": 0.0, "open_s": 0.0})
-                row["idle_s"] += (upto - at) / 1e9
+                waited = _covered_ns(flight, at, upto) if flight is not None else 0
+                pieces = [(name, upto - at, waited)]
+                if name == "none" and flight is not None:
+                    pieces = [(IDLE_IN_FLIGHT, waited, waited), (IDLE_NO_REQUEST, upto - at - waited, 0)]
+                for name, ns, in_flight in pieces:
+                    row = kinds.setdefault(name, dict(blank))
+                    row["idle_s"] += ns / 1e9
+                    if flight is not None:
+                        row["in_flight_s"] += in_flight / 1e9
                 at = upto
     n = max(len(planes), 1)
     for row in kinds.values():
-        row["idle_s"] /= n
+        for key in ("idle_s", "in_flight_s"):
+            if key in row:
+                row[key] /= n
     return {"idle_s": idle_total / n, "planes": len(planes), "kinds": kinds}
 
 
@@ -1136,7 +1194,7 @@ def format_idle_by_span(result: Dict[str, Any]) -> List[str]:
             f"{100.0 * row['idle_s'] / row['open_s']:.1f} %"
             if row["open_s"] else "-"
         )
-        if kind != "none":
+        if not kind.startswith("none"):
             named += row["idle_s"]
         lines.append(
             f"{kind:<26}{row['idle_s']:>10.4f}{share:>8.1f}%"
@@ -1151,5 +1209,16 @@ def format_idle_by_span(result: Dict[str, Any]) -> List[str]:
             f"named: {100.0 * named / idle:.1f} % of the idle seconds; host "
             f"waiting for the chip (*.device_wait): {100.0 * waits / idle:.1f} %, "
             f"chip waiting for the host: {100.0 * (idle - waits) / idle:.1f} %"
+        )
+    waited = {k: row["in_flight_s"] for k, row in result["kinds"].items() if "in_flight_s" in row}
+    if idle and waited:
+        by_kind = ", ".join(
+            f"{k} {100.0 * v / idle:.1f} %"
+            for k, v in sorted(waited.items(), key=lambda kv: -kv[1]) if v > 0.0005 * idle
+        )
+        lines.append(
+            f"with no request in flight (no rest span open): {100.0 * (idle - sum(waited.values())) / idle:.1f} % "
+            f"of the idle seconds; with a request in flight: {100.0 * sum(waited.values()) / idle:.1f} %, "
+            f"by innermost kind: {by_kind or '-'}"
         )
     return lines

@@ -51,20 +51,35 @@ request's ``generate`` span where the submitter had one) and one
 call it enqueues, with one ``.device_wait`` child around the fetch made inside
 it, which is the wait for the call *before* the span's own (nothing, for the
 first call after an idle loop). Counters: ``stats()`` and the ``lm.*`` stage
-counters on ``/metrics``, each moved when its call's result is read;
-``lm_calls_enqueued_ahead`` counts the calls enqueued while the call before
-them was still unread (all but the first after an idle loop). **What a call
-counts on the device is the decoder's to name**: it returns, beside its
+counters on ``/metrics``, each moved when its call's result is read. **What a
+call counts on the device is the decoder's to name**: it returns, beside its
 tokens, one array of as many numbers as its ``count_names`` has (experts
 touched, for one), and the service sums each under that name, a step's as
 ``lm_<name>`` and a prefill's as ``lm_prefill_<name>``: two transfers a call
 whatever the decoder counts, and no model's name here.
+
+**One request's timeline.** A request is stamped on ``time.monotonic`` where
+its work happens: ``submitted`` in ``submit`` (the caller's thread),
+``slotted`` when the loop moves it into a slot, ``first`` when its prefill's
+token is read on the host, and its last token's read. It also notes how many
+other prompts' prefills were enqueued between its own prefill and its last
+step: each stalled every live slot, its own among them. Once its future is
+resolved (the reply waits for none of this) the service adds the intervals
+between the stamps to sums over the requests done, in ``stats()``
+(``lm_requests_done``, ``lm_reply_tokens``, ``lm_queue_s``, ``lm_ttft_s``,
+``lm_decode_phase_s``, ``lm_prefills_in_decode``), and while something
+records and the request's context is sampled it synthesizes three children of
+that context from the same stamps: ``lm.queue`` (submitted to slotted),
+``lm.first_token`` (slotted to first) and ``lm.decode`` (first to last, with
+``tokens`` and ``prefills``). A request that fails or is cancelled is counted
+nowhere.
 """
 
 from __future__ import annotations
 
 import concurrent.futures
 import logging
+import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -80,7 +95,8 @@ _AHEAD = 1
 
 
 class _Request:
-    __slots__ = ("ids", "future", "ctx", "tokens", "given")
+    __slots__ = ("ids", "future", "ctx", "tokens", "given", "submitted", "slotted", "first",
+                 "prefills_at", "prefills_inside")
 
     def __init__(self, ids: List[int], ctx: Optional[_tracing.TraceContext]):
         self.ids = ids
@@ -88,23 +104,26 @@ class _Request:
         self.ctx = ctx
         self.tokens: List[int] = []  # read from the device
         self.given = 0  # produced by the calls enqueued so far, read or not
+        # its timeline on time.monotonic: submitted, moved into a slot, its first token read
+        self.submitted = time.monotonic()
+        self.slotted = self.first = 0.0
+        # the service's prefills enqueued once its own was; other prompts' until its last step
+        self.prefills_at = self.prefills_inside = 0
 
 
 class _Call:
     """A device call whose result the host has not read: its tokens (one a
     slot from a step, one alone from a prefill) and the decoder's counts, still on
     the device; the (slot, request) pairs the tokens belong to; the prompt's
-    (tokens, padded tokens) for a prefill, None for a step; whether the call
-    before it was unread when it was enqueued."""
+    (tokens, padded tokens) for a prefill, None for a step."""
 
-    __slots__ = ("tokens", "counts", "rows", "prompt", "ahead")
+    __slots__ = ("tokens", "counts", "rows", "prompt")
 
     def __init__(self, result: Tuple[Any, Any], rows: List[Tuple[int, _Request]],
-                 prompt: Optional[Tuple[int, int]], ahead: bool):
+                 prompt: Optional[Tuple[int, int]]):
         self.tokens, self.counts = result
         self.rows = rows
         self.prompt = prompt
-        self.ahead = ahead
 
 
 class GenerationService(DeviceWorker):
@@ -129,7 +148,12 @@ class GenerationService(DeviceWorker):
         self.prefill_padded_tokens = 0
         self.decode_steps = 0
         self.decode_rows = 0
-        self.calls_enqueued_ahead = 0
+        # sums over the requests resolved: their timelines' spans, tokens and prefills inside
+        self.requests_done = 0
+        self.reply_tokens = 0
+        self.queue_s = self.ttft_s = self.decode_phase_s = 0.0
+        self.prefills_in_decode = 0
+        self._prefills_enqueued = 0  # only the worker's thread reads or writes it
         # the decoder's own per-call counts, summed: a prefill's and a step's apart
         self.prefill_counts = np.zeros((len(decoder.count_names),), np.int64)
         self.decode_counts = np.zeros((len(decoder.count_names),), np.int64)
@@ -170,6 +194,7 @@ class GenerationService(DeviceWorker):
                     request = self._queue.popleft()
                     # from here on the future cannot be cancelled under the loop's feet
                     if request.future.set_running_or_notify_cancel():
+                        request.slotted = time.monotonic()
                         self._slots[slot] = held = request
                         filled.append(slot)
             return filled
@@ -180,11 +205,12 @@ class GenerationService(DeviceWorker):
         to each request (one given its last leaves its slot, which the next
         prefill may overwrite), then fetch the call before it (for the caller
         to hand out once its span is closed)."""
-        self._unread.append(_Call(result, rows, prompt, ahead=bool(self._unread)))
+        self._unread.append(_Call(result, rows, prompt))
         for slot, request in rows:
             request.given += 1
             if request.given >= self.decoder.max_new_tokens:
                 self._slots[slot] = None  # noqa: PWA103 (only the worker's thread reads or writes a slot)
+                request.prefills_inside = self._prefills_enqueued - request.prefills_at
         with _tracing.get_tracer().trace_span(wait_kind):
             return self._fetch(keep=_AHEAD)
 
@@ -209,7 +235,6 @@ class GenerationService(DeviceWorker):
         for slot, request in call.rows:
             request.tokens.append(int(tokens[slot]))
         with self._cond:
-            self.calls_enqueued_ahead += call.ahead
             if call.prompt is not None:
                 n, padded = call.prompt
                 self.prefill_calls += 1
@@ -224,19 +249,52 @@ class GenerationService(DeviceWorker):
                 self.decode_counts += counts
                 stage = {"lm.decode_steps": 1.0, "lm.decode_rows": float(len(call.rows))}
                 stage.update(("lm." + name, float(n)) for name, n in zip(self.decoder.count_names, counts))
-            stage["lm.calls_enqueued_ahead"] = float(call.ahead)
         telemetry.stage_add_many(stage)
-        want = self.decoder.max_new_tokens
-        for _, request in call.rows:
-            if len(request.tokens) >= want:
-                request.future.set_result(request.tokens)
+        done = [request for _, request in call.rows if len(request.tokens) >= self.decoder.max_new_tokens]
+        if call.prompt is None and not done:
+            return
+        now = time.monotonic()
+        if call.prompt is not None:
+            call.rows[0][1].first = now
+        for request in done:
+            request.future.set_result(request.tokens)
+        if done:
+            self._account(done, now)
+
+    def _account(self, done: List[_Request], last: float) -> None:
+        """Add the timelines of requests just resolved (their last token read at
+        ``last``) to the sums, and give each sampled one its three spans."""
+        with self._cond:
+            self.requests_done += len(done)
+            for r in done:
+                self.reply_tokens += len(r.tokens)
+                self.queue_s += r.slotted - r.submitted
+                self.ttft_s += r.first - r.submitted
+                self.decode_phase_s += last - r.first
+                self.prefills_in_decode += r.prefills_inside
+        tracer = _tracing.get_tracer()
+        if not tracer.recording():
+            return
+        wall = time.time() - time.monotonic()  # the ring's spans start on the wall clock
+        for r in done:
+            if r.ctx is None or not r.ctx.sampled:
+                continue
+            for kind, lo, hi, attrs in (
+                    ("lm.queue", r.submitted, r.slotted, None),
+                    ("lm.first_token", r.slotted, r.first, None),
+                    ("lm.decode", r.first, last, {"tokens": len(r.tokens), "prefills": r.prefills_inside})):
+                tracer.record_span(kind, kind, parent=r.ctx, ts=lo + wall, ts_mono=lo, duration_s=hi - lo,
+                                   attrs=attrs)
 
     def _prefill(self, slot: int) -> None:
         request = self._slots[slot]
         n = len(request.ids)
         with _tracing.get_tracer().trace_span("lm.prefill", ctx=request.ctx, attrs={"slot": slot, "tokens": n}):
-            fetched = self._enqueued(self.decoder.prefill(slot, request.ids), [(slot, request)],
-                                     "lm.prefill.device_wait", prompt=(n, self.decoder.bucket_of(n)))
+            result = self.decoder.prefill(slot, request.ids)
+            self._prefills_enqueued += 1
+            request.prefills_at = self._prefills_enqueued
+            fetched = self._enqueued(result, [(slot, request)], "lm.prefill.device_wait",
+                                     prompt=(n, self.decoder.bucket_of(n)))
         self._hand_out(fetched)
 
     def _decode_step(self) -> None:
@@ -288,7 +346,12 @@ class GenerationService(DeviceWorker):
                 "lm_decode_steps": self.decode_steps,
                 "lm_decode_rows": self.decode_rows,
                 **{"lm_" + name: int(n) for name, n in zip(names, self.decode_counts)},
-                "lm_calls_enqueued_ahead": self.calls_enqueued_ahead,
+                "lm_requests_done": self.requests_done,
+                "lm_reply_tokens": self.reply_tokens,
+                "lm_queue_s": self.queue_s,
+                "lm_ttft_s": self.ttft_s,
+                "lm_decode_phase_s": self.decode_phase_s,
+                "lm_prefills_in_decode": self.prefills_in_decode,
                 "lm_slots": len(self._slots),
                 "lm_compiled_programs": self.decoder.compiled_programs(),
             }

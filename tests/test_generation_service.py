@@ -1,8 +1,9 @@
 """The slot-based generation service (``models/generation_service.py``): its
 loop over a fake decoder (admission, the prompt's crop, draining, a failed
-device call; one call enqueued ahead of the one whose result is read) and over
-the tiny ``lfm2_moe`` decoder of ``test_lfm2.py``, where requests that arrive
-at any time, between any two steps, each get the tokens they get alone."""
+device call; one call enqueued ahead of the one whose result is read; each
+request's timeline, summed and as spans) and over the tiny ``lfm2_moe`` decoder
+of ``test_lfm2.py``, where requests that arrive at any time, between any two
+steps, each get the tokens they get alone."""
 
 import threading
 import time
@@ -82,9 +83,10 @@ class FakeDecoder:
 
 def gated(decoder_):
     """Hold the decoder's first prefill until the returned event is set, so that
-    everything submitted meanwhile waits in the queue."""
-    gate, prefill = threading.Event(), decoder_.prefill
-    decoder_.prefill = lambda slot, ids: (gate.wait(10), prefill(slot, ids))[1]
+    everything submitted meanwhile waits in the queue; ``decoder_.entered`` is
+    set once the worker waits there."""
+    gate, prefill, decoder_.entered = threading.Event(), decoder_.prefill, threading.Event()
+    decoder_.prefill = lambda slot, ids: (decoder_.entered.set(), gate.wait(10), prefill(slot, ids))[2]
     return gate
 
 
@@ -190,7 +192,7 @@ def test_a_call_is_enqueued_before_the_call_before_it_is_read_and_never_two_befo
         assert n < 2 or reads[n - 2][-1] < at[n]  # and only one: the one before that had been read
     st = svc.stats()
     assert st["lm_prefill_calls"] + st["lm_decode_steps"] == len(calls)
-    assert st["lm_calls_enqueued_ahead"] == len(calls) - 1  # all but the first call after the idle loop
+    assert st["lm_requests_done"] == requests and st["lm_reply_tokens"] == requests * new
 
 
 def test_an_idle_loop_starts_again_with_nothing_ahead():
@@ -198,8 +200,16 @@ def test_an_idle_loop_starts_again_with_nothing_ahead():
     for i in range(3):  # one at a time: each finds the loop idle and every result read
         assert svc.submit([i]).result(timeout=10) == [i + 1 + j for j in range(4)]
     st = svc.stats()
-    assert st["lm_prefill_calls"] + st["lm_decode_steps"] == 12 and st["lm_calls_enqueued_ahead"] == 9
+    assert st["lm_prefill_calls"] + st["lm_decode_steps"] == 12
     svc.close()
+    log, calls = svc.decoder.log, svc.decoder.calls
+    at = [i for i, e in enumerate(log) if e[0] != "read"]
+    for n, call in enumerate(calls):
+        if call[0] == "prefill":  # the first call after an idle loop: every call before it had been read
+            assert all(log.index(("read", m)) < at[n] for m in range(n))
+        else:  # a step: enqueued while the call before it was unread
+            assert at[n] < log.index(("read", n - 1))
+    assert sum(call[0] == "prefill" for call in calls) == 3
 
 
 def test_a_slot_refilled_while_its_last_token_is_unread_gives_both_requests_their_tokens():
@@ -243,6 +253,9 @@ def test_one_new_token_resolves_with_no_decode_step():
     assert [f.result(timeout=10) for f in futures] == [[10 * i + 1] for i in range(5)]
     st = svc.stats()
     assert st["lm_prefill_calls"] == 5 and st["lm_decode_steps"] == 0
+    # the first token is the last: no decode phase, no prefill inside a reply
+    assert st["lm_requests_done"] == st["lm_reply_tokens"] == 5
+    assert st["lm_decode_phase_s"] == 0.0 and st["lm_prefills_in_decode"] == 0 and st["lm_ttft_s"] > 0.0
     assert all(call[0] == "prefill" for call in svc.decoder.calls)
     svc.close()
 
@@ -276,6 +289,124 @@ def test_a_cancelled_submission_takes_no_slot():
     assert [f.result(timeout=10)[0] for f in running] == [1, 2, 3]
     svc.close(timeout_s=10)
     assert not any(call[0] == "prefill" and call[2] == [90] for call in decoder_.calls)
+
+
+def run_schedule(slots, new, later, ctx=lambda: None):
+    """One request, then ``later`` more once the worker holds the first's
+    prefill: the first pass admits the first alone, the next passes the others
+    into whichever slots are free, in order."""
+    decoder_ = FakeDecoder(slots=slots, new=new)
+    gate = gated(decoder_)
+    svc = GenerationService(decoder_)
+    futures = [svc.submit([0], ctx=ctx())]
+    assert decoder_.entered.wait(10)
+    futures += [svc.submit([10 * (i + 1)], ctx=ctx()) for i in range(later)]
+    gate.set()
+    assert [f.result(timeout=10) for f in futures] == [[10 * i + 1 + j for j in range(new)] for i in range(later + 1)]
+    svc.close()
+    return svc.stats()
+
+
+@pytest.mark.parametrize("slots,new,later,prefills", [
+    (1, 4, 3, 0),  # one slot: a prompt is prefilled only after the reply before it has had its last step
+    # two slots: B is prefilled after A's first step (A's reply holds it), C when A leaves (B's holds it)
+    (2, 4, 2, 2),
+    (3, 4, 2, 3),  # the first pass admits A alone, the second B and C: both inside A's reply, C's inside B's
+    (2, 1, 3, 0),  # one token a reply: its prefill is its last call
+])
+def test_a_schedule_whose_interleaving_is_fixed_counts_its_replies_exactly(slots, new, later, prefills):
+    st = run_schedule(slots, new, later)
+    assert st["lm_requests_done"] == later + 1 and st["lm_reply_tokens"] == (later + 1) * new
+    assert st["lm_prefills_in_decode"] == prefills
+    assert min(st["lm_queue_s"], st["lm_ttft_s"], st["lm_decode_phase_s"]) >= 0.0
+    assert st["lm_queue_s"] <= st["lm_ttft_s"] and (st["lm_decode_phase_s"] == 0.0) == (new == 1)
+
+
+@pytest.fixture
+def recording(monkeypatch):
+    """The process-wide tracer records every span while the test runs."""
+    from pathway_tpu.engine import tracing
+
+    monkeypatch.setenv("PATHWAY_TRACE", "on")
+    monkeypatch.setenv("PATHWAY_TRACE_SAMPLE", "1.0")
+    tracing.reset_tracing()
+    tracer = tracing.get_tracer()
+    yield tracer
+    monkeypatch.delenv("PATHWAY_TRACE")
+    tracing.reset_tracing()
+
+
+def test_each_reply_gets_three_spans_parented_to_its_context_and_they_add_up_to_the_sums(recording):
+    from pathway_tpu.engine import tracing
+
+    contexts = []
+    unsampled = tracing.new_trace_context(sampled=False)
+
+    def ctx():  # the fourth request's context is not sampled: it gets no span, and is counted all the same
+        contexts.append(tracing.new_trace_context(sampled=True) if len(contexts) < 3 else unsampled)
+        return contexts[-1]
+
+    before = time.monotonic()
+    st = run_schedule(2, 4, 3, ctx)
+    after = time.monotonic()
+    spans = [s for s in recording.recent_spans(limit=1 << 20) if s["kind"] in ("lm.queue", "lm.first_token", "lm.decode")]
+    assert len(spans) == 9 and st["lm_requests_done"] == 4
+    end = lambda s: s["ts_mono"] + s["duration_s"]
+    totals, inside = [], []
+    for c in contexts[:3]:
+        [queue], [first], [decode] = ([s for s in spans if s["parent_id"] == c.span_id and s["kind"] == k]
+                                      for k in ("lm.queue", "lm.first_token", "lm.decode"))
+        assert all(s["trace_id"] == c.trace_id for s in (queue, first, decode))
+        assert before <= queue["ts_mono"] and end(decode) <= after
+        assert end(queue) == pytest.approx(first["ts_mono"], abs=1e-9)
+        assert end(first) == pytest.approx(decode["ts_mono"], abs=1e-9)
+        assert min(s["duration_s"] for s in (queue, first, decode)) >= 0.0
+        assert decode["attrs"]["tokens"] == 4 and first["attrs"] == queue["attrs"] == {}
+        # the wall stamp is the monotonic one moved by one offset: the three stay end to end on it
+        assert queue["ts"] + queue["duration_s"] == pytest.approx(first["ts"], abs=1e-6)
+        totals.append(end(decode) - queue["ts_mono"])
+        inside.append(decode["attrs"]["prefills"])
+    # two slots: B is prefilled inside A's reply, C (once A left) inside B's, D (once B left) inside C's
+    assert inside == [1, 1, 1]
+    assert st["lm_prefills_in_decode"] == 3  # and D, the last, holds none
+    # the sums are the same stamps: ttft and the decode phase make up each reply's whole time (D's
+    # own, which has no span, lies inside the test's)
+    unseen = st["lm_ttft_s"] + st["lm_decode_phase_s"] - sum(totals)
+    assert -1e-9 <= unseen <= after - before
+
+
+def test_no_span_is_built_while_nothing_records(monkeypatch):
+    from pathway_tpu.engine import tracing
+
+    monkeypatch.delenv("PATHWAY_TRACE", raising=False)
+    tracing.reset_tracing()
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("a span was built while nothing records")
+
+    monkeypatch.setattr(tracing, "Span", refuse)
+    st = run_schedule(2, 4, 2, lambda: tracing.new_trace_context(sampled=True))
+    assert st["lm_requests_done"] == 3 and st["lm_prefills_in_decode"] == 2
+    assert tracing.get_tracer().recent_spans() == []
+
+
+def test_a_cancelled_submission_and_a_failed_call_count_nothing():
+    decoder_ = FakeDecoder(fail_on_step=2)
+    gate = gated(decoder_)
+    svc = GenerationService(decoder_)
+    failing = [svc.submit([i]) for i in range(3)]  # all three hold a slot by the failing step
+    time.sleep(0.1)
+    cancelled = svc.submit([90])
+    assert cancelled.cancel()
+    gate.set()
+    assert all(isinstance(f.exception(timeout=10), RuntimeError) for f in failing)
+    st = svc.stats()
+    assert (st["lm_requests_done"], st["lm_reply_tokens"], st["lm_prefills_in_decode"]) == (0, 0, 0)
+    assert st["lm_queue_s"] == st["lm_ttft_s"] == st["lm_decode_phase_s"] == 0.0
+    assert svc.submit([50]).result(timeout=10) == [51, 52, 53, 54]
+    st = svc.stats()
+    assert (st["lm_requests_done"], st["lm_reply_tokens"], st["lm_prefills_in_decode"]) == (1, 4, 0)
+    svc.close()
 
 
 @pytest.mark.parametrize("slots,gap_s", [(3, 0.05), (1, 0.0)])

@@ -198,3 +198,95 @@ def test_device_idle_is_split_over_the_innermost_host_span():
     assert {k: round(v["idle_s"] * 1e3, 6) for k, v in both["kinds"].items()} == {
         "embed_wait": 8.0, "tokenize": 2.0}
     assert tracing.idle_by_span([])["idle_s"] == 0.0
+
+
+def test_a_ring_span_and_its_annotation_start_on_one_clock(tmp_path, monkeypatch):
+    """A real ``jax.profiler`` session: the ``pw.<kind>`` annotation of a
+    synchronous span, as ``load_profile_events`` reads it back, starts within
+    half a millisecond of the ring span's ``ts`` (the median of 21: a thread
+    can lose the interpreter or the core between the two stamps, which is no
+    fault of the clocks), never before it, and a ``start``/``finish`` span
+    opened between two of them lies between them on that clock."""
+    import statistics
+    import time
+
+    import jax
+
+    monkeypatch.delenv("PATHWAY_TRACE", raising=False)
+    inst = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(21):
+            with inst.trace_span("commit", attrs={"commit": i}):
+                time.sleep(0.001)
+        rest = inst.start("rest", "POST /v2/answer")
+        time.sleep(0.002)
+        inst.finish(rest)
+        with inst.trace_span("reply"):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    ring = inst.recent_spans()
+    assert [s["kind"] for s in ring] == ["commit"] * 21 + ["rest", "reply"]
+    events = sorted(tracing.load_profile_events(tracing.find_profile(str(tmp_path))), key=lambda e: e[3])
+    assert [e[2] for e in events] == ["pw.commit"] * 21 + ["pw.reply"]  # `rest` is never an annotation
+    lead = [e[3] - s["ts"] * 1e9 for e, s in zip(events, ring[:21] + ring[-1:])]
+    assert statistics.median(abs(ns) for ns in lead) < 0.5e6 and min(lead) > -0.5e6, lead
+    rest = ring[21]
+    assert events[20][3] + events[20][4] <= rest["ts"] * 1e9 + 0.5e6
+    assert (rest["ts"] + rest["duration_s"]) * 1e9 <= events[21][3] + 0.5e6
+
+
+def _rest(start_ms, dur_ms, kind="rest"):
+    return {"kind": kind, "ts": start_ms / 1e3, "duration_s": dur_ms / 1e3}
+
+
+def test_idle_splits_by_whether_a_request_was_in_flight():
+    """``HAND_MADE``'s idle against requests in flight 25..55 and 90..95 ms (a
+    ``generate`` span over all of it is no request): of the 40 ms under no
+    annotation (45..50, 55..70, 80..100) 5 + 5 ms were waited through and 30
+    had nothing to do; of `commit`'s 17 ms (18..20, 30..45) 15 were waited
+    through. Without spans nothing changes."""
+    spans = [_rest(25, 30), _rest(90, 5), _rest(91, 2), _rest(0, 200, kind="generate")]
+    result = tracing.idle_by_span(HAND_MADE, spans)
+    idle = {kind: round(row["idle_s"] * 1e3, 6) for kind, row in result["kinds"].items()}
+    assert idle == {"search": 3.0, "search.device_wait": 5.0, "commit": 17.0, "encode": 10.0,
+                    tracing.IDLE_IN_FLIGHT: 10.0, tracing.IDLE_NO_REQUEST: 30.0}
+    waited = {kind: round(row["in_flight_s"] * 1e3, 6) for kind, row in result["kinds"].items()}
+    assert waited == {"search": 0.0, "search.device_wait": 0.0, "commit": 15.0, "encode": 0.0,
+                      tracing.IDLE_IN_FLIGHT: 10.0, tracing.IDLE_NO_REQUEST: 0.0}
+    assert result["idle_s"] == pytest.approx(0.075)
+    text = tracing.format_idle_by_span(result)
+    assert "named: 46.7 % of the idle seconds" in "\n".join(text)  # neither half of `none` is named
+    assert text[-1] == ("with no request in flight (no rest span open): 66.7 % of the idle seconds; with a "
+                        f"request in flight: 33.3 %, by innermost kind: commit 20.0 %, {tracing.IDLE_IN_FLIGHT} 13.3 %")
+    # no request in flight at all: every idle instant had nothing to do
+    idle = tracing.idle_by_span(HAND_MADE, [])["kinds"]
+    assert round(idle[tracing.IDLE_NO_REQUEST]["idle_s"] * 1e3, 6) == 40.0
+    assert sum(row["in_flight_s"] for row in idle.values()) == 0.0
+    plain = tracing.idle_by_span(HAND_MADE)
+    assert all("in_flight_s" not in row for row in plain["kinds"].values())
+    assert tracing.format_idle_by_span(plain)[-1].startswith("named: ")
+
+
+def test_cli_trace_prints_the_split_only_with_spans(tmp_path, monkeypatch):
+    import json
+
+    from click.testing import CliRunner
+
+    from pathway_tpu import cli
+
+    monkeypatch.setattr(tracing, "find_profile", lambda directory: "profile.xplane.pb")
+    monkeypatch.setattr(tracing, "load_profile_events", lambda path: list(HAND_MADE))
+    ring = tmp_path / "trace-rank-0.jsonl"
+    ring.write_text("\n".join(json.dumps(s) for s in [{"_meta": {"rank": 0}}, _rest(25, 35), _rest(90, 5)]) + "\n")
+    plain = CliRunner().invoke(cli.cli, ["trace", str(tmp_path)])
+    assert plain.exit_code == 0, plain.output
+    assert plain.output.splitlines() == ["profile profile.xplane.pb"] + tracing.format_idle_by_span(
+        tracing.idle_by_span(HAND_MADE))
+    split = CliRunner().invoke(cli.cli, ["trace", str(tmp_path), "--spans", str(ring)])
+    assert split.exit_code == 0, split.output
+    lines = split.output.splitlines()
+    assert any(line.startswith(tracing.IDLE_IN_FLIGHT) and line.split()[4] == "0.0150" for line in lines)
+    assert any(line.startswith(tracing.IDLE_NO_REQUEST) and line.split()[5] == "0.0250" for line in lines)
+    assert lines[-1].startswith("with no request in flight (no rest span open): 60.0 % of the idle seconds")

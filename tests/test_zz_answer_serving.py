@@ -156,11 +156,11 @@ def test_zz_counters_and_spans_carry_what_the_request_implies(served):
     assert grew["lm_prefill_batched_layers"] == 4 and grew["lm_batched_layers"] == 0
     # another decoder of this process may have named counts of its own: they did not move here
     stage = {k: grown for k in served["after"][1] if (grown := served["after"][1][k] - served["before"][1].get(k, 0.0))}
-    # the loop was idle, so every call but the prefill was enqueued with the call before it unread
-    assert grew["lm_calls_enqueued_ahead"] == NEW_TOKENS - 1
+    # one reply resolved, its tokens all its own steps' and no other prompt prefilled inside it
+    assert grew["lm_requests_done"] == 1 and grew["lm_reply_tokens"] == NEW_TOKENS and grew["lm_prefills_in_decode"] == 0
     assert stage == {"lm.prefill_calls": 1.0, "lm.prefill_tokens": float(n_prompt), "lm.prefill_padded_tokens": 256.0,
                      "lm.decode_steps": NEW_TOKENS - 1.0, "lm.decode_rows": NEW_TOKENS - 1.0,
-                     "lm.experts_touched": 8.0 * (NEW_TOKENS - 1), "lm.calls_enqueued_ahead": NEW_TOKENS - 1.0}
+                     "lm.experts_touched": 8.0 * (NEW_TOKENS - 1)}
 
     by_kind: dict = {}
     for s in spans:
@@ -201,6 +201,18 @@ def test_zz_counters_and_spans_carry_what_the_request_implies(served):
     assert all(by_id[w["parent_id"]]["ts_mono"] <= w["ts_mono"] and end(w) <= end(by_id[w["parent_id"]])
                for w in step_waits + [prefill_wait])
     assert generate["ts_mono"] <= prefill["ts_mono"] and end(inside[-1]) <= end(generate)
+    # the request's timeline: three children of its generate span end to end, from the submission
+    # (inside generate) through its slot, its prefill's token read and its last token read, and the
+    # same stamps summed in stats()
+    [queue], [first], [decode] = ([s for s in by_kind[k] if s["parent_id"] == generate["span_id"]]
+                                  for k in ("lm.queue", "lm.first_token", "lm.decode"))
+    assert generate["ts_mono"] <= queue["ts_mono"]
+    assert end(queue) == pytest.approx(first["ts_mono"], abs=1e-9) and end(first) == pytest.approx(decode["ts_mono"], abs=1e-9)
+    assert queue["ts_mono"] <= prefill["ts_mono"] and end(prefill_wait) <= end(first) and end(inside[-1]) <= end(decode)
+    assert end(decode) <= end(generate) and decode["attrs"] == {"tokens": NEW_TOKENS, "prefills": 0}
+    assert grew["lm_queue_s"] == pytest.approx(queue["duration_s"])
+    assert grew["lm_ttft_s"] == pytest.approx(queue["duration_s"] + first["duration_s"])
+    assert grew["lm_decode_phase_s"] == pytest.approx(decode["duration_s"])
 
 
 def test_zz_a_second_question_is_answered_while_the_first_still_generates(served, tmp_path, monkeypatch):
