@@ -15,7 +15,8 @@ jitted programs that keep every request's state on the device, in *slots*:
 
 Both also return how many distinct experts their tokens chose, summed over the
 expert layers (the bytes a step has to read follow from it), and how many of
-those layers' products ran batched (``COUNT_NAMES``).
+those layers' products ran batched and how many through the streamed kernel
+(``COUNT_NAMES``).
 
 Two kinds of state live side by side in a slot: ``k``/``v`` of every attention
 layer ``(slots, max_len, kv heads, head size)`` and ``tail`` of every convolution
@@ -29,10 +30,10 @@ state, have the dtype of ``params["embed"]`` (bfloat16 as served); products
 accumulate in float32, and so does the residual stream, the sum every layer
 adds into (rounded to bfloat16 after each of 28 additions it alone moved a
 router's choice about three times as often, on the chip); RMSNorm, the router,
-RoPE, softmax and the logits are computed in float32. The expert products are XLA's own grouped
-product (``jax.lax.ragged_dot`` over the tokens sorted by expert): no token is
-dropped and no expert without a token is read. Layers are unrolled, so that no
-expert matrix is sliced out of a stack in front of the grouped product.
+RoPE, softmax and the logits are computed in float32. The expert products are
+``models/moe.py``'s (a step's through a kernel that streams each touched
+expert's weights once): no token is dropped. Layers are unrolled, so that no
+expert matrix is sliced out of a stack in front of the product.
 """
 
 from __future__ import annotations
@@ -47,9 +48,10 @@ import jax.numpy as jnp
 from pathway_tpu.models.moe import grouped_experts, precision as _precision
 from pathway_tpu.models.slot_decoder import SlotDecoder, random_params
 
-# what a call counts beside its tokens, summed over the layers: distinct experts its tokens chose, and the expert
-# layers whose product ran batched (``models/moe.py``: a prefill's, unless an expert overflowed; never a step's)
-COUNT_NAMES = ("experts_touched", "batched_layers")
+# what a call counts beside its tokens, summed over the layers: distinct experts its tokens chose, the expert layers
+# whose product ran batched (``models/moe.py``: a prefill's, unless an expert overflowed; never a step's) and those
+# whose product ran through the streamed kernel (every step's; a prefill's only in a bucket under the floor)
+COUNT_NAMES = ("experts_touched", "batched_layers", "streamed_layers")
 
 # https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/config.json
 PUBLISHED_LAYER_TYPES = tuple(
@@ -177,9 +179,9 @@ def _moe(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Confi
     """The expert layer over ``h`` (tokens, hidden, float32, already normed).
     Tokens outside ``valid`` choose no expert. Returns the float32 output and
     this call's ``COUNT_NAMES``: how many distinct experts the valid tokens
-    chose, and 1 where the product ran batched (``models/moe.py``). Every
-    expert is held here, so a chosen expert's number is its place in the stack
-    and the router's width is the stack's length."""
+    chose, and a 1 in the place of the form its product ran in
+    (``models/moe.py``). Every expert is held here, so a chosen expert's number
+    is its place in the stack and the router's width is the stack's length."""
     k, e = cfg.num_experts_per_tok, cfg.num_experts
     with jax.named_scope("moe_route"):
         scores = jax.nn.sigmoid(jnp.dot(h, p["gate"], precision=jax.lax.Precision.HIGHEST))
@@ -189,8 +191,8 @@ def _moe(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Confi
         if cfg.norm_topk_prob:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
         weights = weights * cfg.routed_scaling_factor
-    out, group_sizes, batched = grouped_experts(p, h, jnp.where(valid[:, None], chosen, e), weights, e)
-    return out, jnp.stack([jnp.sum(group_sizes > 0, dtype=jnp.int32), batched])
+    out, group_sizes, form = grouped_experts(p, h, jnp.where(valid[:, None], chosen, e), weights, e)
+    return out, jnp.concatenate([jnp.sum(group_sizes > 0, dtype=jnp.int32)[None], form])
 
 
 def _ffn(p: Dict[str, jax.Array], h: jax.Array, valid: jax.Array, cfg: Lfm2Config) -> Tuple[jax.Array, jax.Array]:
@@ -217,7 +219,7 @@ def prefill_logits(params: Dict[str, Any], state: Dict[str, Any], ids: jax.Array
                    slot: jax.Array, cfg: Lfm2Config) -> Tuple[Dict[str, Any], jax.Array, jax.Array]:
     """One prompt into one slot. ``ids`` (bucket,) holds ``length`` tokens and
     padding after them. Returns (the state, the logits of the prompt's last
-    token (vocab,), the counts (2,))."""
+    token (vocab,), the counts (3,))."""
     t, dtype, eps = ids.shape[0], params["embed"].dtype, cfg.norm_eps
     valid = jnp.arange(t) < length
     x = params["embed"][ids].astype(jnp.float32)
@@ -268,7 +270,7 @@ def decode_logits(params: Dict[str, Any], state: Dict[str, Any], active: jax.Arr
     """One token for every slot: feeds ``state["last"]`` at ``state["pos"]``.
     Rows outside ``active`` write nothing and choose no expert. Returns (the
     state with keys, values and tails extended but ``pos``/``last`` as they were,
-    logits (slots, vocab), the counts (2,))."""
+    logits (slots, vocab), the counts (3,))."""
     dtype, eps = params["embed"].dtype, cfg.norm_eps
     pos = state["pos"]
     slots, max_len = pos.shape[0], (state["k"][0].shape[1] if state["k"] else 0)

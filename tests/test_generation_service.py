@@ -439,10 +439,10 @@ def test_requests_arriving_between_steps_get_the_tokens_they_get_alone(slots, ga
 
 @pytest.mark.parametrize("family", ["lfm2", "mistral4"])
 def test_the_layers_whose_product_ran_batched_are_counted_for_prefills_and_stay_zero_over_steps(family):
-    """Both decoders name ``batched_layers``; the service sums it as it sums any
-    count. A bucket at the floor of sixteen rows an expert runs every expert
-    layer's product batched (four layers here, three there); a smaller bucket
-    and every step run the grouped product, and count nothing."""
+    """Both decoders name ``batched_layers`` and ``streamed_layers``; the service
+    sums them as it sums any count. A bucket at the floor of sixteen rows an
+    expert runs every expert layer's product batched (four layers here, three
+    there); a smaller bucket and every step run the streamed kernel instead."""
     from pathway_tpu.engine import telemetry
 
     if family == "lfm2":
@@ -454,7 +454,7 @@ def test_the_layers_whose_product_ran_batched_are_counted_for_prefills_and_stay_
         whole = m4.mistral4.init_params(m4.WHOLE, seed=3, dtype=jnp.float32)
         dec = m4.decoder(m4.share_of(whole, m4.CFG), buckets=(16, 128), new=4)
         large, layers = 100, 3
-    assert dec.count_names[-1] == "batched_layers"
+    assert dec.count_names[-2:] == ("batched_layers", "streamed_layers")
     before = telemetry.stage_snapshot("lm.")
     svc = GenerationService(dec)
     futures = [svc.submit(prompt_of(n, seed=60 + n, vocab=1024)) for n in (large, 9, large + 5)]
@@ -462,8 +462,11 @@ def test_the_layers_whose_product_ran_batched_are_counted_for_prefills_and_stay_
     st = svc.stats()
     assert st["lm_prefill_calls"] == 3 and st["lm_decode_rows"] == 9
     assert st["lm_prefill_batched_layers"] == 2 * layers and st["lm_batched_layers"] == 0
+    # the 9-token prompt's 16 bucket streams in every layer, and so does every step
+    assert st["lm_prefill_streamed_layers"] == layers and st["lm_streamed_layers"] == layers * st["lm_decode_steps"]
     grew = {k: v - before.get(k, 0.0) for k, v in telemetry.stage_snapshot("lm.").items()}
     assert grew["lm.decode_rows"] == 9.0 and grew["lm.batched_layers"] == 0.0
+    assert grew["lm.streamed_layers"] == float(layers * st["lm_decode_steps"])
     svc.close()
 
 

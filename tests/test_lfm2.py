@@ -19,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from pathway_tpu.models import lfm2
+from pathway_tpu.models import lfm2, moe
 from pathway_tpu.models import lfm2_reference as ref
 
 TINY = dict(
@@ -108,16 +108,16 @@ def test_prefill_then_decode_gives_the_references_logits_at_every_position(param
 @pytest.mark.parametrize("length", [64, 41])
 def test_a_prefill_bucket_with_sixteen_rows_an_expert_takes_the_batched_product_and_holds_the_reference(params, length):
     """Buckets of 16 and 32 tokens (2 of 8 experts each: 4 and 8 rows an expert)
-    lower to the grouped product alone; 64 is at the floor, and each of the four
+    lower to the streamed kernel alone; 64 is at the floor, and each of the four
     expert layers' products runs batched (64 places an expert for at most 64
-    rows: nothing can overflow). The steps after it are grouped as ever."""
+    rows: nothing can overflow). The steps after it stream, every layer."""
     prompt = prompt_of(length, seed=50)
     served, tokens, counts = run_through_cache(decoder(params, buckets=(16, 64)), 2, prompt, 3)
     full, chosen = reference(params, prompt + tokens, pad_to=80)
     assert_close(served, full[length - 1 : length + 3])
     assert_greedy(params, prompt, tokens)
-    assert counts[0] == [sum(len(set(c[:length].ravel().tolist())) for c in chosen), 4]
-    assert [c[1] for c in counts[1:]] == [0, 0, 0]
+    assert counts[0] == [sum(len(set(c[:length].ravel().tolist())) for c in chosen), 4, 0]
+    assert [c[1:] for c in counts[1:]] == [[0, 4]] * 3
 
 
 def one_operator(kind, ffn):
@@ -179,7 +179,7 @@ def test_experts_are_chosen_by_biased_scores_and_weighted_by_unbiased_ones():
     h = jax.random.normal(jax.random.PRNGKey(0), (10, cfg.hidden_size), jnp.float32)
     out, touched = lfm2._moe(layer, h, jnp.ones((10,), bool), cfg)
     want, chosen = ref.moe_ffn(jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), layer), h, cfg)
-    assert sorted(set(np.asarray(chosen).ravel().tolist())) == [6, 7] and touched.tolist() == [2, 0]
+    assert sorted(set(np.asarray(chosen).ravel().tolist())) == [6, 7] and touched.tolist() == [2, 0, 1]
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
     # weights taken from the biased scores would be 5.x / (5.x + 5.y), near a half each: not what is served
     scores = jax.nn.sigmoid(h @ layer["gate"])[:, 6:]
@@ -187,7 +187,7 @@ def test_experts_are_chosen_by_biased_scores_and_weighted_by_unbiased_ones():
     assert float(jnp.max(jnp.abs(unbiased - 0.5))) > 0.1
     # and a token that is not valid chooses nothing and gets nothing
     out, touched = lfm2._moe(layer, h, jnp.arange(10) < 0, cfg)
-    assert touched.tolist() == [0, 0] and float(jnp.max(jnp.abs(out))) == 0.0
+    assert touched.tolist() == [0, 0, 1] and float(jnp.max(jnp.abs(out))) == 0.0
 
 
 def test_prompts_of_unequal_length_in_the_slots_get_the_tokens_they_get_alone(params):
@@ -232,7 +232,8 @@ def test_the_distinct_expert_count_equals_the_references(params):
     for slot, ids in prompts.items():
         token, touched = dec.prefill(slot, ids)
         _, chosen = reference(params, ids)
-        assert np.asarray(touched).tolist() == [sum(len(set(c.ravel().tolist())) for c in chosen), 0]
+        # a 16 bucket gives an expert 4 rows: the four layers stream in the prefill too
+        assert np.asarray(touched).tolist() == [sum(len(set(c.ravel().tolist())) for c in chosen), 0, 4]
         sequences[slot] = ids + [int(token)]
     active = np.array([False, True, True, False])
     for _ in range(4):
@@ -244,7 +245,7 @@ def test_the_distinct_expert_count_equals_the_references(params):
             for layer, c in enumerate(chosen):
                 per_layer[layer] |= set(c[-1].tolist())
             seq.append(int(tokens[slot]))
-        assert np.asarray(touched).tolist() == [sum(len(s) for s in per_layer), 0]
+        assert np.asarray(touched).tolist() == [sum(len(s) for s in per_layer), 0, 4]
 
 
 def test_bfloat16_as_served_stays_near_the_float32_reference():
@@ -302,3 +303,54 @@ def assert_bit_equal(got, want):
 def test_init_params_at_a_seed_is_bit_equal_to_the_parents_draw(dtype):
     """``random_params`` gained four names for another decoder; no leaf of this one moved."""
     assert_bit_equal(lfm2.init_params(CFG, seed=3, dtype=dtype), parents_random_params(lfm2.param_shapes(CFG, dtype), 3))
+
+
+def slots_through_both_forms(module, dec, prompts, steps, monkeypatch):
+    """``prompts`` (slot -> ids) prefilled and then ``steps`` steps over those
+    slots together, once as the program runs and once with XLA's grouped
+    product where the streamed kernel runs (the parent's step), each with its
+    own jitted cores: per form the tokens of each slot, the step's logits of the
+    live slots and each call's counts."""
+    def run():
+        prefill = jax.jit(lambda *a, cfg: module.prefill_logits(*a, cfg), static_argnames=("cfg",))
+        decode = jax.jit(lambda *a, cfg: module.decode_logits(*a, cfg), static_argnames=("cfg",))
+        state, tokens, logits, counts = dec.state, {}, [], []
+        active = np.isin(np.arange(dec.slots), list(prompts))
+        for slot, ids in prompts.items():
+            padded = jnp.asarray(ids + [0] * (dec.bucket_of(len(ids)) - len(ids)), jnp.int32)
+            state, out, n = prefill(dec.params, state, padded, jnp.int32(len(ids)), jnp.int32(slot), cfg=dec.cfg)
+            tokens[slot] = [int(jnp.argmax(out))]
+            state["pos"] = state["pos"].at[slot].set(len(ids))
+            state["last"] = state["last"].at[slot].set(tokens[slot][0])
+            counts.append(np.asarray(n).tolist())
+        for _ in range(steps):
+            state, out, n = decode(dec.params, state, jnp.asarray(active), cfg=dec.cfg)
+            step = np.asarray(jnp.argmax(out, axis=-1))
+            for slot in prompts:
+                tokens[slot].append(int(step[slot]))
+            state["pos"] = jnp.where(active, state["pos"] + 1, state["pos"])
+            state["last"] = jnp.where(active, jnp.asarray(step, jnp.int32), state["last"])
+            logits.append(np.asarray(out)[active])
+            counts.append(np.asarray(n).tolist())
+        return tokens, np.stack(logits), counts
+
+    streamed = run()
+    with monkeypatch.context() as m:
+        m.setattr(moe, "streamed_product", moe.grouped_product)
+        grouped = run()
+    return streamed, grouped
+
+
+def test_a_steps_expert_layers_all_stream_and_give_the_grouped_products_tokens(params, monkeypatch):
+    """Every step's four expert layers run the streamed kernel (here in the
+    Pallas interpreter) and a 64 bucket's prefill runs none (it is batched);
+    three slots, one of them choosing what the others choose in some layers,
+    get the tokens the grouped product gives, and the reference's."""
+    prompts = {0: prompt_of(41, seed=53), 2: prompt_of(50, seed=54), 3: prompt_of(33, seed=55)}
+    (tokens, logits, counts), (g_tokens, g_logits, g_counts) = slots_through_both_forms(
+        lfm2, decoder(params, buckets=(16, 64)), prompts, 6, monkeypatch)
+    assert tokens == g_tokens and counts == g_counts
+    assert_close(logits, g_logits, share=1e-5)
+    assert [c[1:] for c in counts] == [[4, 0]] * 3 + [[0, 4]] * 6  # (batched, streamed) layers of each call
+    for slot, ids in prompts.items():
+        assert_greedy(params, ids, tokens[slot])

@@ -128,9 +128,9 @@ def test_prefill_then_decode_through_the_latent_cache_gives_the_references_logit
 @pytest.mark.parametrize("length", [128, 90])
 def test_a_prefill_bucket_with_sixteen_rows_an_expert_takes_the_batched_product_and_holds_the_reference(params, length):
     """Buckets of 16 and 32 tokens (2 of the router's 16 each: 2 and 4 rows an
-    expert) lower to the grouped product alone; 128 is at the floor, and the
+    expert) lower to the streamed kernel alone; 128 is at the floor, and the
     product of each of the three layers runs batched over the 4 held experts'
-    64 places, the pairs of the 12 absent ones "none". The steps stay grouped."""
+    64 places, the pairs of the 12 absent ones "none". The steps stream."""
     prompt = prompt_of(length, seed=50)
     served, tokens, counts = run_through_cache(decoder(params, buckets=(16, 128)), 2, prompt, 3)
     full, chosen = reference(params, prompt + tokens, pad_to=144)
@@ -139,8 +139,8 @@ def test_a_prefill_bucket_with_sixteen_rows_an_expert_takes_the_batched_product_
     held = [c[:length][(c[:length] >= CFG.first_expert) & (c[:length] < CFG.first_expert + CFG.n_routed_experts)]
             for c in chosen]
     assert max(np.bincount(c).max() for c in held) <= 64  # no held expert over its capacity: nothing falls back
-    assert counts[0] == [sum(len(set(c.tolist())) for c in held), sum(c.size for c in held), 3 * 2 * length, 3]
-    assert [c[3] for c in counts[1:]] == [0, 0, 0]
+    assert counts[0] == [sum(len(set(c.tolist())) for c in held), sum(c.size for c in held), 3 * 2 * length, 3, 0]
+    assert [c[3:] for c in counts[1:]] == [[0, 3]] * 3
 
 
 def test_the_four_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_layer(whole):
@@ -171,7 +171,7 @@ def test_the_four_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_u
     assert float(jnp.max(jnp.abs(mistral4._moe(share_of(whole, CFG)["layers"][1], h, valid, CFG)[0] - want))) > 0.05
     # and a token that is not valid chooses nothing and gets the shared expert alone
     out, counts = mistral4._moe(share_of(whole, CFG)["layers"][1], h, jnp.arange(10) < 0, CFG)
-    assert counts.tolist() == [0, 0, 0, 0]
+    assert counts.tolist() == [0, 0, 0, 0, 1]
     np.testing.assert_allclose(np.asarray(out), np.asarray(mistral4.shared_expert(p, h)), atol=1e-6)
 
 
@@ -271,8 +271,9 @@ def test_the_counts_equal_the_references(params):
     for slot, ids in prompts.items():
         token, counts = dec.prefill(slot, ids)
         _, chosen = reference(params, ids)
+        # a 16 bucket gives a held expert 2 rows: the three layers stream in the prefill too
         assert np.asarray(counts).tolist() == [sum(len(set(held(c).tolist())) for c in chosen),
-                                               sum(held(c).size for c in chosen), 3 * 2 * len(ids), 0]
+                                               sum(held(c).size for c in chosen), 3 * 2 * len(ids), 0, 3]
         sequences[slot] = ids + [int(token)]
     active = np.array([False, True, True, False])
     for _ in range(4):
@@ -283,7 +284,8 @@ def test_the_counts_equal_the_references(params):
             for layer, c in enumerate(chosen):
                 per_layer[layer] += held(c[-1]).tolist()
             seq.append(int(tokens[slot]))
-        assert np.asarray(counts).tolist() == [sum(len(set(c)) for c in per_layer), sum(len(c) for c in per_layer), 12, 0]
+        assert np.asarray(counts).tolist() == [sum(len(set(c)) for c in per_layer), sum(len(c) for c in per_layer), 12, 0,
+                                               3]
 
 
 def test_the_padding_bucket_changes_nothing(params):
@@ -366,3 +368,21 @@ def test_init_params_at_a_seed_is_bit_equal_to_the_parents_draw(dtype):
 
     assert_bit_equal(mistral4.init_params(WHOLE, seed=3, dtype=dtype),
                      parents_random_params(mistral4.param_shapes(WHOLE, dtype), 3))
+
+
+def test_a_steps_expert_layers_all_stream_and_give_the_grouped_products_tokens(params, monkeypatch):
+    """Every step's three expert layers run the streamed kernel over the 4 held
+    experts (here in the Pallas interpreter; a layer in which no held expert has
+    a row fetches nothing) and a 128 bucket's prefill runs none (it is
+    batched); three slots get the tokens the grouped product gives, and the
+    reference's."""
+    from .test_lfm2 import slots_through_both_forms
+
+    prompts = {0: prompt_of(100, seed=53), 1: prompt_of(90, seed=54), 3: prompt_of(70, seed=55)}
+    (tokens, logits, counts), (g_tokens, g_logits, g_counts) = slots_through_both_forms(
+        mistral4, decoder(params, buckets=(16, 128)), prompts, 6, monkeypatch)
+    assert tokens == g_tokens and counts == g_counts
+    assert_close(logits, g_logits, share=1e-5)
+    assert [c[3:] for c in counts] == [[3, 0]] * 3 + [[0, 3]] * 6  # (batched, streamed) layers of each call
+    for slot, ids in prompts.items():
+        assert_greedy(params, ids, tokens[slot])

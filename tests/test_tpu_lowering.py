@@ -79,26 +79,34 @@ def test_encoder_forward_lowers_for_tpu():
     assert exported.out_avals[0].shape == (8, 64)
 
 
-def _assert_batched_products(text, layers, rows_in, rows_mid):
-    """In ``layers`` expert layers a conditional whose one branch holds three
-    batched products from a padded buffer (``rows_in`` into w1 and w3,
-    ``rows_mid`` into w2) and whose other holds the grouped ones; with 0, neither."""
+def _assert_expert_products(text, layers, batched, rows_in, rows_mid):
+    """In each of ``layers`` expert layers either (``batched``) a conditional
+    whose one branch holds three batched products from a padded buffer
+    (``rows_in`` into w1 and w3, ``rows_mid`` into w2) and whose other holds
+    the three grouped ones, and no streamed kernel; or the streamed kernel,
+    one custom call, and nothing else."""
     # the conditional's branches by their places in the text: the false one grouped, the true one batched
     assert ("/moe_experts/cond/branch_0_fun/ragged_dot" in text) == ("/moe_experts/cond/branch_1_fun/" in text)
-    assert ("/moe_experts/cond/" in text) == bool(layers) and ("/moe_experts/ragged_dot" in text) == (not layers)
-    assert text.count(f"(tensor<{rows_in}>, ") == 2 * layers and text.count(f"(tensor<{rows_mid}>, ") == layers
+    assert ("/moe_experts/cond/" in text) == batched and "/moe_experts/ragged_dot" not in text
+    assert text.count("@chlo.ragged_dot(") == (3 * layers if batched else 0)
+    assert text.count(f"(tensor<{rows_in}>, ") == 2 * layers * batched
+    assert text.count(f"(tensor<{rows_mid}>, ") == layers * batched
+    assert text.count('kernel_name = "moe_streamed"') == (0 if batched else layers) and "/moe_experts/" in text
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_generators_programs_lower_for_tpu_at_published_width(program):
+def test_the_generators_programs_lower_for_tpu_at_published_width(program, monkeypatch):
     """``lm_decode`` and ``lm_prefill`` of the ``lfm2_moe`` decoder at LFM2-8B-A1B's
     widths (hidden 2,048, 32 experts of 1,792, top 4, vocabulary 65,536), one
     period of its layer pattern, 16 slots: shapes only, nothing is allocated.
-    A step's expert products are one grouped product each (XLA's ragged dot) and
+    A step's expert product is the streamed kernel in each expert layer and
     nothing else; a prefill's 256 tokens give an expert 32 rows, so each of its
     four expert layers holds a conditional over the batched products (32 experts
-    x 128 places) and the grouped ones (``models/moe.py``)."""
+    x 128 places) and the grouped ones (``models/moe.py``). The backend the
+    program asks for is the TPU here, so the kernel lowers as the chip runs it."""
     from pathway_tpu.models import lfm2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     cfg = lfm2.Lfm2Config(num_hidden_layers=6, layer_types=lfm2.PUBLISHED_LAYER_TYPES[:6])
     params = lfm2.param_shapes(cfg)
@@ -109,19 +117,23 @@ def test_the_generators_programs_lower_for_tpu_at_published_width(program):
         exported = _export_tpu(functools.partial(lfm2.prefill_logits, cfg=cfg), params, state,
                                S((256,), jnp.int32), S((), jnp.int32), S((), jnp.int32))
     text = exported.mlir_module()
-    assert text.count("@chlo.ragged_dot(") == 3 * 4  # w1, w3, w2 of the four expert layers
-    _assert_batched_products(text, 4 if program == "prefill" else 0, "32x128x2048xbf16", "32x128x1792xbf16")
+    if program == "decode":
+        assert text.count("tpu_custom_call") == 4  # one kernel an expert layer
+    _assert_expert_products(text, 4, program == "prefill", "32x128x2048xbf16", "32x128x1792xbf16")
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_the_mistral4_programs_lower_for_tpu_at_published_width(program):
+def test_the_mistral4_programs_lower_for_tpu_at_published_width(program, monkeypatch):
     """``lm_decode`` (absorbed) and ``lm_prefill`` (expanded) of the ``mistral4``
     decoder at Mistral-Small-4's widths (hidden 4,096, ranks 1,024 and 256, 32
     heads of 64 + 64 and 128, 32 held experts of 2,048 under a router of 128,
     32,768 vocabulary rows), two layers, 16 slots of 2,112 positions: shapes
     only, nothing is allocated. The step never expands a key or a value: no
-    product of its module has the cache's positions beside a head's key size."""
+    product of its module has the cache's positions beside a head's key size;
+    its expert product is the streamed kernel in each layer."""
     from pathway_tpu.models import mistral4
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     cfg = mistral4.Mistral4Config(num_hidden_layers=2, n_routed_experts=32, n_router_experts=128, vocab_size=32768)
     params = mistral4.param_shapes(cfg)
@@ -130,6 +142,7 @@ def test_the_mistral4_programs_lower_for_tpu_at_published_width(program):
     if program == "decode":
         exported = _export_tpu(functools.partial(mistral4.decode_logits, cfg=cfg), params, state, S((16,), jnp.bool_))
         assert "16x2112x32x" not in exported.mlir_module()  # no per-head keys or values over the cache
+        assert exported.mlir_module().count("tpu_custom_call") == 2  # one kernel an expert layer
     else:
         exported = _export_tpu(functools.partial(mistral4.prefill_logits, cfg=cfg), params, state,
                                S((1536,), jnp.int32), S((), jnp.int32), S((), jnp.int32))
@@ -137,9 +150,8 @@ def test_the_mistral4_programs_lower_for_tpu_at_published_width(program):
         assert "tpu_custom_call" in exported.mlir_module()  # over them the flash kernel, not 32 x 1,536 x 1,536 scores
         assert "32x1536x1536" not in exported.mlir_module()
     text = exported.mlir_module()
-    assert text.count("@chlo.ragged_dot(") == 3 * 2  # w1, w3, w2 of both layers
     # 1,536 tokens x 4 over the router's 128 give a held expert 48 rows and 192 places; a step's 16 x 4 give it half a row
-    _assert_batched_products(text, 2 if program == "prefill" else 0, "32x192x4096xbf16", "32x192x2048xbf16")
+    _assert_expert_products(text, 2, program == "prefill", "32x192x4096xbf16", "32x192x2048xbf16")
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_256", "prefill_512", "prefill_1024"])

@@ -152,15 +152,17 @@ def test_zz_counters_and_spans_carry_what_the_request_implies(served):
     assert grew["lm_decode_steps"] == grew["lm_decode_rows"] == NEW_TOKENS - 1
     # four expert layers, two experts a token: one row a step chooses eight
     assert grew["lm_experts_touched"] == 8 * (NEW_TOKENS - 1) and grew["lm_compiled_programs"] == 0
-    # the 256 bucket gives an expert 64 rows: the four layers' products ran batched in the prefill, in no step
+    # the 256 bucket gives an expert 64 rows: the four layers' products ran batched in the prefill, in no step;
+    # every step's ran through the streamed kernel, and no prefill's
     assert grew["lm_prefill_batched_layers"] == 4 and grew["lm_batched_layers"] == 0
+    assert grew["lm_streamed_layers"] == 4 * (NEW_TOKENS - 1) and grew["lm_prefill_streamed_layers"] == 0
     # another decoder of this process may have named counts of its own: they did not move here
     stage = {k: grown for k in served["after"][1] if (grown := served["after"][1][k] - served["before"][1].get(k, 0.0))}
     # one reply resolved, its tokens all its own steps' and no other prompt prefilled inside it
     assert grew["lm_requests_done"] == 1 and grew["lm_reply_tokens"] == NEW_TOKENS and grew["lm_prefills_in_decode"] == 0
     assert stage == {"lm.prefill_calls": 1.0, "lm.prefill_tokens": float(n_prompt), "lm.prefill_padded_tokens": 256.0,
                      "lm.decode_steps": NEW_TOKENS - 1.0, "lm.decode_rows": NEW_TOKENS - 1.0,
-                     "lm.experts_touched": 8.0 * (NEW_TOKENS - 1)}
+                     "lm.experts_touched": 8.0 * (NEW_TOKENS - 1), "lm.streamed_layers": 4.0 * (NEW_TOKENS - 1)}
 
     by_kind: dict = {}
     for s in spans:
