@@ -139,8 +139,9 @@ def test_a_prefill_bucket_with_sixteen_rows_an_expert_takes_the_batched_product_
     held = [c[:length][(c[:length] >= CFG.first_expert) & (c[:length] < CFG.first_expert + CFG.n_routed_experts)]
             for c in chosen]
     assert max(np.bincount(c).max() for c in held) <= 64  # no held expert over its capacity: nothing falls back
-    assert counts[0] == [sum(len(set(c.tolist())) for c in held), sum(c.size for c in held), 3 * 2 * length, 3, 0]
-    assert [c[3:] for c in counts[1:]] == [[0, 3]] * 3
+    assert counts[0] == [sum(len(set(c.tolist())) for c in held), sum(c.size for c in held), 3 * 2 * length,
+                         3 * length, 3, 0]
+    assert [c[4:] for c in counts[1:]] == [[0, 3]] * 3
 
 
 def test_the_four_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_layer(whole):
@@ -168,10 +169,10 @@ def test_the_four_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_u
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), atol=1e-5)
     assert held == 20 and pairs == {20}  # every routed pair is held by exactly one share
     # one share alone is not the layer: the absent experts' part is left out, not made up
-    assert float(jnp.max(jnp.abs(mistral4._moe(share_of(whole, CFG)["layers"][1], h, valid, CFG)[0] - want))) > 0.05
+    assert float(jnp.max(jnp.abs(mistral4._ffn(share_of(whole, CFG)["layers"][1], h, valid, 1, CFG)[0] - want))) > 0.05
     # and a token that is not valid chooses nothing and gets the shared expert alone
-    out, counts = mistral4._moe(share_of(whole, CFG)["layers"][1], h, jnp.arange(10) < 0, CFG)
-    assert counts.tolist() == [0, 0, 0, 0, 1]
+    out, counts = mistral4._ffn(share_of(whole, CFG)["layers"][1], h, jnp.arange(10) < 0, 1, CFG)
+    assert counts.tolist() == [0, 0, 0, 0, 0, 1]
     np.testing.assert_allclose(np.asarray(out), np.asarray(mistral4.shared_expert(p, h)), atol=1e-6)
 
 
@@ -273,7 +274,8 @@ def test_the_counts_equal_the_references(params):
         _, chosen = reference(params, ids)
         # a 16 bucket gives a held expert 2 rows: the three layers stream in the prefill too
         assert np.asarray(counts).tolist() == [sum(len(set(held(c).tolist())) for c in chosen),
-                                               sum(held(c).size for c in chosen), 3 * 2 * len(ids), 0, 3]
+                                               sum(held(c).size for c in chosen), 3 * 2 * len(ids), 3 * len(ids), 0,
+                                               3]
         sequences[slot] = ids + [int(token)]
     active = np.array([False, True, True, False])
     for _ in range(4):
@@ -284,8 +286,9 @@ def test_the_counts_equal_the_references(params):
             for layer, c in enumerate(chosen):
                 per_layer[layer] += held(c[-1]).tolist()
             seq.append(int(tokens[slot]))
-        assert np.asarray(counts).tolist() == [sum(len(set(c)) for c in per_layer), sum(len(c) for c in per_layer), 12, 0,
-                                               3]
+        # one group: every live row of every layer reaches this chip's group
+        assert np.asarray(counts).tolist() == [sum(len(set(c)) for c in per_layer), sum(len(c) for c in per_layer), 12, 6,
+                                               0, 3]
 
 
 def test_the_padding_bucket_changes_nothing(params):
@@ -383,6 +386,85 @@ def test_a_steps_expert_layers_all_stream_and_give_the_grouped_products_tokens(p
         mistral4, decoder(params, buckets=(16, 128)), prompts, 6, monkeypatch)
     assert tokens == g_tokens and counts == g_counts
     assert_close(logits, g_logits, share=1e-5)
-    assert [c[3:] for c in counts] == [[3, 0]] * 3 + [[0, 3]] * 6  # (batched, streamed) layers of each call
+    assert [c[4:] for c in counts] == [[3, 0]] * 3 + [[0, 3]] * 6  # (batched, streamed) layers of each call
     for slot, ids in prompts.items():
         assert_greedy(params, ids, tokens[slot])
+
+
+def parents_routed_experts(p, h, valid, cfg):
+    """``mistral4.routed_experts`` as it stood before the decoder learnt
+    sigmoid scores and groups, spelled out, with the one count it had not
+    (``group_rows``: every valid token, with one group) put in its place."""
+    from pathway_tpu.models.moe import grouped_experts
+
+    k, held, first = cfg.num_experts_per_tok, cfg.n_routed_experts, cfg.first_expert
+    with jax.named_scope("moe_route"):
+        probs = jax.nn.softmax(jnp.dot(h, p["gate"], precision=jax.lax.Precision.HIGHEST), axis=-1)
+        weights, chosen = jax.lax.top_k(probs, k)
+        if cfg.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights * cfg.routed_scaling_factor
+        here = valid[:, None] & (chosen >= first) & (chosen < first + held)
+        local = jnp.where(here, chosen - first, held)
+    out, group_sizes, form = grouped_experts(p, h, local, weights, cfg.router_width)
+    counts = jnp.stack([jnp.sum(group_sizes > 0, dtype=jnp.int32), jnp.sum(group_sizes, dtype=jnp.int32),
+                        k * jnp.sum(valid, dtype=jnp.int32), jnp.sum(valid, dtype=jnp.int32)])
+    return out, jnp.concatenate([counts, form])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_mistral_small_4s_tokens_and_logits_are_bit_equal_under_the_parents_router(params, dtype, monkeypatch):
+    """The decoder that learnt A.X-K1's keys computes Mistral-Small-4 as it
+    did: a prefill (batched, 128 bucket), a prefill that streams (16 bucket)
+    and steps over both slots give the same tokens, the same logits bit for bit
+    and the same counts with the parent's router in its place."""
+    p = params if dtype == jnp.float32 else share_of(mistral4.init_params(WHOLE, seed=3), CFG)
+    dec = decoder(p, buckets=(16, 128))
+
+    def run():
+        prefill = jax.jit(lambda *a, cfg: mistral4.prefill_logits(*a, cfg), static_argnames=("cfg",))
+        decode = jax.jit(lambda *a, cfg: mistral4.decode_logits(*a, cfg), static_argnames=("cfg",))
+        state, out = dec.state, []
+        for slot, ids in ((0, prompt_of(100, seed=56)), (2, prompt_of(11, seed=57))):
+            padded = jnp.asarray(ids + [0] * (dec.bucket_of(len(ids)) - len(ids)), jnp.int32)
+            state, logits, counts = prefill(dec.params, state, padded, jnp.int32(len(ids)), jnp.int32(slot), cfg=CFG)
+            state["pos"] = state["pos"].at[slot].set(len(ids))
+            state["last"] = state["last"].at[slot].set(jnp.argmax(logits).astype(jnp.int32))
+            out.append((np.asarray(logits), np.asarray(counts)))
+        active = jnp.asarray([True, False, True, False])
+        for _ in range(4):
+            state, logits, counts = decode(dec.params, state, active, cfg=CFG)
+            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            state["pos"] = jnp.where(active, state["pos"] + 1, state["pos"])
+            state["last"] = jnp.where(active, tokens, state["last"])
+            out.append((np.asarray(logits), np.asarray(counts)))
+        return out
+
+    now = run()
+    with monkeypatch.context() as m:
+        m.setattr(mistral4, "routed_experts", parents_routed_experts)
+        parents = run()
+    assert [c[4:].tolist() for _, c in now] == [[3, 0], [0, 3]] + [[0, 3]] * 4  # both forms of the product ran
+    for (logits, counts), (want, want_counts) in zip(now, parents):
+        assert np.array_equal(logits.view(np.uint8), want.view(np.uint8))
+        assert counts.tolist() == want_counts.tolist()
+
+
+def test_mistral_small_4s_parameter_tree_and_its_draw_are_the_parents():
+    """A.X-K1's dense layers gave the tree a second kind of layer; with
+    ``first_k_dense_replace`` 0 every layer is the parent's, leaf for leaf, and
+    ``init_params`` draws the same numbers."""
+    from .test_lfm2 import assert_bit_equal, parents_random_params
+
+    h, f, heads, e = WHOLE.hidden_size, WHOLE.moe_intermediate_size, WHOLE.num_attention_heads, WHOLE.n_routed_experts
+    mat = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    layer = {
+        "attn_norm": mat(h), "ffn_norm": mat(h), "wdq": mat(h, 32), "q_norm": mat(32), "wuq": mat(32, heads * 16),
+        "wdkv": mat(h, 16 + 8), "kv_norm": mat(16), "wukv": mat(16, heads * (8 + 16)), "wo": mat(heads * 16, h),
+        "gate": mat(h, 16), "shared_w1": mat(h, f), "shared_w3": mat(h, f), "shared_w2": mat(f, h),
+        "w1": mat(e, h, f), "w3": mat(e, h, f), "w2": mat(e, f, h),
+    }
+    parents = {"embed": mat(4096, h), "final_norm": mat(h), "lm_head": mat(h, 4096), "layers": [layer] * 3}
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), mistral4.param_shapes(WHOLE, jnp.float32)) == jax.tree.map(
+        lambda a: (a.shape, a.dtype), parents)
+    assert_bit_equal(mistral4.init_params(WHOLE, seed=5, dtype=jnp.float32), parents_random_params(parents, 5))

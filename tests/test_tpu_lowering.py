@@ -186,3 +186,43 @@ def test_the_falcon_h1_programs_lower_for_tpu_at_published_width(program, monkey
         assert f"tensor<{chunks}x2x16x128x256xf32>" in text  # a chunk's own state, a head: (d_head, state)
     assert "ssm_op" in text and "attn_op" in text and "mlp_op" in text
     assert "5120x261120xbf16" in text  # the untied head, whole
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_axk1_programs_lower_for_tpu_at_published_width(program, monkeypatch):
+    """``lm_decode`` and ``lm_prefill`` of the latent-attention decoder at
+    A.X-K1's widths (hidden 7,168, ranks 1,536 and 512, 64 heads of 128 + 64
+    for queries and keys and 128 for values, 12 held experts of 2,048 under a
+    router of 192 in 8 groups, 20,480 vocabulary rows), its dense layer 0 (a
+    SwiGLU of 18,432) and one expert layer, 16 slots of 2,176 positions: shapes
+    only. The prefill's attention is the flash kernel, over heads zero-padded
+    to 256 (it takes one size for queries, keys and values, and past 128 a
+    whole number of 128 lanes); its expert layer is batched; the step's is the
+    streamed kernel."""
+    from pathway_tpu.models import mistral4
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    cfg = mistral4.Mistral4Config(
+        vocab_size=20480, hidden_size=7168, num_hidden_layers=2, num_attention_heads=64, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=12,
+        num_experts_per_tok=8, routed_scaling_factor=2.5, rope_factor=32.0, rope_original_max_position_embeddings=4096,
+        first_k_dense_replace=1, intermediate_size=18432, scoring_func="sigmoid", n_group=8, topk_group=4,
+        n_router_experts=192)
+    params = mistral4.param_shapes(cfg)
+    assert params["layers"][0]["w1"].shape == (7168, 18432) and "gate" not in params["layers"][0]
+    state = jax.eval_shape(lambda: mistral4.init_state(cfg, 16, 2176))
+    if program == "decode":
+        text = _export_tpu(functools.partial(mistral4.decode_logits, cfg=cfg), params, state,
+                           S((16,), jnp.bool_)).mlir_module()
+        assert "2176x64x128" not in text and "2176x64x192" not in text  # no per-head keys or values over the cache
+        assert text.count("tpu_custom_call") == 1  # the one expert layer's kernel
+    else:
+        text = _export_tpu(functools.partial(mistral4.prefill_logits, cfg=cfg), params, state,
+                           S((1024,), jnp.int32), S((), jnp.int32), S((), jnp.int32)).mlir_module()
+        assert "tpu_custom_call" in text  # the flash kernel
+        assert "1x64x1024x256xbf16" in text and "1x64x1024x192xbf16" not in text  # heads padded to the kernel's size
+        assert "64x1024x1024" not in text  # no scores over the bucket in XLA
+    assert "7168x18432xbf16" in text  # layer 0's dense SwiGLU
+    # 1,024 tokens x 8 over the router's 192 give a held expert 43 rows and 192 places; a step's 16 x 8 under one
+    _assert_expert_products(text, 1, program == "prefill", "12x192x7168xbf16", "12x192x2048xbf16")
