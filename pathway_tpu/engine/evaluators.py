@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 import numpy as np
 
 from pathway_tpu.engine import expression_evaluator as ee
+from pathway_tpu.engine import telemetry
 from pathway_tpu.engine.columnar import ERROR, Delta, Error, StateTable, empty_keys, objarray
 from pathway_tpu.internals import expression as expr
 from pathway_tpu.internals import parse_graph as pg
@@ -2809,7 +2810,20 @@ class ExternalIndexEvaluator(Evaluator):
     answered once against the index state at arrival and never revisited. With
     ``asof_now=False`` live queries are *re-answered* whenever the index changes: the old
     reply is retracted and the fresh one emitted (reference full differential semantics of
-    ``DataIndex.query``)."""
+    ``DataIndex.query``).
+
+    With ``index_payload_columns`` in the config (``DataIndex.query_as_of_now`` with
+    ``collapse_rows=True``) the operator also assembles the reply the user reads: one row
+    per query holding ``_pw_index_reply_score`` and each payload (data) column as a tuple,
+    best score first, equal scores in the order the matches joined the reply. It keeps
+    every data row's payload beside its vector, so such a query needs no flatten / ix /
+    groupby / join_left downstream, and its row is the one that chain gives a live
+    query: a matched data row that changes while the reply is live re-emits the reply
+    with the new values and the old scores (that match moves behind its equal scores),
+    a deleted one reads ``ERROR``, and a matched key that is no ``Pointer`` (a row
+    installed through the index's descriptor contract) reads ``None``. Live queries
+    keep the chain: their replies are re-answered, and the chain's differential update
+    is what tracks that."""
 
     # the data side replicates to every process (each holds the FULL index);
     # queries stay local and answer exactly against the replicated state —
@@ -2826,10 +2840,16 @@ class ExternalIndexEvaluator(Evaluator):
     def __init__(self, node: pg.Node, runner: Any):
         super().__init__(node, runner)
         self.index = node.config["index_factory"].make_instance()
-        self.replies = StateTable(["_pw_index_reply"])
+        # the last row emitted per query key: a retraction replays it
+        self.replies = StateTable(self.output_columns)
         self.asof_now: bool = bool(self.node.config.get("asof_now", True))
         # kb -> (key, qvec, limit, filter) for re-answering mode
         self.live_queries: Dict[bytes, tuple] = {}
+        # assembled replies: data key -> payload values, and per live reply its
+        # query key and matches [(data key, score)] in the order they joined it
+        self.payload: Dict[Any, tuple] = {}
+        self.reply_order: Dict[bytes, tuple] = {}
+        self.watchers: Dict[Pointer, set] = {}
 
     # -- elastic membership: the rebuildable-descriptor contract -------------
     #
@@ -2853,11 +2873,20 @@ class ExternalIndexEvaluator(Evaluator):
     def rebuild_descriptor(self) -> "Any | None":
         if not self.rebuild_supported():
             return None
-        return self.index.rebuild_descriptor()
+        desc = self.index.rebuild_descriptor()
+        if desc is not None and self._payload_refs() is not None:
+            # the payload replicates with the index: a rank that joins answers
+            # with the data rows, not with None
+            desc = dict(desc, payload=dict(self.payload))
+        return desc
 
     def install_rebuild_descriptor(self, desc: Any) -> None:
         if desc is not None:
+            desc = dict(desc)
+            payload = desc.pop("payload", None)
             self.index.install_rebuild_descriptor(desc)
+            if payload is not None:
+                self.payload = dict(payload)
 
     def reshard_check(self) -> "str | None":
         if self.rebuild_supported():
@@ -2882,6 +2911,9 @@ class ExternalIndexEvaluator(Evaluator):
             out.setdefault(dest, {}).setdefault("live_queries", {})[kb] = (
                 key, _to_host(qvec), limit, flt,
             )
+        for kb, entry in self.reply_order.items():
+            dest = int(np.asarray(owner_of(np.frombuffer(kb, dtype=KEY_DTYPE)))[0])
+            out.setdefault(dest, {}).setdefault("reply_order", {})[kb] = entry
         return out
 
     def reshard_import(self, payload: Any) -> None:
@@ -2892,6 +2924,9 @@ class ExternalIndexEvaluator(Evaluator):
             if len(keys):
                 self.replies.apply(Delta(keys, diffs, columns))
         self.live_queries.update(payload.get("live_queries", {}))
+        for kb, entry in payload.get("reply_order", {}).items():
+            self.reply_order[kb] = entry
+            self._watch(kb, entry[1])
 
     def _search_batch(
         self, vecs: List[Any], limits: List[int], filters: List[Any]
@@ -2904,10 +2939,119 @@ class ExternalIndexEvaluator(Evaluator):
             self.index.search(v, l, f) for v, l, f in zip(vecs, limits, filters)
         ]
 
+    # -- the assembled reply (as-of-now, collapsed) ----------------------------
+
+    def _payload_refs(self) -> "Dict[str, expr.ColumnReference] | None":
+        return self.node.config.get("index_payload_columns")
+
+    def _visible(self, key: Any, width: int) -> tuple:
+        """What a reply shows of a matched key: its payload; ``None`` in every
+        column for a key that is no ``Pointer`` (no data row); ``ERROR`` for a
+        data row deleted while a reply holds it."""
+        if not isinstance(key, Pointer):
+            return (None,) * width
+        row = self.payload.get(key)
+        return (ERROR,) * width if row is None else row
+
+    def _apply_payload(
+        self, refs: Dict[str, Any], index_delta: Delta, resolver: Callable, ptrs: List[Any]
+    ) -> Dict[Any, tuple]:
+        """Keep each data row's payload as the index keeps its vector. Returns, for
+        the touched keys a live reply holds, what they showed before this commit,
+        in order of first appearance."""
+        cols = [self._eval_expr(ref, index_delta, resolver) for ref in refs.values()]
+        before: Dict[Any, tuple] = {}
+        watchers, payload = self.watchers, self.payload
+        for i, key in enumerate(ptrs):
+            if key in watchers and key not in before:
+                before[key] = self._visible(key, len(cols))
+            if index_delta.diffs[i] > 0:
+                payload[key] = tuple(c[i] for c in cols)
+            else:
+                payload.pop(key, None)
+        return before
+
+    def _watch(self, kb: bytes, order: List[tuple]) -> None:
+        for data_key, _ in order:
+            if isinstance(data_key, Pointer):
+                self.watchers.setdefault(data_key, set()).add(kb)
+
+    def _forget_reply(self, kb: bytes) -> None:
+        entry = self.reply_order.pop(kb, None)
+        if entry is None:
+            return
+        for data_key, _ in entry[1]:
+            held = self.watchers.get(data_key)
+            if held is not None:
+                held.discard(kb)
+                if not held:
+                    del self.watchers[data_key]
+
+    def _assemble(self, order: List[tuple], names: List[str]) -> dict:
+        """One reply row: scores and payload tuples, best score first; equal scores
+        keep the order the matches joined the reply (the rule of
+        ``reducers.tuple(..., sort_by=-score)``, which orders the live path's reply)."""
+        ranked = sorted(range(len(order)), key=lambda j: (-order[j][1], j))
+        values = [self._visible(order[j][0], len(names)) for j in ranked]
+        # float64 scores, as the live path's score column holds them
+        row = {"_pw_index_reply_score": tuple(np.float64(order[j][1]) for j in ranked)}
+        for c, name in enumerate(names):
+            row[name] = tuple(v[c] for v in values)
+        return row
+
+    def _answer(self, key: Any, kb: bytes, matches: List[tuple], names: List[str]) -> dict:
+        for data_key, _ in matches:
+            if isinstance(data_key, Pointer) and data_key not in self.payload:
+                raise KeyError(f"index reply: matched key {data_key!r} is not in the data table")
+        order = list(matches)
+        self.reply_order[kb] = (key, order)
+        self._watch(kb, order)
+        return self._assemble(order, names)
+
+    def _reemit(
+        self, before: Dict[Any, tuple], answered: set, names: List[str],
+        out_keys: list, out_diffs: list, out_rows: list,
+    ) -> int:
+        """A matched data row that changed while its reply is live re-emits the
+        reply with the new values and the old scores; the changed match moves
+        behind its equal scores, as ix / groupby move it in the live path."""
+        touched: Dict[bytes, None] = {}
+        for data_key, old in before.items():
+            if not _cells_differ(old, self._visible(data_key, len(names))):
+                continue
+            for kb in self.watchers.get(data_key, ()):
+                if kb in answered:
+                    continue
+                order = self.reply_order[kb][1]
+                j = next(j for j, m in enumerate(order) if m[0] == data_key)
+                order.append(order.pop(j))
+                touched[kb] = None
+        n = 0
+        for kb in touched:
+            key, order = self.reply_order[kb]
+            row = self._assemble(order, names)
+            stored = self.replies.get_row(kb)
+            if stored is not None:
+                if not _cells_differ(
+                    tuple(stored[c] for c in row), tuple(row.values())
+                ):
+                    continue
+                out_keys.append(key)
+                out_diffs.append(-1)
+                out_rows.append(stored)
+            out_keys.append(key)
+            out_diffs.append(1)
+            out_rows.append(row)
+            n += 1
+        return n
+
     def process(self, input_deltas: List[Delta]) -> Delta:
         index_delta, query_delta = input_deltas
         index_table, query_table = self.node.inputs
         index_changed = len(index_delta) > 0
+        refs = self._payload_refs()
+        names = list(refs) if refs is not None else []
+        before: Dict[Any, tuple] = {}
 
         if len(index_delta):
             resolver = self._resolver_for(index_table, index_delta)
@@ -2937,8 +3081,12 @@ class ExternalIndexEvaluator(Evaluator):
                         )
                     else:
                         self.index.remove(ptrs[i])
+            if refs is not None:
+                before = self._apply_payload(refs, index_delta, resolver, ptrs)
 
         out_keys, out_diffs, out_rows = [], [], []
+        q_kbs: List[bytes] = []
+        assembled = 0
         if len(query_delta):
             resolver = self._resolver_for(query_table, query_delta)
             qvecs = self._eval_expr(
@@ -2969,10 +3117,15 @@ class ExternalIndexEvaluator(Evaluator):
                 if query_delta.diffs[i] > 0:
                     limit = int(limits[i]) if limits is not None else 1
                     flt = qfilters[i] if qfilters is not None else None
-                    reply = tuple(reply_of[i])
                     out_keys.append(query_delta.keys[i])
                     out_diffs.append(1)
-                    out_rows.append({"_pw_index_reply": reply})
+                    if refs is not None:
+                        out_rows.append(
+                            self._answer(query_delta.keys[i], kb, reply_of[i], names)
+                        )
+                        assembled += 1
+                    else:
+                        out_rows.append({"_pw_index_reply": tuple(reply_of[i])})
                     if not self.asof_now:
                         self.live_queries[kb] = (
                             query_delta.keys[i],
@@ -2982,6 +3135,7 @@ class ExternalIndexEvaluator(Evaluator):
                         )
                 else:
                     self.live_queries.pop(kb, None)
+                    self._forget_reply(kb)
                     stored = self.replies.get_row(kb)
                     if stored is not None:
                         out_keys.append(query_delta.keys[i])
@@ -3012,7 +3166,16 @@ class ExternalIndexEvaluator(Evaluator):
                 out_keys.append(key)
                 out_diffs.append(1)
                 out_rows.append({"_pw_index_reply": reply})
-        delta = _delta_from_rows(out_keys, out_diffs, out_rows, ["_pw_index_reply"])
+        reemits = (
+            self._reemit(before, set(q_kbs), names, out_keys, out_diffs, out_rows)
+            if before
+            else 0
+        )
+        if assembled or reemits:
+            telemetry.stage_add_many(
+                {"index.replies_assembled": assembled, "index.reply_reemits": reemits}
+            )
+        delta = _delta_from_rows(out_keys, out_diffs, out_rows, self.output_columns)
         self.replies.apply(delta)
         return delta
 
@@ -3174,6 +3337,11 @@ class OutputEvaluator(Evaluator):
 
     def finish(self) -> None:
         self.notify_stream_end()
+
+
+def _cells_differ(old: tuple, new: tuple) -> bool:
+    """Whether two rows of cells differ (ndarray and ``ERROR`` cells allowed)."""
+    return bool(_col_neq(objarray(list(old)), objarray(list(new))).any())
 
 
 def _delta_from_rows(

@@ -84,7 +84,7 @@ STAGE_NAMESPACES: "tuple[str, ...]" = (
     "eval.",        # batch-UDF evaluation; fully_async calls out of a commit (_rows) and back (_returned)
     "exchange.",    # per-peer traffic + barrier waits/stragglers
     "fuse.",        # whole-commit fusion planner/jit
-    "index.",       # tiered IVF index: tier hits, prefetch, rebuild/swap
+    "index.",       # tiered IVF index: tier hits, prefetch, rebuild/swap; as-of-now replies assembled, re-emitted
     "index.quant.", # int8 retrieval: rescore batches, recalibrations, audits
     "lint.",        # graph/runtime lint diagnostics
     "lm.",          # generation service: prefills, decode steps, rows, experts touched

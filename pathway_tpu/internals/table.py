@@ -632,10 +632,16 @@ class Table(Joinable):
         index_filter_data_column: expr.ColumnReference | None = None,
         query_filter_column: expr.ColumnReference | None = None,
         asof_now: bool = True,
+        index_payload_columns: Dict[str, expr.ColumnReference] | None = None,
     ) -> "Table":
         """Query a pluggable external index (reference ``graph.rs:917``,
         ``external_index.rs:38``). ``self`` is the query table. With ``asof_now=False``
-        live queries are re-answered when the index changes."""
+        live queries are re-answered when the index changes.
+
+        Without ``index_payload_columns`` a reply is one ``_pw_index_reply`` tuple of
+        ``(key, score)``. With them (as-of-now only) the operator assembles the
+        collapsed reply itself: ``_pw_index_reply_score`` and one tuple per payload
+        column (columns of ``index_table``), best score first."""
         node = G.add_node(
             pg.ExternalIndexNode(
                 inputs=[index_table, self],
@@ -646,8 +652,18 @@ class Table(Joinable):
                 index_filter_data_column=index_filter_data_column,
                 query_filter_column=query_filter_column,
                 asof_now=asof_now,
+                index_payload_columns=index_payload_columns,
             )
         )
+        if index_payload_columns is not None:
+            # the type the live path's groupby / join_left gives the same columns
+            reply_type = dt.Optional_(dt.ANY_TUPLE)
+            columns = {
+                name: sch.ColumnSchema(name, reply_type)
+                for name in ("_pw_index_reply_score", *index_payload_columns)
+            }
+            schema = sch.schema_from_columns(columns, "external_index")
+            return Table(node, schema, universe=self._universe, name="external_index")
         columns = {"_pw_index_reply": sch.ColumnSchema("_pw_index_reply", res_type)}
         schema = sch.schema_from_columns(columns, "external_index")
         return Table(node, schema, universe=self._universe, name="external_index")

@@ -354,6 +354,9 @@ def compute_reshard_plan(runner: Any) -> ReshardPlan:
             for i, inp in enumerate(node.inputs)
             if not (i < len(pol) and pol[i] == "broadcast")
         ] or [placement(inp._node) for inp in node.inputs]
+        # only the index content replicates: an external index's replies are
+        # keyed by query row and partition by key, as its own query side does
+        contrib = ["bykey" if c == "replicate" else c for c in contrib]
         if not contrib:
             return "source"
         if any(c == "refuse" for c in contrib):

@@ -47,11 +47,22 @@ class _InstanceFactory:
         return self._make()
 
 
+def _payload_name(name: str) -> str:
+    return f"_pw_payload_{name}"
+
+
 class DataIndex:
     """Index over ``data_table``; querying returns per-query matched rows.
 
     ``query_as_of_now`` gives as-of-now semantics (answers never retracted on index change;
     used by RAG serving); ``query`` re-answers queries when the index updates.
+
+    Who builds the reply: for ``query_as_of_now(..., collapse_rows=True)`` the index
+    operator itself (``ExternalIndexEvaluator``) assembles one row per query from the
+    data columns it keeps beside the vectors, because such a reply is answered once
+    and never revisited. ``query`` and ``collapse_rows=False`` lower the reply through
+    flatten / ix (and, collapsed, groupby / join_left): a live query is re-answered
+    whenever the index changes, and that chain's differential update tracks it.
     """
 
     def __init__(
@@ -62,7 +73,8 @@ class DataIndex:
         self.data_table = data_table
         self.inner_index = inner_index
         # build the (possibly embedded) index-side table ONCE: every query surface shares
-        # it, so the corpus crosses the TPU embedder a single time per document update
+        # it, so the corpus crosses the TPU embedder a single time per document update;
+        # the data columns ride along as the payload of assembled as-of-now replies
         self._index_table = data_table.select(
             _pw_vec=inner_index.preprocess_data(inner_index.data_column),
             **(
@@ -70,6 +82,7 @@ class DataIndex:
                 if inner_index.metadata_column is not None
                 else {}
             ),
+            **{_payload_name(name): data_table[name] for name in data_table.column_names()},
         )
 
     def query_as_of_now(
@@ -125,6 +138,8 @@ class DataIndex:
             ),
         )
         index_table = self._index_table
+        assemble = as_of_now and collapse_rows
+        data_names = self.data_table.column_names()
         reply = query_table._external_index_as_of_now(
             index_table,
             index_column=index_table._pw_vec,
@@ -139,7 +154,19 @@ class DataIndex:
                 query_table._pw_qfilter if metadata_filter is not None else None
             ),
             asof_now=as_of_now,
+            index_payload_columns=(
+                {name: index_table[_payload_name(name)] for name in data_names}
+                if assemble
+                else None
+            ),
         )
+        if assemble:
+            # the operator's row is the collapsed reply, keyed by the query
+            return reply.select(
+                *[queries[n] for n in queries.column_names()],
+                _pw_index_reply_score=reply._pw_index_reply_score,
+                **{name: reply[name] for name in data_names},
+            )
         # reply: per query key, tuple of (data_key, score)
         if not collapse_rows:
             flat = reply.flatten(reply._pw_index_reply, origin_id="_pw_query_id")

@@ -570,7 +570,7 @@ def test_reshard_plan_accepts_descriptor_capable_external_index():
     plan = compute_reshard_plan(runner)
     # the external-index node itself plans as "replicate" — the blanket
     # device-resident refusal is GONE for descriptor-capable indexes (the
-    # collapse_rows flatten downstream keeps its own, unrelated refusal)
+    # collapsed as-of-now reply downstream is keyed by query and plans by key)
     ext = [
         nid for nid, pol in plan.policies.items() if pol == "replicate"
     ]
